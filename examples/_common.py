@@ -18,17 +18,37 @@ def base_parser(description: str) -> argparse.ArgumentParser:
     p.add_argument("--batch", type=int, default=32, help="micro-batch / window size")
     p.add_argument("--parallelism", type=int, default=1)
     p.add_argument("--cpu", action="store_true",
-                   help="force CPU with 8 virtual devices (default: real TPU if present)")
+                   help="force CPU with 8 virtual devices (default: the "
+                        "platform jax finds, named in the JSON line)")
     p.add_argument("--smoke", action="store_true", help="tiny sizes for CI")
     return p
 
 
-def select_platform(force_cpu: bool, virtual_devices: int = 8) -> None:
-    """Must run before jax touches a backend."""
-    if force_cpu:
-        from flink_tensorflow_tpu.utils.platform import force_cpu as _force
+def select_platform(force_cpu: bool, virtual_devices: int = 8,
+                    parallelism: int = 1):
+    """Must run before jax touches a backend.  Returns the job's device
+    provider for ``env.configure(device_provider=...)``: None at
+    parallelism 1 (the library's default placement), otherwise subtask
+    ``i`` runs on ``jax.local_devices()[i % n]`` — one replica per chip
+    instead of every replica on the first."""
+    from flink_tensorflow_tpu.utils import platform
 
-        _force(virtual_devices)
+    if force_cpu:
+        platform.force_cpu(virtual_devices)
+    else:
+        # The cache is for the chip's compiles (Inception-v3 takes tens
+        # of seconds); forced-CPU runs are the tests' and skip it.
+        platform.enable_compile_cache()
+    if parallelism <= 1:
+        return None
+
+    def device_provider(task: str, index: int):
+        import jax
+
+        devices = jax.local_devices()
+        return devices[index % len(devices)]
+
+    return device_provider
 
 
 def synthetic_images(n: int, size: int, channels: int = 3, seed: int = 0):
@@ -49,9 +69,15 @@ def synthetic_images(n: int, size: int, channels: int = 3, seed: int = 0):
 
 def report(job: str, metrics: dict, t0: float, records: int, extra: dict = None):
     """One human-readable summary + one machine-readable JSON line."""
+    import jax
+
     wall = time.time() - t0
+    devices = jax.devices()
     out = {
         "job": job,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "devices": len(devices),
         "records": records,
         "wall_s": round(wall, 3),
         "records_per_s": round(records / wall, 2) if wall > 0 else None,

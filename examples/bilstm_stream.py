@@ -34,7 +34,7 @@ def synthetic_texts(n, vocab, max_len, seed=0):
 
 def main(argv=None):
     args = base_parser(__doc__).parse_args(argv)
-    select_platform(args.cpu)
+    device_provider = select_platform(args.cpu, parallelism=args.parallelism)
     if args.smoke:
         args.records, args.batch = 24, 8
     vocab, hidden, max_len = (1000, 64, 48) if args.smoke else (20000, 256, 192)
@@ -50,6 +50,7 @@ def main(argv=None):
     records = synthetic_texts(args.records, vocab, max_len)
 
     env = StreamExecutionEnvironment(parallelism=args.parallelism)
+    env.configure(device_provider=device_provider)
     results = (
         # Plan-time schema: tokens has a dynamic (None) length dim — the
         # analyzer confirms the model's length-bucketing policy resolves

@@ -34,7 +34,7 @@ def main(argv=None):
                         "two-phase-commit file sink (committed on durable "
                         "checkpoints)")
     args = p.parse_args(argv)
-    select_platform(args.cpu)
+    device_provider = select_platform(args.cpu, parallelism=args.parallelism)
     if args.smoke:
         args.records, args.batch = 16, 8
 
@@ -65,6 +65,7 @@ def main(argv=None):
     records = synthetic_images(args.records, 299)
 
     env = StreamExecutionEnvironment(parallelism=args.parallelism)
+    env.configure(device_provider=device_provider)
     if args.output_dir:
         # Deterministic barriers + the 2PC sink: committed output files
         # hold each result exactly once even across failover.

@@ -46,7 +46,7 @@ PROMPTS = [
 
 def main(argv=None):
     args = base_parser(__doc__).parse_args(argv)
-    select_platform(args.cpu)
+    device_provider = select_platform(args.cpu, parallelism=args.parallelism)
     if args.smoke:
         args.records = 8
 
@@ -72,6 +72,7 @@ def main(argv=None):
     ]
 
     env = StreamExecutionEnvironment(parallelism=args.parallelism)
+    env.configure(device_provider=device_provider)
     # Declared serving layout: an ABSTRACT v5e-8 mesh (data=4 x tp=2) +
     # the per-chip HBM ceiling.  Nothing at execution time touches these
     # on a CPU box — they exist so `flink-tpu-shardcheck` (and the

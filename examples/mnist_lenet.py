@@ -16,7 +16,7 @@ from examples._common import base_parser, report, select_platform, synthetic_ima
 
 def main(argv=None):
     args = base_parser(__doc__).parse_args(argv)
-    select_platform(args.cpu)
+    device_provider = select_platform(args.cpu, parallelism=args.parallelism)
     if args.smoke:
         args.records, args.batch = 32, 8
 
@@ -31,6 +31,7 @@ def main(argv=None):
     records = synthetic_images(args.records, 28, channels=1)
 
     env = StreamExecutionEnvironment(parallelism=args.parallelism)
+    env.configure(device_provider=device_provider)
     results = (
         # Declaring the source schema lets the plan analyzer check the
         # stream against the model's input contract before execution

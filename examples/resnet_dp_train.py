@@ -76,7 +76,6 @@ def main(argv=None):
     job = env.execute("resnet50-dp-training", timeout=3600)
     losses = [float(r["loss"]) for r in out]
     return report("resnet50_dp_training", job.metrics, t0, args.records, {
-        "devices": n_dev,
         "steps": len(losses),
         "loss_first": round(losses[0], 4) if losses else None,
         "loss_last": round(losses[-1], 4) if losses else None,

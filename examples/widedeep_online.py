@@ -41,7 +41,7 @@ def synthetic_events(n, num_wide, num_dense, slots, buckets, users=16, seed=0):
 
 def main(argv=None):
     args = base_parser(__doc__).parse_args(argv)
-    select_platform(args.cpu)
+    device_provider = select_platform(args.cpu, parallelism=args.parallelism)
     if args.smoke:
         args.records, args.batch = 64, 4
 
@@ -65,6 +65,7 @@ def main(argv=None):
                                cfg["num_cat_slots"], cfg["hash_buckets"])
 
     env = StreamExecutionEnvironment(parallelism=args.parallelism)
+    env.configure(device_provider=device_provider)
     out = (
         # The train schema doubles as the source's record schema, so the
         # plan analyzer validates the keyed pipeline end to end.

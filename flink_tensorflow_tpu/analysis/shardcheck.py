@@ -58,10 +58,10 @@ if typing.TYPE_CHECKING:
     from flink_tensorflow_tpu.analysis.rules import AnalysisContext
 
 #: jaxpr primitives that lower to inter-device collectives (ICI/DCN
-#: traffic).  ``psum_scatter`` is reduce-scatter's primitive name.
+#: traffic).  ``lax.psum_scatter`` binds ``reduce_scatter``.
 COLLECTIVE_PRIMS = frozenset({
     "psum", "pmax", "pmin", "pgather", "all_gather", "all_to_all",
-    "ppermute", "pshuffle", "psum_scatter", "reduce_scatter",
+    "ppermute", "reduce_scatter",
 })
 
 #: Donation findings only fire for args at least this large — donating
@@ -241,12 +241,12 @@ def _aval_bytes(v) -> int:
 
 def count_collectives(closed) -> typing.Dict[str, int]:
     """primitive name -> occurrences across every level of ``closed``.
-    jax revs collective primitives by suffixing a digit (``psum`` became
-    ``psum2``); the census strips the suffix so the names stay stable."""
+    Under ``shard_map(check_vma=True)`` psum and all_gather bind their
+    ``*_invariant`` twins; the census counts both under the plain name."""
     counts: typing.Dict[str, int] = {}
     for level in _iter_levels(closed.jaxpr):
         for eqn in level.eqns:
-            name = eqn.primitive.name.rstrip("0123456789")
+            name = eqn.primitive.name.removesuffix("_invariant")
             if name in COLLECTIVE_PRIMS:
                 counts[name] = counts.get(name, 0) + 1
     return counts

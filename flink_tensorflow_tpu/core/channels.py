@@ -9,7 +9,7 @@ The gate is **event-driven**: readers block on a condition variable and are
 woken by the first put (or :meth:`wake`, or :meth:`close`) — there is no
 timed poll interval anywhere on the record plane, so an idle hop costs a
 wakeup latency of one ``notify``, not a 50 ms sleep quantum (the
-``collection_poll`` / idle-poll floor components of BENCH_r05).  Writers
+``collection_poll`` / idle-poll floor components of round 5).  Writers
 blocked on a full queue are likewise woken by the consuming ``poll``.
 
 Only host objects (numpy buffers, metadata) cross channels.  Device arrays

@@ -368,8 +368,8 @@ class MapOperator(_FunctionOperator):
         # A watermark must not overtake in-flight results: flush the
         # function's buffered/in-flight records first, or downstream
         # event-time operators would see them arrive "late" (< watermark)
-        # and drop them.  Consequence (documented on ModelMapFunction and
-        # PARITY.md): watermark_every=1 upstream degrades the transparent
+        # and drop them.  Consequence (documented on ModelMapFunction):
+        # watermark_every=1 upstream degrades the transparent
         # micro-batch to batch-of-1 — choose watermark_every >= the
         # micro_batch when an event-time pipeline feeds an async map.
         if self._async:

@@ -19,7 +19,7 @@ are untouched by fusion.
 The record plane between chains is event-driven end to end: the worker
 loop blocks on its input gate until a put / wake / close or the chain's
 earliest operator deadline — there is no timed idle poll (the 50 ms
-``_IDLE_POLL_S`` of BENCH_r05's latency floor is gone).
+``_IDLE_POLL_S`` of round 5's latency floor is gone).
 
 The mapping to TPU topology (SURVEY.md §7 step 4): subtask index -> local
 chip for operator-DP inference; gang operators instead share one

@@ -146,7 +146,7 @@ class AdaptiveLatencyTrigger(Trigger):
     At 0.5x capacity this puts p50 near one inter-arrival gap plus the
     small-batch service time instead of near the budget — the static
     ``CountOrTimeoutTrigger`` parks every record at the timeout
-    (measured 1149ms p50 vs a 1000ms timeout, BENCH_r02).
+    (round 2: 1149ms p50 against a 1000ms timeout).
 
     The EWMA is per-subtask (``clone``) and pools across keys of a keyed
     window — it estimates the subtask's aggregate arrival process.

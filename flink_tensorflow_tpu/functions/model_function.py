@@ -447,9 +447,8 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
 
     Dispatch is pipelined (``pipeline_depth`` batches in flight): while
     the device runs window k, the host batches and ships window k+1 —
-    transfer hides under compute, which is the throughput lever on
-    PCIe/tunnel-attached chips.  ``transfer_lanes > 1`` additionally
-    overlaps the wire transfers of in-flight batches on a thread pool
+    the host->device transfer hides under compute.  ``transfer_lanes > 1``
+    additionally overlaps the transfers of in-flight batches on a thread pool
     (the lever when single-stream transfer bandwidth is the ceiling);
     ``pipeline_depth`` defaults to ``2 * transfer_lanes`` so the lanes
     stay fed.  In-flight batches are flushed at end of input and before
@@ -682,7 +681,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     # blocking flush idle_flush_s after the last dispatch) turned the
     # operator into an M/D/1 server at open-loop rates: every window's
     # results waited out the whole device round trip on the subtask
-    # thread while later windows queued behind it (BENCH_r03's 536ms p50
+    # thread while later windows queued behind it (round 3's 536ms p50
     # at 0.5x capacity).  Polling emits each batch within one poll
     # interval of its results landing, and the thread stays free to
     # accept arrivals and fire the next window meanwhile.

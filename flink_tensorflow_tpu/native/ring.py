@@ -25,6 +25,7 @@ guarantees) when the native library isn't built.
 from __future__ import annotations
 
 import ctypes
+import logging
 import mmap
 import os
 import struct
@@ -35,6 +36,8 @@ import threading
 import numpy as np
 
 from flink_tensorflow_tpu.tensors.schema import RecordSchema
+
+logger = logging.getLogger(__name__)
 
 _LIB = None
 _LIB_TRIED = False
@@ -52,8 +55,10 @@ def _load_lib():
     _LIB_TRIED = True
     path = _lib_path()
     if not os.path.exists(path):
+        logger.info("TensorRing: python ring (%s not built; make -C native)", path)
         return None
     lib = ctypes.CDLL(path)
+    logger.info("TensorRing: native ring loaded from %s", path)
     lib.ring_create.restype = ctypes.c_void_p
     lib.ring_create.argtypes = [ctypes.c_uint64, ctypes.c_uint64]
     lib.ring_destroy.argtypes = [ctypes.c_void_p]
@@ -75,6 +80,12 @@ def _load_lib():
 
 def native_available() -> bool:
     return _load_lib() is not None
+
+
+def ring_impl() -> str:
+    """Which ring a default ``TensorRing`` runs on: ``"native"`` (the
+    C++ arena from native/src/spsc_ring.cpp) or ``"python"``."""
+    return "native" if native_available() else "python"
 
 
 def _soa_layout(schema: RecordSchema, length_bucket: int, capacity: int):

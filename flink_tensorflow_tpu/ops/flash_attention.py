@@ -10,7 +10,9 @@ native TPU kernel (pallas) rather than a composed jnp graph:
 - online softmax (running max/denominator) — no [T, T] score matrix ever
   materializes in HBM
 - ``jnp.dot(..., preferred_element_type=f32)`` keeps both matmuls on the
-  MXU with f32 accumulation over bf16 inputs
+  MXU with f32 accumulation over bf16 inputs; float32 inputs contract at
+  float32 precision (Mosaic's default would round them to bf16 — a
+  0.01 output error the interpreter never shows)
 - causal grids skip fully-masked K/V tiles entirely (upper-triangle
   blocks are never read)
 
@@ -150,11 +152,10 @@ def _tileable_block(t: int, pref: int) -> int:
 
 def _vma(*xs):
     """Union of the operands' varying-mesh-axes sets — required on pallas
-    out_shapes when the kernel runs inside shard_map (check_vma=True).
-    Empty on jax versions without vma tracking (utils/jaxcompat)."""
-    from flink_tensorflow_tpu.utils.jaxcompat import varying_axes
+    out_shapes when the kernel runs inside shard_map (check_vma=True)."""
+    import jax
 
-    return varying_axes(*xs)
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
 def _flash_bh(q, k, v, *, causal, block_q, block_k, interpret):
@@ -180,14 +181,13 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flink_tensorflow_tpu.utils.jaxcompat import (
-        shape_dtype_struct,
-        tpu_compiler_params,
-    )
-
     dtype = jnp.dtype(dtype_str)
     nq, nk = t // block_q, tk // block_k
     scale = 1.0 / math.sqrt(d)
+    # Mosaic's default contraction feeds the MXU one bf16 pass whatever
+    # the operand dtype: right for bf16 callers, a silent downcast of
+    # q/k/v/p for float32 ones.
+    precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
         # Grid (bh, nq, nk): the innermost k dimension iterates
@@ -211,7 +211,8 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
             q_blk = q_ref[0].astype(jnp.float32) * scale       # [bq, d]
             k_blk = k_ref[0].astype(jnp.float32)               # [bk, d]
             v_blk = v_ref[0].astype(jnp.float32)
-            s = jnp.dot(q_blk, k_blk.T, preferred_element_type=jnp.float32)
+            s = jnp.dot(q_blk, k_blk.T, precision=precision,
+                        preferred_element_type=jnp.float32)
             if causal:
                 q_pos = qi * block_q + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
@@ -231,7 +232,8 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
             m_scr[:] = m_new[:, None]
             l_scr[:] = (l * alpha + jnp.sum(p, axis=-1))[:, None]
             acc_scr[:] = acc_scr[:] * alpha[:, None] + jnp.dot(
-                p, v_blk, preferred_element_type=jnp.float32)
+                p, v_blk, precision=precision,
+                preferred_element_type=jnp.float32)
 
         @pl.when(j == nk - 1)
         def _finalize():
@@ -262,8 +264,8 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            shape_dtype_struct((bh, t, d), dtype, vma),
-            shape_dtype_struct((bh, t, 1), jnp.float32, vma),
+            jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -274,7 +276,7 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
         # j==0 per (bh, qi)): declaring them parallel lets Mosaic
         # megacore-partition the grid on v4/v5p; only the K sweep is
         # order-dependent (online-softmax carry).
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

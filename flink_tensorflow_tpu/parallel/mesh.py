@@ -117,15 +117,14 @@ def abstract_mesh(axes: typing.Mapping[str, int]):
     spec = MeshSpec(axes)  # validates names/sizes against AXIS_ORDER
     from jax.sharding import AbstractMesh
 
-    return AbstractMesh(tuple((a, spec.axes[a]) for a in spec.axis_names))
+    names = spec.axis_names
+    return AbstractMesh(tuple(spec.axes[a] for a in names), names)
 
 
 def is_abstract_mesh(mesh) -> bool:
     """True for AbstractMesh declarations (shape-only, no devices)."""
-    try:
-        from jax.sharding import AbstractMesh
-    except ImportError:  # pragma: no cover - ancient jax
-        return False
+    from jax.sharding import AbstractMesh
+
     return isinstance(mesh, AbstractMesh)
 
 

@@ -21,11 +21,8 @@ from __future__ import annotations
 
 import functools
 import math
-import typing
 
 from flink_tensorflow_tpu.parallel.mesh import SEQ_AXIS
-from flink_tensorflow_tpu.utils.jaxcompat import axis_size as compat_axis_size
-from flink_tensorflow_tpu.utils.jaxcompat import shard_map as compat_shard_map
 
 
 def _block_attention(q, k, v, m, l, o, mask):
@@ -74,8 +71,7 @@ def _combine_blocks(o_acc, lse_acc, o_blk, lse_blk):
 
 
 def ring_attention_sharded(q, k, v, *, axis_name: str = SEQ_AXIS,
-                           causal: bool = False, impl: str = "flash",
-                           axis_size: typing.Optional[int] = None):
+                           causal: bool = False, impl: str = "flash"):
     """Ring attention body — call INSIDE ``shard_map`` over ``axis_name``.
 
     q/k/v: the local shard ``[B, T_local, H, D]``.  Returns the local
@@ -87,15 +83,14 @@ def ring_attention_sharded(q, k, v, *, axis_name: str = SEQ_AXIS,
     online-softmax path (golden baseline / debugging).
     """
     if impl == "flash":
-        return _ring_flash(q, k, v, axis_name=axis_name, causal=causal,
-                           axis_size=axis_size)
+        return _ring_flash(q, k, v, axis_name=axis_name, causal=causal)
     if impl != "einsum":
         raise ValueError(f"impl must be 'flash' or 'einsum', got {impl!r}")
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    n = compat_axis_size(axis_name, axis_size)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     qf = q.astype(jnp.float32)
@@ -136,8 +131,7 @@ def ring_attention_sharded(q, k, v, *, axis_name: str = SEQ_AXIS,
     return out.astype(q.dtype)
 
 
-def _ring_flash(q, k, v, *, axis_name: str, causal: bool,
-                axis_size: typing.Optional[int] = None):
+def _ring_flash(q, k, v, *, axis_name: str, causal: bool):
     """Flash-kernel ring body: each K/V block runs through the pallas
     kernel (MXU matmuls, O(block) VMEM), blocks merge via lse residuals.
 
@@ -152,7 +146,7 @@ def _ring_flash(q, k, v, *, axis_name: str, causal: bool,
 
     from flink_tensorflow_tpu.ops.flash_attention import flash_attention
 
-    n = compat_axis_size(axis_name, axis_size)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     perm = [(j, (j + 1) % n) for j in range(n)]
@@ -215,15 +209,15 @@ def ring_attention(mesh, q, k, v, *, causal: bool = False, impl: str = "flash"):
     # Batch rides the data axis when the mesh has one (dp x sp composes).
     batch_axis = DATA_AXIS if DATA_AXIS in mesh.axis_names else None
     spec = P(batch_axis, SEQ_AXIS, None, None)
-    fn = compat_shard_map(
-        functools.partial(ring_attention_sharded, causal=causal, impl=impl,
-                          axis_size=dict(mesh.shape)[SEQ_AXIS]),
+    fn = jax.shard_map(
+        functools.partial(ring_attention_sharded, causal=causal, impl=impl),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        # pallas_call outputs don't yet thread varying-mesh-axes through
-        # the interpret-mode lowering (dynamic_slice vma mismatch), so the
-        # flash body runs with vma checking off; einsum keeps it on.
+        # The INTERPRETED kernel's dynamic_slice trips the vma check
+        # inside the ring's lax.switch (the Mosaic-compiled one passes
+        # it on a v5e), so the flash body runs with the check off on
+        # every backend; einsum keeps it on.
         check_vma=impl != "flash",
     )
     sharding = NamedSharding(mesh, spec)
@@ -277,7 +271,7 @@ def ring_decode_attention(mesh, q, k, v, lengths, *, axis_name: str = SEQ_AXIS):
 
     kv_spec = P(None, axis_name, None, None)
     rep = P(None, None, None, None)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, kv_spec, kv_spec, P(None)),
         out_specs=rep,

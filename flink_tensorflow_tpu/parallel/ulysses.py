@@ -23,16 +23,12 @@ compose with a ``data`` axis for dp x sp meshes.
 from __future__ import annotations
 
 import functools
-import typing
 
 from flink_tensorflow_tpu.parallel.mesh import SEQ_AXIS
-from flink_tensorflow_tpu.utils.jaxcompat import axis_size as compat_axis_size
-from flink_tensorflow_tpu.utils.jaxcompat import shard_map as compat_shard_map
 
 
 def ulysses_attention_sharded(q, k, v, *, axis_name: str = SEQ_AXIS,
-                              causal: bool = False, impl: str = "flash",
-                              axis_size: typing.Optional[int] = None):
+                              causal: bool = False, impl: str = "flash"):
     """Ulysses body — call INSIDE ``shard_map`` over ``axis_name``.
 
     q/k/v: the local shard ``[B, T_local, H, D]`` with ``H`` divisible by
@@ -40,7 +36,7 @@ def ulysses_attention_sharded(q, k, v, *, axis_name: str = SEQ_AXIS,
     """
     from jax import lax
 
-    n = compat_axis_size(axis_name, axis_size)
+    n = lax.axis_size(axis_name)
     b, t, h, d = q.shape
     if h % n:
         raise ValueError(
@@ -106,7 +102,7 @@ def ulysses_decode_attention(mesh, q, k, v, lengths, *,
         return flash_attention_decode(q_, k_, v_, lengths_)
 
     head_spec = P(None, None, axis_name, None)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(head_spec, head_spec, head_spec, P(None)),
         out_specs=head_spec,
@@ -132,13 +128,13 @@ def ulysses_attention(mesh, q, k, v, *, causal: bool = False, impl: str = "flash
     # Batch rides the data axis when the mesh has one (dp x sp composes).
     batch_axis = DATA_AXIS if DATA_AXIS in mesh.axis_names else None
     spec = P(batch_axis, SEQ_AXIS, None, None)
-    fn = compat_shard_map(
-        functools.partial(ulysses_attention_sharded, causal=causal, impl=impl,
-                          axis_size=dict(mesh.shape)[SEQ_AXIS]),
+    fn = jax.shard_map(
+        functools.partial(ulysses_attention_sharded, causal=causal, impl=impl),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        # Same interpret-mode vma caveat as the ring's flash body.
+        # Same caveat as the ring's flash body: the interpreted kernel
+        # trips the vma check, the Mosaic-compiled one passes it.
         check_vma=impl != "flash",
     )
     sharding = NamedSharding(mesh, spec)

@@ -24,7 +24,7 @@ asynchrony lives one layer up, in two places:
 
 Wire narrowing: ``DeviceTransfer(wire_dtype=...)`` casts float fields to
 a compact dtype (bf16/f16) host-side before ``device_put``, halving the
-bytes over the PCIe/tunnel hop; the model runner restores the declared
+bytes of the host->device transfer; the model runner restores the declared
 dtype INSIDE its jitted call, so the upcast runs fused on device and the
 numerics past the input cast are full precision.
 """

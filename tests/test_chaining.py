@@ -391,7 +391,7 @@ class TestChainedExecution:
 
 class TestEventDrivenRecordPlane:
     def test_no_timed_poll_constants_remain(self):
-        """The 50 ms quanta of BENCH_r05's fixed floor components are
+        """The 50 ms quanta of round 5's fixed floor components are
         gone from both layers — waits are condition-variable driven."""
         from flink_tensorflow_tpu.core import channels, runtime
 

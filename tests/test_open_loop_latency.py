@@ -13,7 +13,7 @@ framework defects, each pinned here:
    WindowOperator from the runner's EWMA) reserves the round trip out
    of the budget — clamped to one expected gap so the reserve can never
    collapse windows to batch-1 (whose per-call overhead sinks below
-   offered rates; measured as a queueing collapse on the tunnel).
+   offered rates and the queue collapses).
 3. Nothing attributed latency to stages.  The runner stamps per-record
    stage timestamps (``meta["__stages__"]``) and the window operator
    stamps arrival (``__arrive_ts__``) when the function opts in.

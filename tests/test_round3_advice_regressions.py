@@ -1,4 +1,4 @@
-"""Pins for the round-2 advisor findings (ADVICE.md r2).
+"""Pins for the round-2 advisor findings.
 
 1. (high) IntervalJoinOperator evicted matches prematurely when the
    interval excludes zero — retention/acceptance now use the

@@ -1,4 +1,4 @@
-"""Pins for the round-3 advisor findings (ADVICE.md r3).
+"""Pins for the round-3 advisor findings.
 
 1. (medium) Cohort restore was impossible when num_processes exceeded the
    job's max operator parallelism: idle processes own no subtasks and

@@ -6,7 +6,7 @@
    measured span, and a small run (records < 2*batch) must clamp the
    exclusion instead of indexing past the arrivals list.
 2. ``_delta_timing``: the shared probe-timing helper widens the K
-   spread once when tunnel RTT variance inverts the delta, and reports
+   spread once when round-trip variance inverts the delta, and reports
    degenerate (never a negative rate) when even the widened spread
    inverts.
 3. Stage stamps tile: the per-record stage boundaries stamped by the
@@ -77,7 +77,7 @@ class TestDeltaTiming:
             return base[0]
 
         # k=2 takes LONGER than any larger k (inverted medians — the
-        # tunnel-RTT-variance pathology): widened once, then degenerate.
+        # round-trip-variance pathology): widened once, then degenerate.
         def run(k):
             base[0] += 0.5 if k == 2 else 0.1
 

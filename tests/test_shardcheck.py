@@ -376,10 +376,10 @@ class TestHealthy:
         from the jaxpr — the per-step ICI bill, statically."""
         from functools import partial
 
+        from jax import shard_map
         from jax.sharding import AbstractMesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
-        mesh = AbstractMesh((("data", 1),))
+        mesh = AbstractMesh((1,), ("data",))
         schema = RecordSchema({"x": spec((8,), np.float32)})
 
         def fn(params, batch):

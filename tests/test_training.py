@@ -146,7 +146,7 @@ class TestOnlineTrain:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_disk_checkpoint_roundtrip_with_adam(self, tmp_path):
-        """Persistence regression (ADVICE.md r1): a training snapshot must
+        """Persistence regression (round-1 advisor): a training snapshot must
         survive write_checkpoint → pickle → read_checkpoint with (a) the
         typed PRNG key and (b) optax's namedtuple optimizer state intact,
         and the restored function must complete a post-restore adam step."""
