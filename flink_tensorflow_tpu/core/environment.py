@@ -558,6 +558,11 @@ class StreamExecutionEnvironment:
         if validate:
             self.validate_plan()
         executor = self._make_executor(restart_epoch)
+        # Post-mortem accessor: this job's flight ring stays reachable by
+        # name (tracing.flight.recorder_of) after the handle is released.
+        from flink_tensorflow_tpu.tracing import flight as flight_mod
+
+        flight_mod.keep(job_name, executor.flight)
         reporter = self._make_reporter(report_interval_s,
                                        flight=executor.flight)
         executor.checkpoint_interval_s = self.checkpoint_interval_s
@@ -637,8 +642,6 @@ class StreamExecutionEnvironment:
         # BEFORE the previous handler (usually: death) runs — a killed
         # worker no longer loses its last reporting interval.  Chained
         # and uninstalled at wait()/cancel(); no-op off the main thread.
-        from flink_tensorflow_tpu.tracing.flight import ShutdownFlusher
-
         callbacks = []
         if reporter is not None:
             callbacks.append(reporter.flush_now)
@@ -647,7 +650,7 @@ class StreamExecutionEnvironment:
         if executor.tracer is not None and executor.trace_path:
             callbacks.append(handle._export_trace)
         if callbacks:
-            flusher = ShutdownFlusher(callbacks)
+            flusher = flight_mod.ShutdownFlusher(callbacks)
             if flusher.install():
                 handle._flusher = flusher
         return handle

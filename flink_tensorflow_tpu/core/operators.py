@@ -605,7 +605,6 @@ class WindowOperator(_FunctionOperator):
         self._window_seq: typing.Dict[typing.Any, int] = {}
         self._collector: typing.Optional[fn.Collector] = None
         self._svc_feed = None       # resolved in open()
-        self._arrival_stamp = False  # resolved in open()
 
     def open(self) -> None:
         self._collector = fn.Collector(self.output.emit)
@@ -620,10 +619,6 @@ class WindowOperator(_FunctionOperator):
             (estimate, observe) if observe is not None and estimate is not None
             else None
         )
-        # Stage-stamping functions also want each record's ARRIVAL time
-        # at this operator (splits upstream queue-wait from the trigger's
-        # own hold in the latency decomposition).
-        self._arrival_stamp = bool(getattr(self.function, "_stamp_stages", False))
 
     def _feed_service_time(self) -> None:
         if self._svc_feed is not None:
@@ -644,15 +639,6 @@ class WindowOperator(_FunctionOperator):
             buf = WindowBuffer(window=CountWindow(seq))
             self._buffers[key] = buf
         value = record.value
-        if self._arrival_stamp:
-            stamp = getattr(value, "with_meta", None)
-            if stamp is not None:
-                # Stamp onto a COPY of the record (ADVICE r4): the same
-                # record object may fan out to sibling operators or be
-                # retained by a sliding trigger, and an in-place meta
-                # mutation would be visible to those other consumers.
-                # The copy is shallow — frozen field arrays are shared.
-                value = stamp(__arrive_ts__=time.monotonic())
         # Zero-copy ingestion: tensor window functions may take the record
         # payload NOW (into their ring arena) and buffer only a token —
         # non-keyed only, and never for retaining (sliding) triggers:

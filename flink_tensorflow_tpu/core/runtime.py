@@ -222,6 +222,10 @@ class _Subtask:
         # -- instrumentation (wired by the executor in _build) -----------
         #: Single-writer accumulators behind this subtask's pull gauges.
         self.stats = SubtaskStats()
+        #: Window-level span hook (tracing.flight.SpanHook) shared by the
+        #: chain's operators; the two event loops report their parks to
+        #: it.  None when the flight ring and the tracer are both off.
+        self.spans = None
         self.records_in = None      # Meter (workers only; head operator)
         self.latency = None         # Timer: per-record processing/emit time
         self.alignment = None       # Timer: barrier-alignment spans
@@ -459,6 +463,7 @@ class _Subtask:
             every_n = executor.checkpoint_every_n
             tracer = executor.tracer
             faults = executor.faults
+            spans = self.spans
             while not executor.cancelled.is_set():
                 self._deliver_notifications()
                 self._drain_aborts()
@@ -522,9 +527,11 @@ class _Subtask:
                     if target is not None:
                         t = max(0.0, target - now)
                         timeout = t if timeout is None else min(timeout, t)
-                t0 = now
-                self.mailbox.wait(timeout)
-                stats.idle_s += time.monotonic() - t0
+                woken = self.mailbox.wait(timeout)
+                t1 = time.monotonic()
+                stats.idle_s += t1 - now
+                if spans is not None:
+                    spans.park(self.scope, timeout, t1 - now, woken, t1)
             # Serve barrier requests that raced with the last records.
             for cid in self._drain_control():
                 self._split_barrier(cid)
@@ -554,6 +561,7 @@ class _Subtask:
         latency = self.latency
         tracer = self.executor.tracer
         faults = self.executor.faults
+        spans = self.spans
         processed = 0
         try:
             self._open_chain()
@@ -585,6 +593,10 @@ class _Subtask:
                     # only empty polls are charged — no extra clock read
                     # either way).
                     stats.idle_s += now - poll_start
+                    if spans is not None:
+                        slept = now - poll_start
+                        spans.park(self.scope, timeout, slept,
+                                   timeout is None or slept < timeout, now)
                 if deadline is not None and now >= deadline:
                     self._chain_fire_due(now)
                 if item is None:
@@ -1075,6 +1087,10 @@ class LocalExecutor:
         proc_idx, num_procs = self._process_identity()
         head_gate = st.gate
         chain_len = len(st.units)
+        if self.flight is not None or self.tracer is not None:
+            from flink_tensorflow_tpu.tracing.flight import SpanHook
+
+            st.spans = SpanHook(self.flight, self.tracer)
         for pos, unit in enumerate(st.units):
             grp = self.metrics.group(unit.scope)
             unit.records_in = grp.meter("records_in")
@@ -1131,10 +1147,12 @@ class LocalExecutor:
                 process_index=proc_idx,
                 num_processes=num_procs,
             )
-            # Span tracer hand-off: model runners / remote sinks read
-            # ctx.tracer at open() and record their stage spans
-            # (h2d/compute/d2h, serde/wire) on this unit's track.
+            # Span tracer hand-off: remote sinks / decode runners read
+            # ctx.tracer at open() and record their stage spans on this
+            # unit's track; the model and train operators' window-level
+            # spans go through the subtask's hook.
             ctx.tracer = self.tracer
+            ctx.spans = st.spans
             # Sanitizer hand-off: remote sinks/sources log cross-process
             # happens-before events (frame send/recv, credit grant/spend)
             # through this at open().

@@ -56,11 +56,18 @@ class RuntimeContext:
         self.wakeup: typing.Optional[typing.Callable[[], None]] = None
         #: Span tracer (flink_tensorflow_tpu.tracing.Tracer) when the
         #: job runs traced; None (the default) is the zero-cost off
-        #: path.  Operators/functions with internal stages (the model
-        #: runner's h2d/compute/d2h, remote sinks' serde/wire) record
-        #: their spans through this on the ``task_name.subtask_index``
-        #: track.
+        #: path.  Per-record spans (remote sinks' serde/wire, the decode
+        #: runners' steps) are recorded through this on the
+        #: ``task_name.subtask_index`` track; the window-level spans of
+        #: the model and train operators go through ``spans`` below.
         self.tracer: typing.Optional[typing.Any] = None
+        #: Window-level span hook (tracing.flight.SpanHook), one per
+        #: subtask thread and shared by the operators chained onto it:
+        #: ``spans.span(track, name, t0, t1, args)`` once a window,
+        #: batch or step — never per record — lands in the always-on
+        #: flight ring and, when tracing is on, in the tracer.  None when
+        #: both are off.
+        self.spans: typing.Optional[typing.Any] = None
         #: Device-resident dataflow mode (JobConfig.device_resident):
         #: model functions consult it at open() to decide whether chained
         #: results stay HBM-resident (DeviceBatch) instead of fetching.

@@ -80,7 +80,6 @@ class _ModelFunctionBase(fn.RichFunction):
         donate_inputs: bool = False,
         outputs: typing.Optional[typing.Sequence[str]] = None,
         transfer_lanes: int = 1,
-        stamp_stages: bool = False,
         device_resident: typing.Optional[bool] = None,
         wire_dtype: typing.Optional[str] = None,
         sharding_axes: typing.Optional[typing.Sequence[str]] = None,
@@ -105,9 +104,6 @@ class _ModelFunctionBase(fn.RichFunction):
         self._donate = donate_inputs
         self._outputs = outputs
         self._transfer_lanes = transfer_lanes
-        #: Stamp per-record stage timestamps into result metadata
-        #: (``meta["__stages__"]``) for latency decomposition.
-        self._stamp_stages = stamp_stages
         #: Device-resident emission: True forces DeviceBatch output,
         #: False forces host records, None (default) follows
         #: JobConfig.device_resident AND the executor's chained-consumer
@@ -123,6 +119,15 @@ class _ModelFunctionBase(fn.RichFunction):
         self.runner: typing.Optional[CompiledMethodRunner] = None
         self._out: typing.Optional[fn.Collector] = None
         self._derived_schema: typing.Any = _UNKNOWN
+        #: Window-level span hook + track and the operator's metric group
+        #: (from ctx at open; tracing/flight.py lists the spans).
+        self._spans = None
+        self._track: typing.Optional[str] = None
+        self._metrics = None
+        #: Seconds spent in :meth:`_emit` so far (plain sum: the ``fill``
+        #: span takes its children's share from it, with no clock read
+        #: per record).
+        self._emit_total_s = 0.0
 
     # -- plan-time hooks (no model load, no device work) ------------------
     def plan_input_schema(self):
@@ -222,8 +227,23 @@ class _ModelFunctionBase(fn.RichFunction):
         signal, so results cannot strand behind a readiness lie."""
         if self.runner is None or self._out is None:
             return
-        for record in self.runner.collect_available():
-            self._out.collect(record)
+        self._emit(self.runner.collect_batches(), self._out)
+
+    def _emit(self, batches, out: fn.Collector) -> None:
+        """Hand collected batches (``runner.collect_batches``) downstream:
+        one ``emit`` span and one ``emit_s`` update a fetched batch (the
+        chained consumers' own work included, as the chain runs it)."""
+        for seq, records in batches:
+            t0 = time.monotonic()
+            for record in records:
+                out.collect(record)
+            t1 = time.monotonic()
+            self._emit_total_s += t1 - t0
+            if self._metrics is not None:
+                self._metrics.timer("emit_s").update(t1 - t0)
+            if self._spans is not None:
+                self._spans.span(self._track, "emit", t0, t1,
+                                 {"seq": seq, "records": len(records)})
 
     def clone(self) -> "fn.Function":
         # Subtasks share the host-side source (read-only); each builds its
@@ -237,6 +257,11 @@ class _ModelFunctionBase(fn.RichFunction):
         return dup
 
     def open(self, ctx) -> None:
+        t_open = time.monotonic()
+        self._metrics = getattr(ctx, "metrics", None)
+        self._spans = getattr(ctx, "spans", None)
+        if self._spans is not None:
+            self._track = f"{ctx.task_name}.{ctx.subtask_index}"
         model = _resolve(self._source)
         wire = (self._wire_dtype if self._wire_dtype is not None
                 else getattr(ctx, "wire_dtype", None))
@@ -249,7 +274,6 @@ class _ModelFunctionBase(fn.RichFunction):
             dispatch_lanes=self._transfer_lanes,
             wire_dtype=wire,
         )
-        self.runner.stamp_stages = self._stamp_stages
         self.runner.open(ctx)
         # Device-resident emission: explicit kwarg wins; otherwise the
         # job-wide mode applies only where the executor marked the next
@@ -262,16 +286,25 @@ class _ModelFunctionBase(fn.RichFunction):
             self.runner.emit_device_batches = bool(
                 getattr(ctx, "device_resident", False)
                 and self._device_chain_hint)
-        if self.runner.emit_device_batches and self._stamp_stages:
-            # Stage stamps ride per-record host metadata, which a
-            # device-resident batch does not materialize here.
-            self.runner.stamp_stages = False
         # Completed results wake the subtask loop immediately (instead of
         # waiting out the poll interval) when the runtime provides a
         # gate wakeup hook.
         self.runner.on_results_ready = getattr(ctx, "wakeup", None)
         if self._warmup:
             self.runner.warmup(self._warmup, self._warmup_length_bucket)
+        self._open_buffers()
+        # The ``open`` span (children: the runner's ``params_to_device``
+        # and ``jit_warmup_compile``) and ``open_s``.  Whether the compile
+        # cache was hit is not recorded: jax tells only a process-wide
+        # listener.
+        now = time.monotonic()
+        if self._metrics is not None:
+            self._metrics.timer("open_s").update(now - t_open)
+        if self._spans is not None:
+            self._spans.span(self._track, "open", t_open, now)
+
+    def _open_buffers(self) -> None:
+        """Subclass hook: the rest of ``open()``, inside its span."""
 
     def close(self) -> None:
         if self.runner is not None:
@@ -360,8 +393,7 @@ class ModelMapFunction(_ModelFunctionBase, fn.AsyncMapFunction):
             if len(self._buf) >= self._micro_batch:
                 self._dispatch_buf()
         self._last_activity = time.monotonic()
-        for record in self.runner.collect_progress(self._max_in_flight):
-            out.collect(record)
+        self._emit(self.runner.collect_batches(self._max_in_flight), out)
 
     def _dispatch_buf(self):
         if self._buf:
@@ -372,8 +404,7 @@ class ModelMapFunction(_ModelFunctionBase, fn.AsyncMapFunction):
         out = out if out is not None else self._out
         self._dispatch_buf()
         if self.runner is not None and out is not None:
-            for record in self.runner.flush():
-                out.collect(record)
+            self._emit(self.runner.collect_batches(0), out)
 
     # -- latency bound in a lull (MapOperator timer hooks) ---------------
     # Same poll-don't-block discipline as the windowed path: the idle
@@ -492,10 +523,18 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         self._ring_capacity = ring_capacity
         self._ring = None
         self._last_ingested: typing.Optional[TensorValue] = None
+        #: When the window now filling got its first record (None: no
+        #: window is filling), and the running sums as they stood then:
+        #: (emit + collect_wait, ring wait, park seconds since the window
+        #: before).
+        self._fill_t0: typing.Optional[float] = None
+        self._fill_marks = (0.0, 0.0, 0.0)
+        #: Seconds in the ring-full drain loop so far (emissions and
+        #: blocked collections: ``emit`` and ``collect_wait`` count them).
+        self._ring_wait_total_s = 0.0
 
     # -- ring lifecycle ----------------------------------------------------
-    def open(self, ctx) -> None:
-        super().open(ctx)
+    def _open_buffers(self) -> None:
         if self._use_ring is False:
             return
         method = self.runner.method
@@ -532,6 +571,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         dup = super().clone()
         dup._ring = None
         dup._last_ingested = None
+        dup._fill_t0 = None
         return dup
 
     def close(self) -> None:
@@ -544,27 +584,73 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     def ingest_element(self, value, out: fn.Collector):
         """Write one record into the ring at arrival; returns the buffer
         token, or None to buffer the value itself (ring off/full)."""
+        if self._fill_t0 is None:
+            self._begin_fill()
         if self._ring is None:
             return None
         tv = value if isinstance(value, TensorValue) else coerce(
             value, self.runner.method.input_schema)
-        while not self._ring.try_push(tv.fields):
-            # Ring full: completed-but-uncollected batches hold slots
-            # (releases are deferred to collection) — drain them first,
-            # then block for the oldest in-flight batch and retry.  No
-            # in-flight work at all means the buffered window alone
-            # exceeds capacity: list-buffer it.
-            drained = self.runner.collect_available()
-            for record in drained:
-                out.collect(record)
-            if drained:
-                continue
-            if not self.runner._pending:
-                return None
-            for record in self.runner.collect_ready(len(self.runner._pending) - 1):
-                out.collect(record)
+        if not self._ring.try_push(tv.fields) and not self._wait_for_slot(tv, out):
+            return None
         self._last_ingested = tv
         return _RingToken(tv.meta)
+
+    def _wait_for_slot(self, tv, out: fn.Collector) -> bool:
+        """Ring full: completed-but-uncollected batches hold slots
+        (releases are deferred to collection) — drain them first, then
+        block for the oldest in-flight batch and retry.  False when no
+        work is in flight at all: the buffered window alone exceeds
+        capacity, and the caller list-buffers the record."""
+        runner = self.runner
+        t0 = time.monotonic()
+        try:
+            while True:
+                drained = runner.collect_batches()
+                self._emit(drained, out)
+                if not drained:
+                    if not runner._pending:
+                        return False
+                    self._emit(runner.collect_batches(len(runner._pending) - 1), out)
+                if self._ring.try_push(tv.fields):
+                    return True
+        finally:
+            self._ring_wait_total_s += time.monotonic() - t0
+
+    # -- the window's spans (tracing/flight.py) ----------------------------
+    def _begin_fill(self) -> None:
+        """The first record of a window has arrived: one clock read a
+        window, none a record."""
+        parked = self._spans.park_s if self._spans is not None else 0.0
+        self._fill_marks = (self._emit_total_s + self.runner.collect_wait_total_s,
+                            self._ring_wait_total_s, parked)
+        self._fill_t0 = time.monotonic()
+
+    def _close_fill(self, now: float, records: int) -> None:
+        """``process_window`` is entered: close the ``fill`` span.  Its
+        self time — the fill less the emissions, blocked collections and
+        parks inside it — is the ingest of the window's records (source poll, chain, ring write), got with no
+        clock read per record."""
+        t0, self._fill_t0 = self._fill_t0, None
+        if t0 is None:
+            return
+        children0, ring0, park_before = self._fill_marks
+        children = self._emit_total_s + self.runner.collect_wait_total_s - children0
+        spans = self._spans
+        # Without a hook (flight ring and tracer both off) nobody counts
+        # the parks, and the ingest then includes them.
+        park_s, park_n, park_over = (
+            spans.take_parks() if spans is not None else (0.0, 0, 0.0))
+        park_s -= park_before  # the hook's sums run from the fill before
+        self_s = max(now - t0 - children - park_s, 0.0)
+        if self._metrics is not None:
+            self._metrics.timer("ingest_s").update(self_s)
+        if spans is not None:
+            spans.span(self._track, "fill", t0, now, {
+                "seq": self.runner._batch_seq + 1, "records": records,
+                "self_s": self_s, "park_s": park_s,
+                "ring_wait_s": self._ring_wait_total_s - ring0,
+                "park_n": park_n, "park_over_max_s": park_over,
+                "park_before_s": park_before})
 
     def materialize_tokens(self, elements):
         """Replace ring tokens with concrete TensorValues (copy-out) —
@@ -577,9 +663,9 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
                 self.runner._pending or self.runner.has_completed()):
             # flush() also runs the deferred ring releases of completed
             # batches, so the ring head is the buffer afterwards.
-            for record in self.runner.flush():
-                if self._out is not None:
-                    self._out.collect(record)
+            drained = self.runner.collect_batches(0)
+            if self._out is not None:
+                self._emit(drained, self._out)
         values = {}
         remaining = len(tokens)
         idx = 0
@@ -604,7 +690,9 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
 
     # -- firing ------------------------------------------------------------
     def process_window(self, key, window, elements, out: fn.Collector):
+        t_fire = time.monotonic()
         elements = list(elements)
+        self._close_fill(t_fire, len(elements))
         self._out = out
         tokens = all(isinstance(e, _RingToken) for e in elements) and bool(elements)
         if tokens and self._ring is not None:
@@ -618,9 +706,15 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             cap = policy.fixed_batch or policy.batch.sizes[-1]
             for i in range(0, len(elements), cap):
                 self.runner.dispatch(elements[i:i + cap])
-                for record in self.runner.collect_progress(self._max_in_flight):
-                    out.collect(record)
-        self._last_dispatch = time.monotonic()
+                self._emit(self.runner.collect_batches(self._max_in_flight), out)
+        self._last_dispatch = now = time.monotonic()
+        if self._spans is not None:
+            policy = self.runner.policy
+            cap = policy.fixed_batch or policy.batch.sizes[-1]
+            tail = len(elements) % cap
+            self._spans.span(self._track, "fire", t_fire, now, {
+                "seq": self.runner._batch_seq, "records": len(elements),
+                "padded": policy.batch_bucket(tail) - tail if tail else 0})
 
     def _fire_ring(self, tokens, out: fn.Collector):
         """Claim contiguous arena views per chunk and dispatch them —
@@ -650,8 +744,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
                 # both (their deferred on_done releases run FIFO at
                 # collection), making our claim the oldest.
                 if self.runner._pending or self.runner.has_completed():
-                    for record in self.runner.flush():
-                        out.collect(record)
+                    self._emit(self.runner.collect_batches(0), out)
                 arrays = {f: np.empty((b, *v.shape[1:]), v.dtype)
                           for f, v in views.items()}
                 filled = 0
@@ -672,8 +765,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             batch = Batch(arrays=arrays, valid=valid, lengths={},
                           metas=[t.meta for t in chunk])
             self.runner.dispatch_batch(batch, on_done=release)
-            for record in self.runner.collect_progress(self._max_in_flight):
-                out.collect(record)
+            self._emit(self.runner.collect_batches(self._max_in_flight), out)
 
     # Timer hooks (WindowOperator.next_deadline/fire_due): while batches
     # are in flight, poll every idle_flush_s and emit whatever is READY —
@@ -711,16 +803,14 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         self._last_poll = now
 
     def on_finish(self, out: fn.Collector):
-        for record in self.runner.flush():
-            out.collect(record)
+        self._emit(self.runner.collect_batches(0), out)
 
     def snapshot_state(self):
         # Barrier alignment: emit everything in flight BEFORE the snapshot
         # is taken — the emissions precede the forwarded barrier, keeping
         # the snapshot consistent with the downstream stream position.
         if self.runner is not None and getattr(self, "_out", None) is not None:
-            for record in self.runner.flush():
-                self._out.collect(record)
+            self._emit(self.runner.collect_batches(0), self._out)
         return None
 
 

@@ -995,6 +995,11 @@ class PagedDecodeStepRunner(DecodeStepRunner):
         return self.snapshot_block(slot, length)
 
 
+def _flat(batches) -> typing.List[TensorValue]:
+    """``[(seq, results)]`` as one list of results."""
+    return [r for _, results in batches for r in results]
+
+
 class _FetchError:
     """Completed-queue marker for a batch whose lane work or fetch
     failed; the exception re-raises on the collecting thread."""
@@ -1063,10 +1068,10 @@ class CompiledMethodRunner:
         #: touching lane futures.
         self._pending_t0: collections.deque = collections.deque()
         #: Batches the fetch thread has fully fetched+unbatched, waiting
-        #: for the subtask thread to collect: ``(results, on_done)`` or
-        #: a :class:`_FetchError`.  ``on_done`` (ring-slot release) runs
-        #: at COLLECTION, on the subtask thread — the TensorRing is
-        #: SPSC and claims happen there, so releases must too.
+        #: for the subtask thread to collect: ``(results, on_done, seq,
+        #: t_ready)`` or a :class:`_FetchError`.  ``on_done`` (ring-slot
+        #: release) runs at COLLECTION, on the subtask thread — the
+        #: TensorRing is SPSC and claims happen there, so releases must too.
         self._completed: collections.deque = collections.deque()
         self._lock = threading.Lock()
         #: Signals the fetch thread that ``_pending`` gained work.
@@ -1080,21 +1085,24 @@ class CompiledMethodRunner:
         #: subtask gate's ``wake()`` so emission doesn't wait out the
         #: poll interval.
         self.on_results_ready: typing.Optional[typing.Callable[[], None]] = None
+        #: Number of the newest dispatched batch: ``args["seq"]`` of every
+        #: span of that batch, across the subtask, lane and fetch threads.
         self._batch_seq = 0
-        #: Stamp per-record stage timestamps into result metadata
-        #: (``meta["__stages__"]``) — the open-loop bench's per-sample
-        #: latency decomposition (VERDICT r3 #1).  Off by default: the
-        #: stamps cost a dict per record on the hot path.
-        self.stamp_stages = False
+        #: Newest batch collected on the subtask thread (the ``seq`` of
+        #: the ``collect_wait`` span that ends with it).
+        self.collected_seq = 0
+        #: Seconds the collecting thread has spent blocked in
+        #: :meth:`collect_ready` (the ``collect_wait`` spans' sum).
+        self.collect_wait_total_s = 0.0
         #: EWMA of dispatch-call -> results-fetched seconds per batch.
         #: Fed to latency-budget triggers (AdaptiveLatencyTrigger
         #: reserves this much of the budget for service).
         self.service_ewma_s: typing.Optional[float] = None
-        #: Span tracer + track (from ctx at open): per-batch stage spans
-        #: lane_wait / h2d / compute / d2h — the decomposition the
-        #: latency-attribution profiler folds into its table.  None =
-        #: untraced (production no-op path).
-        self._tracer = None
+        #: Window-level span hook + track (from ctx at open): per-batch
+        #: spans lane_wait / enqueue / in_flight / unbatch /
+        #: handoff_wait / collect_wait (tracing/flight.py lists them).
+        #: None = flight ring and tracer both off (no-op path).
+        self._spans = None
         self._trace_track: typing.Optional[str] = None
         #: Roofline probe (metrics/roofline.py) when the executor wired
         #: a plane through ctx.roofline: each fetched batch's compute
@@ -1111,7 +1119,18 @@ class CompiledMethodRunner:
         self.device = device
         self._transfer = DeviceTransfer(device, self.wire_dtype)
         # Params to HBM once — the Session-owns-variables analogue.
-        self._params_on_device = jax.device_put(self.model.params, device)
+        t_params = time.monotonic()
+        # Blocked on, so that the span is the transfer and not its enqueue.
+        self._params_on_device = jax.block_until_ready(
+            jax.device_put(self.model.params, device))
+        spans = getattr(ctx, "spans", None)
+        if spans is not None:
+            # Track name computed only on the recorded path — bare test
+            # contexts carry metrics but no task identity.
+            self._spans = spans
+            self._trace_track = f"{ctx.task_name}.{ctx.subtask_index}"
+            spans.span(self._trace_track, "params_to_device", t_params,
+                       time.monotonic())
 
         method = self.method
         select = self.output_names
@@ -1183,11 +1202,6 @@ class CompiledMethodRunner:
             self._fetcher.start()
         if ctx is not None:
             self._metrics = ctx.metrics
-            self._tracer = getattr(ctx, "tracer", None)
-            if self._tracer is not None:
-                # Track name computed only on the traced path — bare
-                # test contexts carry metrics but no task identity.
-                self._trace_track = f"{ctx.task_name}.{ctx.subtask_index}"
             plane = getattr(ctx, "roofline", None)
             if plane is not None:
                 self._roofline = plane.probe(ctx.task_name,
@@ -1207,7 +1221,7 @@ class CompiledMethodRunner:
         # the service-time EWMA (a compile-contaminated estimate would
         # make the latency-budget trigger reserve seconds it never needs).
         metrics, self._metrics = self._metrics, None
-        tracer, self._tracer = self._tracer, None
+        spans, self._spans = self._spans, None
         t_warm = time.monotonic()
         if self._roofline is not None:
             # Compile events still log (trigger = "warmup"); throughput
@@ -1221,16 +1235,16 @@ class CompiledMethodRunner:
             if self._roofline is not None:
                 self._roofline.end_warmup()
             self._metrics = metrics
-            self._tracer = tracer
+            self._spans = spans
             self.service_ewma_s = None
-            if tracer is not None:
-                # One span for the whole warmup (per-stage spans are
+            if spans is not None:
+                # One span for the whole warmup (per-batch spans are
                 # suppressed above for the same reason as the metrics:
                 # compile time must not masquerade as steady-state
-                # h2d/compute cost).
-                tracer.span(self._trace_track, "jit_warmup_compile",
-                            t_warm, time.monotonic(),
-                            args={"batches": list(batch_sizes)})
+                # enqueue/in_flight cost).
+                spans.span(self._trace_track, "jit_warmup_compile",
+                           t_warm, time.monotonic(),
+                           {"batches": list(batch_sizes)})
 
     def close(self) -> None:
         # Drain dispatched work through the fetch thread before dropping
@@ -1297,7 +1311,8 @@ class CompiledMethodRunner:
             item = self._dispatch_work(records, t0, seq)
         self._enqueue(item, t0)
 
-    def dispatch_batch(self, batch: Batch, *, assemble_s: float = 0.0,
+    def dispatch_batch(self, batch: Batch, *,
+                       assemble_s: typing.Optional[float] = None,
                        on_done: typing.Optional[typing.Callable[[], None]] = None) -> None:
         """Transfer + launch a pre-assembled :class:`Batch` (zero-copy ring
         path: ``batch.arrays`` are views onto the ring arena).
@@ -1339,7 +1354,7 @@ class CompiledMethodRunner:
         return self._launch_batch(batch, t0, seq, time.monotonic() - t_a, None)
 
     def _launch_batch(self, batch: Batch, t0: float, seq: int,
-                      assemble_s: float, on_done):
+                      assemble_s: typing.Optional[float], on_done):
         """Transfer + launch; returns (batch, output futures, timings, on_done)."""
         import jax
 
@@ -1365,16 +1380,17 @@ class CompiledMethodRunner:
             t_c = time.monotonic()
         timings = {
             "t0": t0,
+            "seq": seq,
             "assemble_s": assemble_s,
-            # Where the host->device transfer blocks inside the jitted-call
-            # dispatch, this interval IS the transfer cost.
+            # ``device_put`` is asynchronous: this is the enqueue of the
+            # transfer and the launch, not the transfer.
             "dispatch_s": t_c - t_b,
             # Bytes that actually crossed (narrowed when wire_dtype set).
             "h2d_bytes": h2d_bytes,
             "wire_saved": wire_saved,
-            # Stage boundaries for the per-sample latency decomposition:
-            # t0 -> t_lane_start is lane-pool queueing, t_lane_start ->
-            # t_dispatched is assemble + h2d transfer + launch.
+            # Span boundaries: t0 -> t_lane_start is lane-pool queueing
+            # (and assembly on the list path), t_lane_start ->
+            # t_dispatched the enqueue of transfer and launch.
             "t_lane_start": t_b,
             "t_dispatched": t_c,
         }
@@ -1452,7 +1468,8 @@ class CompiledMethodRunner:
                       metas=dbatch.metas)
         timings = {
             "t0": t0,
-            "assemble_s": 0.0,
+            "seq": seq,
+            "assemble_s": None,
             "dispatch_s": t_c - t_b,
             "h2d_bytes": 0,
             "wire_saved": 0,
@@ -1502,10 +1519,8 @@ class CompiledMethodRunner:
         if isinstance(item, concurrent.futures.Future):
             item = item.result()  # re-raises lane-thread failures here
         # Stamped AFTER the lane future resolves: the fetch thread can
-        # reach this batch while its lane is still transferring, and that
-        # wait belongs to ready_wait (t_dispatched -> t_fetch_start),
-        # keeping the stage boundaries monotone and exactly tiling
-        # t0..t_done.
+        # reach this batch while its lane is still enqueueing, so the
+        # stamp never precedes t_dispatched.
         t_fetch_start = time.monotonic()
         batch, outputs, timings, on_done = item
         if self.emit_device_batches:
@@ -1514,6 +1529,7 @@ class CompiledMethodRunner:
         host = DeviceTransfer.fetch(outputs)  # blocks on this batch only
         t_done = time.monotonic()
         results = batch.unbatch(host)
+        t_unbatched = time.monotonic()
         dt = t_done - timings["t0"]
         # Per-batch service time (dispatch call -> results on host): the
         # latency-budget trigger reserves this out of its budget.
@@ -1521,64 +1537,20 @@ class CompiledMethodRunner:
             dt if self.service_ewma_s is None
             else 0.75 * self.service_ewma_s + 0.25 * dt
         )
-        tracer = self._tracer
-        if tracer is not None:
-            # Per-batch stage spans on this operator's track — the
-            # boundaries tile t0..t_done exactly (same cuts as the
-            # __stages__ stamps below): lane-pool queueing, assemble +
-            # host->device wire + jit launch, launch -> fetch reached
-            # (device compute, overlapped with earlier fetches), and the
-            # batch's own d2h round trip.  A batch fed by an upstream
-            # DeviceBatch records NO h2d span — the elision shows as an
-            # ``h2d.elided`` instant (the CI guard greps for exactly
-            # this shape: zero h2d spans between fused model ops).
-            track = self._trace_track
-            n = len(results)
-            tracer.span(track, "lane_wait", timings["t0"],
-                        timings["t_lane_start"], args={"batch": n})
-            if timings.get("h2d_elided"):
-                tracer.instant(track, "h2d.elided",
-                               ts=timings["t_lane_start"], args={"batch": n})
-            else:
-                tracer.span(track, "h2d", timings["t_lane_start"],
-                            timings["t_dispatched"],
-                            args={"bytes": timings["h2d_bytes"], "batch": n,
-                                  "assemble_s": round(timings["assemble_s"], 6)})
-            tracer.span(track, "compute", timings["t_dispatched"],
-                        t_fetch_start, args={"batch": n})
-            tracer.span(track, "d2h", t_fetch_start, t_done,
-                        args={"batch": n})
-        if self.stamp_stages:
-            stages = {
-                "t0": timings["t0"],
-                # lane_wait INCLUDES coerce+assemble on the dispatch()
-                # path (both run on the lane thread before launch);
-                # assemble_s is its sub-component, t_lane_start the
-                # boundary — so the stage intervals t0 -> t_lane_start ->
-                # t_dispatched -> t_fetch_start -> t_done tile the batch
-                # lifetime exactly (no overlap, no gap).
-                "lane_wait_s": timings["t_lane_start"] - timings["t0"],
-                "assemble_s": timings["assemble_s"],
-                "dispatch_s": timings["dispatch_s"],
-                "t_lane_start": timings["t_lane_start"],
-                "t_dispatched": timings["t_dispatched"],
-                "t_fetch_start": t_fetch_start,
-                "t_done": t_done,
-                "batch_n": len(results),
-            }
-            for r in results:
-                # Each result's meta dict is its own copy (unbatch
-                # rebuilds TensorValues) AND each gets its own copy of
-                # the stages dict — a consumer mutating one record's
-                # stamps must not mutate its batch-siblings' (VERDICT r4
-                # weak #5: the shared dict made the isolation claim a
-                # half-truth).
-                r.meta["__stages__"] = dict(stages)
+        if self._spans is not None:
+            self._batch_spans(timings, t_fetch_start, t_done, len(results))
+            self._spans.span(self._trace_track, "unbatch", t_done, t_unbatched,
+                             {"seq": timings["seq"], "records": len(results)})
         if self._metrics is not None:
             self._metrics.meter("records").mark(len(results))
             self._metrics.histogram("batch_latency_s").record(dt)
             self._metrics.histogram("record_latency_s").record(dt / max(1, len(results)))
-            self._metrics.histogram("assemble_s").record(timings["assemble_s"])
+            self._metrics.timer("unbatch_s").update(t_unbatched - t_done)
+            if timings["assemble_s"] is not None:
+                # The list path's stacking copy.  Ring views and device
+                # arrays were never assembled (None) and record nothing:
+                # their per-record cost is the ``fill`` span's.
+                self._metrics.histogram("assemble_s").record(timings["assemble_s"])
             self._metrics.histogram("dispatch_s").record(timings["dispatch_s"])
             self._metrics.counter("h2d_bytes").inc(timings["h2d_bytes"])
             if timings.get("wire_saved"):
@@ -1594,7 +1566,38 @@ class CompiledMethodRunner:
                 self.method.name, t_fetch_start - timings["t_dispatched"],
                 signature=f"b{batch.padded_size}",
                 h2d_bytes=timings["h2d_bytes"])
-        return results, on_done
+        return results, on_done, timings["seq"], time.monotonic()
+
+    def _batch_spans(self, timings, t_fetch_start: float, t_done: float,
+                     n: int) -> None:
+        """One batch's spans off the subtask thread, written by the fetch
+        thread from the stamps the lane left: the boundaries t0 ->
+        t_lane_start -> t_dispatched -> t_done tile the batch's service
+        time.  A batch fed by an upstream DeviceBatch records NO enqueue
+        span — the elision shows as an ``h2d.elided`` instant (the CI
+        guard greps for exactly this shape: zero transfers between fused
+        model ops)."""
+        spans, track, seq = self._spans, self._trace_track, timings["seq"]
+        if self._pool is not None:
+            # On the list path the lane assembles before it launches:
+            # ``assemble_s`` is a part of this span (None on the ring path).
+            spans.span(track, "lane_wait", timings["t0"],
+                       timings["t_lane_start"],
+                       {"seq": seq, "batch": n,
+                        "assemble_s": timings["assemble_s"]})
+        if timings.get("h2d_elided"):
+            spans.instant(track, "h2d.elided", timings["t_lane_start"],
+                          {"seq": seq, "batch": n})
+        else:
+            spans.span(track, "enqueue", timings["t_lane_start"],
+                       timings["t_dispatched"],
+                       {"seq": seq, "bytes": timings["h2d_bytes"], "batch": n})
+        # Launched .. results on the host.  Where the fetch thread stood
+        # when it reached the batch is an accident of its schedule, so it
+        # is a number here and no longer a cut between two spans.
+        spans.span(track, "in_flight", timings["t_dispatched"], t_done,
+                   {"seq": seq, "batch": n, "fetch_reached_s":
+                    round(t_fetch_start - timings["t_dispatched"], 6)})
 
     def _complete_device(self, batch, outputs, timings, on_done,
                          t_fetch_start: float):
@@ -1615,30 +1618,20 @@ class CompiledMethodRunner:
             dt if self.service_ewma_s is None
             else 0.75 * self.service_ewma_s + 0.25 * dt
         )
-        tracer = self._tracer
-        if tracer is not None:
-            track = self._trace_track
-            tracer.span(track, "lane_wait", timings["t0"],
-                        timings["t_lane_start"], args={"batch": n})
-            if timings.get("h2d_elided"):
-                tracer.instant(track, "h2d.elided",
-                               ts=timings["t_lane_start"], args={"batch": n})
-            else:
-                tracer.span(track, "h2d", timings["t_lane_start"],
-                            timings["t_dispatched"],
-                            args={"bytes": timings["h2d_bytes"], "batch": n,
-                                  "assemble_s": round(timings["assemble_s"], 6)})
-            # Compute runs to t_done (block_until_ready IS the barrier);
-            # the d2h.elided instant is what the attribution table and
-            # the CI guard read as "no fetch happened here".
-            tracer.span(track, "compute", timings["t_dispatched"],
-                        t_done, args={"batch": n})
-            tracer.instant(track, "d2h.elided", ts=t_done, args={"batch": n})
+        spans = self._spans
+        if spans is not None:
+            # In flight to t_done (block_until_ready IS the barrier); the
+            # d2h.elided instant is what the attribution table and the
+            # CI guard read as "no fetch happened here".
+            self._batch_spans(timings, t_fetch_start, t_done, n)
+            spans.instant(self._trace_track, "d2h.elided", t_done,
+                          {"seq": timings["seq"], "batch": n})
         if self._metrics is not None:
             self._metrics.meter("records").mark(n)
             self._metrics.histogram("batch_latency_s").record(dt)
             self._metrics.histogram("record_latency_s").record(dt / max(1, n))
-            self._metrics.histogram("assemble_s").record(timings["assemble_s"])
+            if timings["assemble_s"] is not None:  # as in _process_item
+                self._metrics.histogram("assemble_s").record(timings["assemble_s"])
             self._metrics.histogram("dispatch_s").record(timings["dispatch_s"])
             self._metrics.counter("h2d_bytes").inc(timings["h2d_bytes"])
             if timings.get("wire_saved"):
@@ -1655,18 +1648,26 @@ class CompiledMethodRunner:
                 signature=f"b{batch.padded_size}",
                 h2d_bytes=timings["h2d_bytes"])
         dbatch = DeviceBatch(outputs, batch.valid, batch.metas,
-                             tracer=tracer, track=self._trace_track)
-        return [dbatch], on_done
+                             tracer=spans, track=self._trace_track)
+        return [dbatch], on_done, timings["seq"], time.monotonic()
 
-    def _consume(self, entry) -> typing.List[TensorValue]:
+    def _consume(self, entry) -> typing.Tuple[int, typing.List[TensorValue]]:
         """Collect one completed entry on the calling (subtask) thread:
-        re-raise fetch-thread failures, run the deferred ring release."""
+        re-raise fetch-thread failures, run the deferred ring release.
+        Returns the batch's ``(seq, results)``."""
         if isinstance(entry, _FetchError):
             raise entry.exc
-        results, on_done = entry
+        results, on_done, seq, t_ready = entry
+        now = time.monotonic()
+        self.collected_seq = seq
+        if self._metrics is not None:
+            self._metrics.timer("handoff_wait_s").update(now - t_ready)
+        if self._spans is not None:
+            self._spans.span(self._trace_track, "handoff_wait", t_ready, now,
+                             {"seq": seq})
         if on_done is not None:
             on_done()
-        return results
+        return seq, results
 
     def has_completed(self) -> bool:
         """True when fetched results are waiting to be collected."""
@@ -1675,8 +1676,13 @@ class CompiledMethodRunner:
     def collect_ready(self, max_in_flight: int = 1) -> typing.List[TensorValue]:
         """Drain completed batches until <= ``max_in_flight`` remain in
         flight (dispatched but not yet fetched), blocking as needed."""
+        return _flat(self._collect_ready(max_in_flight))
+
+    def _collect_ready(self, max_in_flight: int) -> typing.List[tuple]:
+        """:meth:`collect_ready`, batch by batch: ``[(seq, results)]``."""
         max_in_flight = max(0, max_in_flight)
-        out: typing.List[TensorValue] = []
+        out: typing.List[tuple] = []
+        t_blocked = None  # start of the blocking stretch under way
         while True:
             entries: typing.List[typing.Any] = []
             with self._lock:
@@ -1684,16 +1690,33 @@ class CompiledMethodRunner:
                     entries.append(self._completed.popleft())
                 done = len(self._pending) <= max_in_flight
                 if not entries and not done:
+                    if t_blocked is None:
+                        t_blocked = time.monotonic()
                     self._done_cv.wait(timeout=0.2)
                     if (self._fetcher is None or not self._fetcher.is_alive()) \
                             and self._pending and not self._completed:
                         raise RuntimeError(
                             "fetch thread died with batches in flight")
                     continue
-            for e in entries:
-                out.extend(self._consume(e))
+                in_flight = len(self._pending)
+            now = time.monotonic() if t_blocked is not None else 0.0
+            out.extend(self._consume(e) for e in entries)
+            if t_blocked is not None:
+                self._note_collect_wait(t_blocked, now, in_flight)
+                t_blocked = None
             if done:
                 return out
+
+    def _note_collect_wait(self, t_blocked: float, now: float,
+                           in_flight: int) -> None:
+        """One blocking stretch of :meth:`collect_ready` has ended with
+        the results of batch ``collected_seq``."""
+        self.collect_wait_total_s += now - t_blocked
+        if self._metrics is not None:
+            self._metrics.timer("collect_wait_s").update(now - t_blocked)
+        if self._spans is not None:
+            self._spans.span(self._trace_track, "collect_wait", t_blocked, now,
+                             {"seq": self.collected_seq, "in_flight": in_flight})
 
     def collect_available(self) -> typing.List[TensorValue]:
         """Drain every batch the fetch thread has already completed —
@@ -1703,13 +1726,7 @@ class CompiledMethodRunner:
         whole device round trip (which turns the operator into a
         blocking M/D/1 server and queues every later window behind the
         wire — round 3's unexplained 536ms p50)."""
-        out: typing.List[TensorValue] = []
-        while True:
-            with self._lock:
-                if not self._completed:
-                    return out
-                entry = self._completed.popleft()
-            out.extend(self._consume(entry))
+        return _flat(self.collect_batches())
 
     def collect_progress(self, max_in_flight: int) -> typing.List[TensorValue]:
         """Opportunistic collection on the hot path: everything already
@@ -1717,8 +1734,24 @@ class CompiledMethodRunner:
         depth bound requires.  Keeps emission latency at one arrival
         interval instead of one pipeline drain without sacrificing the
         depth backpressure."""
-        out = self.collect_available()
-        out.extend(self.collect_ready(max_in_flight))
+        return _flat(self.collect_batches(max_in_flight))
+
+    def collect_batches(self, max_in_flight: typing.Optional[int] = None
+                        ) -> typing.List[tuple]:
+        """What the three calls above collect, batch by batch, as ``[(seq,
+        results)]`` in dispatch order: everything already ready
+        (:meth:`collect_available`), then, with ``max_in_flight``, blocking
+        down to that bound (:meth:`collect_progress`; 0 is a flush).  For a
+        caller that emits a batch at a time under the batch's ``seq``."""
+        out: typing.List[tuple] = []
+        while True:
+            with self._lock:
+                if not self._completed:
+                    break
+                entry = self._completed.popleft()
+            out.append(self._consume(entry))
+        if max_in_flight is not None:
+            out.extend(self._collect_ready(max_in_flight))
         return out
 
     def oldest_pending_age_s(self, now: typing.Optional[float] = None) -> typing.Optional[float]:

@@ -25,6 +25,7 @@ calls (SURVEY.md §7 hard part 5).  Snapshots are host-side numpy pytrees
 
 from __future__ import annotations
 
+import time
 import typing
 
 from flink_tensorflow_tpu.core import functions as fn
@@ -435,6 +436,10 @@ class DPTrainWindowFunction(fn.WindowFunction):
         self._step_no = 0
         self._policy = BucketPolicy(fixed_batch=global_batch)
         self.mesh = None
+        #: Window-level span hook + track (from ctx at open): a step's
+        #: ``assemble`` / ``h2d_enqueue`` / ``dispatch`` / ``drain_wait``.
+        self._spans = None
+        self._track: typing.Optional[str] = None
 
     def clone(self):
         import copy
@@ -462,6 +467,10 @@ class DPTrainWindowFunction(fn.WindowFunction):
         from flink_tensorflow_tpu.parallel.dp import init_train_state, make_dp_train_step
         from flink_tensorflow_tpu.parallel.mesh import replicate
 
+        t_open = time.monotonic()
+        self._spans = getattr(ctx, "spans", None)
+        if self._spans is not None:
+            self._track = f"{ctx.task_name}.{ctx.subtask_index}"
         if ctx.mesh is None:
             raise RuntimeError(
                 "DPTrainWindowFunction needs env.set_mesh(...) — the gang owns the mesh"
@@ -502,6 +511,7 @@ class DPTrainWindowFunction(fn.WindowFunction):
         optimizer = self.optimizer or optax.sgd(0.01)
         self.optimizer = optimizer
         self._step_fn = make_dp_train_step(self.model_def, optimizer, self.mesh)
+        t_init = time.monotonic()
         state = self._restored or init_train_state(
             self.model_def, optimizer, jax.random.key(self.seed)
         )
@@ -509,7 +519,16 @@ class DPTrainWindowFunction(fn.WindowFunction):
         # Concrete at open (fresh init or restored host snapshot);
         # later states are pipelined futures we must not sync on.
         self._step_no = int(state["step"])
-        self._state = replicate(self.mesh, state)
+        t_replicate = time.monotonic()
+        # Blocked on, so that the span is the transfer and not its enqueue.
+        self._state = jax.block_until_ready(replicate(self.mesh, state))
+        now = time.monotonic()
+        ctx.metrics.timer("open_s").update(now - t_open)
+        if self._spans is not None:
+            spans, track = self._spans, self._track
+            spans.span(track, "init_state", t_init, t_replicate)
+            spans.span(track, "replicate", t_replicate, now)
+            spans.span(track, "open", t_open, now)
 
     def process_window(self, key, window, elements, out: fn.Collector) -> None:
         import collections
@@ -517,13 +536,25 @@ class DPTrainWindowFunction(fn.WindowFunction):
         from flink_tensorflow_tpu.parallel.mesh import shard_batch
 
         self._out = out
+        t0 = time.monotonic()
         _, arrays = _train_batch_arrays(list(elements), self.train_schema, self._policy)
+        t1 = time.monotonic()
         batch = shard_batch(self.mesh, arrays)
+        t2 = time.monotonic()
         # Dispatch-and-go: the state chains asynchronously; metrics fetch
         # lags by pipeline_depth so the NEXT window's h2d transfer
         # overlaps this step's device compute.
         self._state, metrics = self._step_fn(self._state, batch)
+        t3 = time.monotonic()
         self._step_no += 1
+        # The host's work a step, against the step's device time.
+        self.ctx.metrics.timer("feed_s").update(t3 - t0)
+        if self._spans is not None:
+            spans, track = self._spans, self._track
+            args = {"step": self._step_no, "examples": len(elements)}
+            spans.span(track, "assemble", t0, t1, args)
+            spans.span(track, "h2d_enqueue", t1, t2, args)
+            spans.span(track, "dispatch", t2, t3, args)
         if self._pending is None:
             self._pending = collections.deque()
         self._pending.append((metrics, self._step_no, len(elements)))
@@ -534,7 +565,13 @@ class DPTrainWindowFunction(fn.WindowFunction):
 
         while self._pending and len(self._pending) > keep:
             metrics, step_no, n = self._pending.popleft()
+            t0 = time.monotonic()
             host = {k: np.asarray(v) for k, v in metrics.items()}
+            t1 = time.monotonic()
+            self.ctx.metrics.timer("drain_wait_s").update(t1 - t0)
+            if self._spans is not None:
+                self._spans.span(self._track, "drain_wait", t0, t1,
+                                 {"step": step_no, "examples": n})
             host["step"] = np.asarray(step_no, np.int64)
             out.collect(TensorValue(host))
             self.ctx.metrics.meter("train_records").mark(n)

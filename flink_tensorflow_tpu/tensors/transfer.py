@@ -15,12 +15,12 @@ asynchrony lives one layer up, in two places:
 
 - the model runner's dedicated **fetch thread** (functions/runner.py)
   pays that block off the subtask thread, so fetch overlaps the next
-  batch's assemble/h2d — the runner's ``d2h`` trace span marks exactly
-  where the block lands;
+  batch's assemble/h2d — the runner's ``in_flight`` span ends exactly
+  where the block returns;
 - :class:`DeviceBatch` makes the fetch **lazy**: a device-resident
   result defers the d2h until the first host-only consumer forces
   :meth:`DeviceBatch.materialize`, which fetches exactly once (and, when
-  traced, records the deferred ``d2h`` span at the point of the block).
+  recorded, leaves a ``materialize`` span at the point of the block).
 
 Wire narrowing: ``DeviceTransfer(wire_dtype=...)`` casts float fields to
 a compact dtype (bf16/f16) host-side before ``device_put``, halving the
@@ -202,9 +202,9 @@ class DeviceBatch:
     The first host-only consumer (sink, keyed shuffle, remote edge, any
     plain user function) hits the **lazy materialization boundary**:
     :meth:`materialize` forces the deferred d2h exactly once, caches the
-    per-record ``TensorValue``s, and (when traced) records the d2h span
-    at the point of the block — the elision the ``h2d``/``d2h`` trace
-    tracks must show.  The runtime's ``Output``/``ChainedOutput`` call
+    per-record ``TensorValue``s, and (when recorded) leaves a
+    ``materialize`` span at the point of the block — beside the
+    ``h2d.elided``/``d2h.elided`` instants of the hops that paid none.  The runtime's ``Output``/``ChainedOutput`` call
     it automatically, so user code never sees a ``DeviceBatch`` unless
     it asked to.
 
@@ -257,7 +257,7 @@ class DeviceBatch:
         """Force the deferred d2h (once) and return per-record values.
 
         This IS the host-only boundary: the fetch blocks HERE, on the
-        consumer's thread — the traced ``d2h`` span (args
+        consumer's thread — the ``materialize`` span (args
         ``deferred=true``) asserts exactly where that block lands.
         """
         if self._host is None:
@@ -268,8 +268,8 @@ class DeviceBatch:
             t1 = time.monotonic()
             if self._tracer is not None:
                 self._tracer.span(
-                    self._track, "d2h", t0, t1,
-                    args={"batch": self.num_records, "deferred": True})
+                    self._track, "materialize", t0, t1,
+                    {"batch": self.num_records, "deferred": True})
             records: typing.List[TensorValue] = []
             for i in range(self.padded_size):
                 if not self.valid[i]:

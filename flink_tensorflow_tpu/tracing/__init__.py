@@ -7,6 +7,17 @@ Enable with ``JobConfig(trace=True)`` (optionally ``trace_path=...``,
 captured pipeline under tracing and print the per-operator stage
 attribution table.  See ``tracer.py`` for the span model and
 ``attribution.py`` for the profiler.
+
+Two rates.  The ``Tracer`` is per record (sampled, opt-in).  The
+WINDOW-LEVEL spans of the model and train operators' hot paths — ``fill``,
+``fire``, ``enqueue``, ``in_flight``, ``unbatch``, ``handoff_wait``,
+``collect_wait``, ``emit``, ``park.overslept``, ``open``; a train step's
+``assemble``, ``h2d_enqueue``, ``dispatch``, ``drain_wait`` — are always
+on: one :class:`SpanHook` per subtask (``ctx.spans``) writes them, once
+a window, into the flight ring and, when tracing is on, into the tracer
+(``flight.py`` lists them with their cuts).  :func:`recorder_of` hands
+back the ring of the most recent job of a given name after the job has
+been released: ``recorder_of("job").events()``.
 """
 
 from flink_tensorflow_tpu.tracing.attribution import (
@@ -18,7 +29,9 @@ from flink_tensorflow_tpu.tracing.attribution import (
 from flink_tensorflow_tpu.tracing.clocksync import OffsetEstimator
 from flink_tensorflow_tpu.tracing.flight import (
     FlightRecorder,
+    SpanHook,
     load_flight_dump,
+    recorder_of,
 )
 from flink_tensorflow_tpu.tracing.stitch import (
     cross_process_traces,
@@ -38,6 +51,7 @@ __all__ = [
     "STAGES",
     "FlightRecorder",
     "OffsetEstimator",
+    "SpanHook",
     "TraceContext",
     "Tracer",
     "attribution",
@@ -51,4 +65,5 @@ __all__ = [
     "load_flight_dump",
     "merge_cohort_trace_files",
     "merge_cohort_traces",
+    "recorder_of",
 ]

@@ -5,21 +5,26 @@ Chrome trace file it exported), aggregate the stage spans per operator
 and report p50/p95/p99/total per stage.  The canonical stages tile a
 batch's end-to-end path:
 
-- ``queue``   — channel enqueue -> delivery at the downstream subtask
-- ``h2d``     — host assemble + host->device wire transfer + jit launch
-- ``compute`` — launch -> the fetch thread reaching the batch (device
-  compute, overlapped with earlier batches' fetches)
-- ``d2h``     — the batch's own device->host fetch round trip
-- ``serde``   — record encode/decode on remote edges
-- ``wire``    — socket send time on remote edges
+- ``queue``        — channel enqueue -> delivery at the downstream subtask
+- ``fill``         — a window's first record ingested -> the window fired
+- ``enqueue``      — ``device_put`` of the batch + jit launch (both
+  asynchronous: the enqueue, not the transfer)
+- ``in_flight``    — launch -> results on the host (transfer, queueing
+  behind the batch before, the program, the copy back)
+- ``unbatch``      — the fetch thread building the result records
+- ``handoff_wait`` — results fetched -> popped by the subtask thread
+- ``serde``        — record encode/decode on remote edges
+- ``wire``         — socket send time on remote edges
 
-Other spans (``process``, ``emit``, ``align``, ``snapshot``,
-``split.read``, ``lane_wait``, ...) are aggregated too and listed after
-the canonical block.  Device-resident elisions (``h2d.elided`` /
-``d2h.elided`` instants — batches whose transfer never happened because
-the chain kept them HBM-resident) appear as count-only rows, so a
-model->model chain's table shows ONE h2d and ONE d2h column of real
-spans plus the matching elision counts on the other side.  Pure
+Other spans (``process``, ``emit``, ``fire``, ``collect_wait``,
+``lane_wait``, ``align``, ``snapshot``, ``split.read``, ...) are
+aggregated too and listed after the canonical block.  Device-resident
+elisions (``h2d.elided`` / ``d2h.elided`` instants — batches whose
+transfer never happened because the chain kept them HBM-resident) appear
+as count-only rows, so a model->model chain's table shows ONE enqueue
+row of real spans on the first model, the deferred ``materialize`` where
+the host first needs the values, and the matching elision counts on the
+other side.  Pure
 functions over event tuples — unit-testable with synthetic data, no
 runtime required.
 """
@@ -29,7 +34,8 @@ from __future__ import annotations
 import typing
 
 #: Canonical stage order of the attribution table.
-STAGES = ("queue", "h2d", "compute", "d2h", "serde", "wire")
+STAGES = ("queue", "fill", "enqueue", "in_flight", "unbatch", "handoff_wait",
+          "serde", "wire")
 
 Row = typing.Dict[str, typing.Any]
 

@@ -11,7 +11,8 @@ per-operator latency-attribution table.
 Captures the pipeline's plan the same way the analyzer/inspector CLIs do
 (``analysis.capture``), executes it with ``trace=True``, writes the
 Chrome trace JSON (Perfetto-loadable), and prints p50/p95/p99 per stage
-(queue / h2d / compute / d2h / serde / wire) per operator plus one
+(queue / fill / enqueue / in_flight / unbatch / handoff_wait / serde /
+wire) per operator plus one
 machine-readable JSON line.  ``--cohort`` instead MERGES a distributed
 job's per-process trace files onto the process-0 clock (tracing/
 stitch.py) — one Perfetto timeline with per-process track groups and
@@ -96,7 +97,8 @@ def main(argv: typing.Optional[typing.Sequence[str]] = None) -> int:
         description="Span tracer: execute a pipeline with per-batch span "
                     "tracing, export a Perfetto-loadable Chrome trace, and "
                     "print the per-operator stage attribution table "
-                    "(queue / h2d / compute / d2h / serde / wire).",
+                    "(queue / fill / enqueue / in_flight / unbatch / "
+                    "handoff_wait / serde / wire).",
     )
     parser.add_argument("pipelines", nargs="*", metavar="pipeline.py",
                         help="pipeline script(s) defining main(argv)")

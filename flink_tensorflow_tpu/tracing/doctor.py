@@ -23,7 +23,8 @@ Each answers a different question; the doctor joins them:
   own blocked-emitting time, idleness;
 - **which stage dominates its latency** — the trace/flight events fold
   through the standard attribution table
-  (queue / h2d / compute / d2h / serde / wire) per operator;
+  (queue / fill / enqueue / in_flight / unbatch / handoff_wait / serde /
+  wire) per operator;
 - **what the supervisor did** — health transitions and autoscale
   decisions recorded on the flight ring, plus the decision file.
 

@@ -26,6 +26,41 @@ Disk writes only happen when a dump PATH is configured
 (``JobConfig.flight_path`` / ``FLINK_TPU_FLIGHT_PATH``); the in-memory
 ring itself always runs unless disabled (``flight_recorder=False`` /
 ``FLINK_TPU_FLIGHT=0`` — the zero-alloc off path, tier-1 guarded).
+
+**Window-level spans.**  The ring also holds the hot path's spans at
+WINDOW rate — never per record: one :class:`SpanHook` per subtask
+(``ctx.spans``) takes ``(track, name, t0, t1, args)`` on
+``time.monotonic()`` and appends the event here and, when tracing is
+on, to the :class:`~flink_tensorflow_tpu.tracing.tracer.Tracer`.  Every
+span of one batch carries the runner's batch number as ``args["seq"]``.
+On the model operator's track (``<task>.<subtask>``), by thread:
+
+- subtask thread: ``fill`` (first record of a window ingested ..
+  ``process_window`` entered; ``args``: ``records``, ``ring_wait_s`` in
+  the ring-full drain loop (emissions and blocked collections, counted
+  as those), ``park_s`` inside it and ``park_before_s`` between the fill
+  before and it, ``park_n``/``park_over_max_s`` over both, ``self_s`` =
+  the fill less its ``emit``/``collect_wait`` children and its parks: the
+  ingest), ``fire`` (``process_window`` entered .. returned),
+  ``collect_wait`` (each blocking stretch of ``collect_ready``),
+  ``emit`` (one fetched batch handed downstream), ``open`` with children
+  ``params_to_device`` and ``jit_warmup_compile``, and on the chain
+  head's track the instant ``park.overslept`` (a park that returned more
+  than 50 ms after the timeout it asked for);
+- lane thread: ``lane_wait`` (dispatch call .. lane picked it up, only
+  where a lane pool exists), ``enqueue`` (``device_put`` + jit launch);
+- fetch thread: ``in_flight`` (launched .. results on the host),
+  ``unbatch`` (results built), ``handoff_wait`` (results queued ..
+  popped by the subtask thread).
+
+The gang train operator records ``assemble``, ``h2d_enqueue``,
+``dispatch`` and ``drain_wait`` a step (``args["step"]``), and ``open``
+with children ``init_state`` and ``replicate``.
+
+**Post-mortem accessor.**  :func:`recorder_of` returns the ring of the
+most recent job of a given name in this process, after the job has been
+released — for a notebook, a test, a crash handler, or a benchmark
+reader that only ever gets ``job.metrics``.
 """
 
 from __future__ import annotations
@@ -53,6 +88,14 @@ def env_flight_path() -> typing.Optional[str]:
     return os.environ.get("FLINK_TPU_FLIGHT_PATH") or None
 
 
+#: Events the ring holds.  The window-level spans run near 50 events/s
+#: on the stream path (ten a window, five windows a second) and 80 on
+#: the train path (eight a step, ten steps a second); 16384 keeps the
+#: last 200-330 s, so a reader at the end of a minute's run still finds
+#: its first seconds (4096 held 50-80 s: too close).  At most ~6 MB.
+DEFAULT_CAPACITY = 16384
+
+
 class FlightRecorder:
     """Bounded ring of recent events + metric deltas.
 
@@ -64,7 +107,7 @@ class FlightRecorder:
     part a post-mortem needs.
     """
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self._ring: typing.Deque[tuple] = collections.deque(maxlen=capacity)
         self.capacity = capacity
         self._last_counts: typing.Dict[str, typing.Any] = {}
@@ -146,6 +189,89 @@ class FlightRecorder:
                 "flight-recorder dump to %s failed", path, exc_info=True)
             return None
         return path
+
+
+#: A park counts as overslept when it returns this long after the
+#: timeout it asked for.
+OVERSLEPT_S = 0.05
+
+
+class SpanHook:
+    """The one hook the hot path's window-level spans go through: one
+    per subtask, handed to every chained operator as ``ctx.spans``
+    (None when the flight ring and the tracer are both off — callers
+    guard with one ``is None`` test and build no ``args``).
+
+    Callers fire once a window, batch or step — never per record — with
+    two clock reads they already have.  The runtime's loops report each
+    park of the subtask thread through :meth:`park`; the sums wait here
+    for the ``fill`` span that closes next (:meth:`take_parks`)."""
+
+    __slots__ = ("_flight", "_tracer", "park_s", "park_n", "park_over_max_s")
+
+    def __init__(self, flight: typing.Optional[FlightRecorder],
+                 tracer: typing.Optional[typing.Any] = None):
+        self._flight = flight._ring if flight is not None else None
+        self._tracer = tracer
+        self.park_s = 0.0
+        self.park_n = 0
+        self.park_over_max_s = 0.0
+
+    def _write(self, ev: tuple) -> None:
+        if self._flight is not None:
+            self._flight.append(ev)
+        if self._tracer is not None:
+            self._tracer.record(ev)
+
+    def span(self, track: str, name: str, t0: float, t1: float,
+             args: typing.Optional[dict] = None) -> None:
+        self._write((track, name, "X", t0, t1 - t0, args))
+
+    def instant(self, track: str, name: str, ts: float,
+                args: typing.Optional[dict] = None) -> None:
+        self._write((track, name, "i", ts, 0.0, args))
+
+    def park(self, track: str, asked: typing.Optional[float], slept: float,
+             woken: bool, now: float) -> None:
+        """One park of the subtask thread: it asked to wait ``asked``
+        seconds (None = until signalled) and came back after ``slept``."""
+        self.park_s += slept
+        self.park_n += 1
+        if asked is not None and slept > asked + OVERSLEPT_S:
+            over = slept - asked
+            if over > self.park_over_max_s:
+                self.park_over_max_s = over
+            self.instant(track, "park.overslept", now, {
+                "asked_s": asked, "slept_s": slept, "woken": woken})
+
+    def take_parks(self) -> typing.Tuple[float, int, float]:
+        """(seconds, count, longest oversleep) of the parks since the
+        last call; clears them."""
+        out = (self.park_s, self.park_n, self.park_over_max_s)
+        self.park_s, self.park_n, self.park_over_max_s = 0.0, 0, 0.0
+        return out
+
+
+#: (job name, recorder) of the most recent job started in this process.
+_kept: typing.Optional[typing.Tuple[str, FlightRecorder]] = None
+
+
+def keep(job_name: str, recorder: typing.Optional[FlightRecorder]) -> None:
+    """Called by ``execute_async``: the newest job's ring replaces the
+    one kept (one job, bounded by the ring's capacity)."""
+    global _kept
+    _kept = (job_name, recorder) if recorder is not None else None
+
+
+def recorder_of(job_name: str) -> typing.Optional[FlightRecorder]:
+    """The flight ring of the most recent job in this process if it ran
+    under ``job_name`` (``env.execute_async(job_name)``), else None.  It
+    stays reachable after the job's handle, environment and executor
+    are released; ``.events()`` gives the ``(track, name, ph, t0, dur,
+    args)`` tuples on ``time.monotonic()``."""
+    if _kept is not None and _kept[0] == job_name:
+        return _kept[1]
+    return None
 
 
 def load_flight_dump(path: str) -> dict:

@@ -199,6 +199,11 @@ class Tracer:
                 self._rings.append(ring)
         return ring
 
+    def record(self, ev: tuple) -> None:
+        """Append a ready ``(track, name, ph, t0, dur, args)`` event (the
+        window-level span hook's entry, tracing/flight.py)."""
+        self._ring().append(ev)
+
     def span(self, track: str, name: str, t0: float, t1: float,
              args: typing.Optional[dict] = None) -> None:
         """Record a complete event [t0, t1) (monotonic seconds) on ``track``."""

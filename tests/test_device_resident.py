@@ -318,9 +318,10 @@ class TestChainedPipeline:
 
 class TestTracedElisionGuard:
     """Tier-1 CI guard (not slow): in a traced model->model smoke
-    pipeline, zero h2d/d2h spans between the two fused model ops —
-    exactly one h2d (first model) and one d2h (second model) per batch
-    end to end, with the elisions visible as instants."""
+    pipeline, zero transfers between the two fused model ops — exactly
+    one enqueue of inputs (first model) and one fetch built into records
+    (``unbatch``, second model) per batch end to end, with the elisions
+    visible as instants."""
 
     def test_exactly_one_h2d_and_one_d2h_per_batch(self):
         from flink_tensorflow_tpu.tracing.attribution import attribution
@@ -339,24 +340,27 @@ class TestTracedElisionGuard:
                        and e[2] == ph)
 
         batches = 3  # 12 records / micro_batch 4
-        # First model: h2d spans only; its d2h is ELIDED per batch.
-        assert count("m1", "h2d", "X") == batches
-        assert count("m1", "d2h", "X") == 0
+        # First model: enqueue spans only; its d2h is ELIDED per batch
+        # (in flight to block_until_ready, no records built here).
+        assert count("m1", "enqueue", "X") == batches
+        assert count("m1", "in_flight", "X") == batches
+        assert count("m1", "unbatch", "X") == 0
         assert count("m1", "d2h.elided", "i") == batches
-        # Second model: h2d ELIDED per batch; the one real d2h lands here.
-        assert count("m2", "h2d", "X") == 0
+        # Second model: h2d ELIDED per batch; the one real fetch lands here.
+        assert count("m2", "enqueue", "X") == 0
         assert count("m2", "h2d.elided", "i") == batches
-        assert count("m2", "d2h", "X") == batches
-        # The attribution table agrees: no h2d stage on m2, none d2h on m1.
+        assert count("m2", "unbatch", "X") == batches
+        # The attribution table agrees: no enqueue stage on m2, no
+        # unbatch on m1.
         table = attribution(events)
-        assert "h2d" not in table.get("m2", {})
-        assert "d2h" not in table.get("m1", {})
-        assert table["m1"]["h2d"]["count"] == batches
-        assert table["m2"]["d2h"]["count"] == batches
+        assert "enqueue" not in table.get("m2", {})
+        assert "unbatch" not in table.get("m1", {})
+        assert table["m1"]["enqueue"]["count"] == batches
+        assert table["m2"]["unbatch"]["count"] == batches
 
     def test_deferred_d2h_span_lands_at_boundary(self):
         """Satellite: the fetch-block's location is asserted by a span —
-        DeviceBatch.materialize records d2h(deferred=true) where the
+        DeviceBatch.materialize records materialize(deferred=true) where the
         block actually lands (the host boundary, not the model op)."""
         model = _res_model()
         env = StreamExecutionEnvironment(parallelism=1)
@@ -376,7 +380,7 @@ class TestTracedElisionGuard:
         assert len(out) == 8
         events = handle.executor.tracer.events()
         deferred = [e for e in events
-                    if e[1] == "d2h" and (e[5] or {}).get("deferred")]
+                    if e[1] == "materialize" and (e[5] or {}).get("deferred")]
         assert len(deferred) == 2  # one per batch, at materialization
 
 

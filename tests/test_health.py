@@ -572,9 +572,9 @@ class TestDoctor:
         "health": {"slow": 2.0, "job": 2.0},
     }
     EVENTS = [
-        ("slow.0", "compute", "X", 0.00, 0.040, None),
-        ("slow.0", "compute", "X", 0.10, 0.050, None),
-        ("slow.0", "h2d", "X", 0.05, 0.001, None),
+        ("slow.0", "in_flight", "X", 0.00, 0.040, None),
+        ("slow.0", "in_flight", "X", 0.10, 0.050, None),
+        ("slow.0", "enqueue", "X", 0.05, 0.001, None),
     ]
 
     def test_health_findings_rank_breach_first(self):
@@ -595,7 +595,7 @@ class TestDoctor:
         from flink_tensorflow_tpu.tracing.doctor import stage_dominance
 
         stages = stage_dominance(self.EVENTS)
-        assert stages["slow"]["stage"] == "compute"
+        assert stages["slow"]["stage"] == "in_flight"
         assert stages["slow"]["share"] > 0.9
 
     def test_diagnose_names_operator_stage_and_action(self):
@@ -609,7 +609,7 @@ class TestDoctor:
                           decision=decision, channel_capacity=8)
         head = report["findings"][0]
         assert "#1 bottleneck slow" in head
-        assert "dominant stage compute" in head
+        assert "dominant stage in_flight" in head
         assert any("scale_up 2 -> 3" in f for f in report["findings"])
 
     def test_diagnose_notes_missing_actuation_on_breach(self):

@@ -15,7 +15,7 @@ framework defects, each pinned here:
    collapse windows to batch-1 (whose per-call overhead sinks below
    offered rates and the queue collapses).
 3. Nothing attributed latency to stages.  The runner stamps per-record
-   stage timestamps (``meta["__stages__"]``) and the window operator
+   cuts as window-level spans (tracing/flight.py) and the window operator
    stamps arrival (``__arrive_ts__``) when the function opts in.
 """
 
@@ -41,6 +41,22 @@ def _lenet_runner(**kw):
     r.open(None)
     r.warmup([1, 2, 4, 8])
     return r
+
+
+def _with_spans(r, track="lenet.0"):
+    """Give a bare runner a span hook; returns the ring it writes to."""
+    from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
+
+    ring = FlightRecorder()
+    r._spans, r._trace_track = SpanHook(ring), track
+    return ring
+
+
+def _span(ring, name, seq=None):
+    """The one span ``name`` (of batch ``seq``) as (t0, t1, args)."""
+    (ev,) = [e for e in ring.events() if e[1] == name
+             and (seq is None or e[5]["seq"] == seq)]
+    return ev[3], ev[3] + ev[4], ev[5]
 
 
 def _recs(n):
@@ -115,25 +131,37 @@ class TestCollectAvailable:
         finally:
             r.close()
 
-    def test_stage_stamps_on_results(self):
+    def test_stage_spans_of_a_batch(self):
+        """The cuts the per-record stamps carried, read from the batch's
+        spans: lane_wait -> enqueue -> in_flight -> unbatch, one of each,
+        sharing the batch's seq."""
         r = _lenet_runner(dispatch_lanes=1)
-        r.stamp_stages = True
+        ring = _with_spans(r)
         try:
             out = r.run_batch(_recs(3))
-            for v in out:
-                st = v.meta["__stages__"]
-                assert st["batch_n"] == 3
-                assert st["lane_wait_s"] >= 0
-                assert st["t0"] + st["lane_wait_s"] <= st["t_dispatched"]
-                assert st["t_dispatched"] <= st["t_fetch_start"] <= st["t_done"]
+            assert len(out) == 3
+            seq = r._batch_seq
+            t0, t_lane_start, lane = _span(ring, "lane_wait", seq)
+            _, t_dispatched, enq = _span(ring, "enqueue", seq)
+            t_disp, t_done, fly = _span(ring, "in_flight", seq)
+            assert lane["batch"] == enq["batch"] == fly["batch"] == 3
+            assert t_lane_start - t0 >= 0
+            assert t0 <= t_lane_start <= t_dispatched + 1e-9
+            assert abs(t_disp - t_dispatched) < 1e-9
+            # Where the fetch thread reached the batch is a number now.
+            assert 0 <= fly["fetch_reached_s"] <= t_done - t_disp + 1e-6
+            _, _, unb = _span(ring, "unbatch", seq)
+            assert unb["records"] == 3
+            assert _span(ring, "handoff_wait", seq)[0] >= t_done
         finally:
             r.close()
 
-    def test_stamps_off_by_default(self):
+    def test_no_hook_records_nothing_and_meta_is_untouched(self):
         r = _lenet_runner(dispatch_lanes=1)
         try:
+            assert r._spans is None
             out = r.run_batch(_recs(1))
-            assert "__stages__" not in out[0].meta
+            assert out[0].meta == {"id": 0}
         finally:
             r.close()
 
@@ -194,8 +222,6 @@ class TestServiceReserve:
         from flink_tensorflow_tpu.core import functions as fn
 
         class Svc(fn.WindowFunction):
-            _stamp_stages = False
-
             def service_time_estimate(self):
                 return 0.123
 
@@ -211,53 +237,72 @@ class TestServiceReserve:
 
 
 class TestArrivalStamp:
-    def _driven_op(self, func):
+    """The arrival of a window's first record is the start of its
+    ``fill`` span; no record's metadata is stamped."""
+
+    def _driven_op(self, spans):
+        import jax
+
+        from flink_tensorflow_tpu.core import functions as fn
         from flink_tensorflow_tpu.core.operators import Output, WindowOperator
+        from flink_tensorflow_tpu.core.runtime_context import RuntimeContext
         from flink_tensorflow_tpu.core.state import KeyedStateStore
+        from flink_tensorflow_tpu.core.windows import CountTrigger
+        from flink_tensorflow_tpu.functions import ModelWindowFunction
+        from flink_tensorflow_tpu.metrics.registry import MetricRegistry
+        from flink_tensorflow_tpu.models import get_model_def
 
-        trig = AdaptiveLatencyTrigger(4, 5.0)
-        op = WindowOperator("w", func, trig)
-        op.setup(None, Output([(None, [])]), KeyedStateStore())
+        mdef = get_model_def("lenet", num_classes=10)
+        model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
+        func = ModelWindowFunction(model, policy=BucketPolicy(fixed_batch=4))
+        got = []
+        op = WindowOperator("w", func, CountTrigger(4))
+        state = KeyedStateStore()
+        ctx = RuntimeContext("w", 0, 1, state, MetricRegistry().group("w.0"))
+        ctx.spans = spans
+        op.setup(ctx, Output([(None, [])]), state)
         op.open()
-        return op
+        op._collector = fn.Collector(lambda value, ts: got.append(value))
+        return op, got
 
-    def test_stamps_when_function_opts_in(self):
+    def test_fill_span_starts_at_the_first_arrival(self):
         from flink_tensorflow_tpu.core import elements as el
-        from flink_tensorflow_tpu.core import functions as fn
+        from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
 
-        class Svc(fn.WindowFunction):
-            _stamp_stages = True
+        ring = FlightRecorder()
+        op, got = self._driven_op(SpanHook(ring))
+        try:
+            recs = _recs(4)
+            before = time.monotonic()
+            op.process_record(el.StreamRecord(recs[0]))
+            after = time.monotonic()
+            for r in recs[1:]:
+                op.process_record(el.StreamRecord(r))
+            fired = time.monotonic()
+            op.finish()
+            t0, t1, args = _span(ring, "fill")
+            assert before <= t0 <= after
+            assert after <= t1 <= fired
+            assert args["records"] == 4 and args["seq"] == _span(ring, "fire")[2]["seq"]
+            # The input record objects stay untouched — they may fan out to
+            # sibling operators or be retained by a sliding trigger.
+            assert [r.meta for r in recs] == [{"id": i} for i in range(4)]
+            assert [v.meta for v in got] == [{"id": i} for i in range(4)]
+        finally:
+            op.close()
 
-            def process_window(self, key, window, elements, out):
-                pass
-
-        op = self._driven_op(Svc())
-        tv = TensorValue({"x": np.zeros((1,), np.float32)}, {"id": 1})
-        before = time.monotonic()
-        op.process_record(el.StreamRecord(tv))
-        after = time.monotonic()
-        # The stamp lands on the BUFFERED copy; the input record object
-        # stays untouched — it may fan out to sibling operators or be
-        # retained by a sliding trigger (ADVICE r4).
-        assert "__arrive_ts__" not in tv.meta
-        (buf,) = op._buffers.values()
-        (stamped,) = buf.elements
-        assert before <= stamped.meta["__arrive_ts__"] <= after
-        assert stamped.meta["id"] == 1
-
-    def test_no_stamp_without_opt_in(self):
+    def test_no_stamp_and_no_span_without_a_hook(self):
         from flink_tensorflow_tpu.core import elements as el
-        from flink_tensorflow_tpu.core import functions as fn
 
-        class Svc(fn.WindowFunction):
-            def process_window(self, key, window, elements, out):
-                pass
-
-        op = self._driven_op(Svc())
-        tv = TensorValue({"x": np.zeros((1,), np.float32)}, {"id": 1})
-        op.process_record(el.StreamRecord(tv))
-        assert "__arrive_ts__" not in tv.meta
-
+        op, got = self._driven_op(None)
+        try:
+            for r in _recs(4):
+                op.process_record(el.StreamRecord(r))
+            op.finish()
+            assert [v.meta for v in got] == [{"id": i} for i in range(4)]
+            assert op.function._fill_t0 is None  # closed at the fire
+        finally:
+            op.close()
 
 class TestAsyncMapPolling:
     def test_partial_batch_dispatches_and_emits_via_poll(self):

@@ -20,7 +20,7 @@ on:
    to the collecting thread (the TensorRing is SPSC), wakes the
    subtask loop on completion (InputGate.wake), and a completion wake
    must NOT flush the async map's partial micro-batch.
-3. The per-batch ``__stages__`` stamp was ONE dict shared by every
+3. The per-batch stage stamp was ONE dict shared by every
    record of the batch (VERDICT r4 weak #5): mutating one record's
    stamps mutated its siblings'.
 4. MFU attribution (VERDICT r4 #3): the trace parser aggregates only
@@ -409,15 +409,25 @@ class TestBackgroundFetch:
         finally:
             r.close()
 
-    def test_stage_stamp_dict_not_shared_across_batch(self):
-        """VERDICT r4 weak #5: each record owns its stages dict."""
+    def test_stage_cuts_are_per_batch_and_records_share_nothing(self):
+        """VERDICT r4 weak #5, after the stamps went: a batch's cuts live
+        in its own spans (one ``args`` dict a span, none shared between
+        batches), and each record still owns its metadata."""
+        from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
+
         r = _lenet_runner(dispatch_lanes=1)
-        r.stamp_stages = True
+        ring = FlightRecorder()
+        r._spans, r._trace_track = SpanHook(ring), "lenet.0"
         try:
             out = r.run_batch(_recs(3))
-            out[0].meta["__stages__"]["t0"] = -1.0
-            assert out[1].meta["__stages__"]["t0"] != -1.0
-            assert out[2].meta["__stages__"]["t0"] != -1.0
+            out[0].meta["t0"] = -1.0
+            assert "t0" not in out[1].meta and "t0" not in out[2].meta
+            r.run_batch(_recs(2))
+            flights = [e[5] for e in ring.events() if e[1] == "in_flight"]
+            assert [a["batch"] for a in flights] == [3, 2]
+            assert flights[0]["seq"] + 1 == flights[1]["seq"]
+            args = [e[5] for e in ring.events() if e[5] is not None]
+            assert len({id(a) for a in args}) == len(args)
         finally:
             r.close()
 

@@ -88,7 +88,7 @@ class TestTracerUnit:
 
     def test_chrome_trace_round_trips_as_valid_json(self, tmp_path):
         tr = Tracer()
-        tr.span("op.0", "h2d", 1.0, 1.5, args={"bytes": 128})
+        tr.span("op.0", "enqueue", 1.0, 1.5, args={"bytes": 128})
         tr.instant("op.0", "barrier.inject", ts=1.2, args={"checkpoint": 1})
         path = tr.export(str(tmp_path / "t.json"))
         trace = json.loads(pathlib.Path(path).read_text())
@@ -99,7 +99,7 @@ class TestTracerUnit:
         threads = [e for e in evs if e["ph"] == "M" and e["name"] == "thread_name"]
         assert [t["args"]["name"] for t in threads] == ["op.0"]
         (x,) = [e for e in evs if e["ph"] == "X"]
-        assert x["name"] == "h2d" and abs(x["dur"] - 0.5e6) < 1.0
+        assert x["name"] == "enqueue" and abs(x["dur"] - 0.5e6) < 1.0
         (i,) = [e for e in evs if e["ph"] == "i"]
         assert i["name"] == "barrier.inject" and i["s"] == "t"
 
@@ -107,28 +107,28 @@ class TestTracerUnit:
         events = [
             ("lenet.0", "queue", "X", 0.0, 0.001, None),
             ("lenet.0", "queue", "X", 0.1, 0.003, None),
-            ("lenet.0", "h2d", "X", 0.2, 0.010, None),
-            ("lenet.0", "d2h", "X", 0.3, 0.020, None),
+            ("lenet.0", "enqueue", "X", 0.2, 0.010, None),
+            ("lenet.0", "in_flight", "X", 0.3, 0.020, None),
             ("checkpoint", "checkpoint", "X", 0.0, 1.0, None),  # job track: excluded
         ]
         attr = attribution(events)
         assert set(attr) == {"lenet"}
         assert attr["lenet"]["queue"]["count"] == 2
-        assert attr["lenet"]["h2d"]["p50_ms"] == 10.0
+        assert attr["lenet"]["enqueue"]["p50_ms"] == 10.0
         table = format_attribution_table(attr)
-        # Canonical stage order: queue before h2d before d2h.
+        # Canonical stage order: queue before enqueue before in_flight.
         lines = [ln.split()[1] for ln in table.splitlines()[2:]]
-        assert lines == ["queue", "h2d", "d2h"]
+        assert lines == ["queue", "enqueue", "in_flight"]
 
     def test_events_from_chrome_preserves_attribution(self, tmp_path):
         tr = Tracer()
-        tr.span("op.0", "compute", 5.0, 5.25)
+        tr.span("op.0", "in_flight", 5.0, 5.25)
         tr.span("op.0", "queue", 4.0, 4.5)
         path = tr.export(str(tmp_path / "t.json"))
         loaded = events_from_chrome(json.loads(pathlib.Path(path).read_text()))
         attr = attribution(loaded)
-        assert attr["op"]["compute"]["count"] == 1
-        assert abs(attr["op"]["compute"]["p50_ms"] - 250.0) < 1.0
+        assert attr["op"]["in_flight"]["count"] == 1
+        assert abs(attr["op"]["in_flight"]["p50_ms"] - 250.0) < 1.0
         assert abs(attr["op"]["queue"]["p50_ms"] - 500.0) < 1.0
 
 
