@@ -52,6 +52,16 @@ def metrics_of(manifest: dict, group: str, workload: str) -> list:
     return [m for m in manifest[group] if workload in m.get("workloads", [workload])]
 
 
+def unix_instant():
+    """``(time.time_ns(), time.monotonic())`` read together: the monotonic
+    clock is read on both sides of the Unix one, and of three readings the one
+    whose two sides lie closest is kept, at their middle (a thread can be put
+    off its core between two reads)."""
+    reads = [(time.monotonic(), time.time_ns(), time.monotonic()) for _ in range(3)]
+    before, unix_ns, after = min(reads, key=lambda r: r[2] - r[0])
+    return unix_ns, (before + after) / 2
+
+
 class Context:
     """What a job kind gets, and the few steps every kind takes the same way."""
 
@@ -133,6 +143,7 @@ class Context:
         # 467 MB trace, took 67 s to stop and ran at a tenth of its speed (PERF.md 6).
         options.host_tracer_level = 0
         options.enable_hlo_proto = False
+        unix_at = unix_instant()
         t_call = time.monotonic()
         jax.profiler.start_trace(out, profiler_options=options)
         t_on = time.monotonic()
@@ -146,8 +157,13 @@ class Context:
         size = os.path.getsize(files[0])
         self.traced = trace_reduce.load(files[0])
         self.traced.host_span = (t_on, t_off)
-        self.note(f"traced {t_off - t_on:.2f}s: start_trace {t_on - t_call:.2f}s, stop_trace "
-                  f"{t_stopped - t_off:.2f}s, xplane {size / 1e6:.1f} MB read in "
+        # What the span join chooses its pairing by: the xplane counts from the
+        # profile's start, which lies inside the start_trace call.
+        self.traced.start_call, self.traced.unix_at = (t_call, t_on), unix_at
+        began = self.traced.profile_start_host()
+        self.note(f"traced {t_off - t_on:.2f}s: start_trace {t_on - t_call:.3f}s"
+                  + ("" if began is None else f" (the profile began {(began - t_call) * 1e3:.3f} ms into it)")
+                  + f", stop_trace {t_stopped - t_off:.2f}s, xplane {size / 1e6:.1f} MB read in "
                   f"{time.monotonic() - t_stopped:.2f}s, {len(self.traced.rows)} events kept")
         shutil.rmtree(out, ignore_errors=True)
 

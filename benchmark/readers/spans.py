@@ -24,8 +24,11 @@ nothing: every ``what`` then returns None and the line leaves the metric out.
   span covers).
 
 The joined readings need ``module``, the pattern of the step's program, and
-raise when the two records cannot be put on one clock (``span_join.JoinError``):
-a traced run must not print a wrong share.
+of the harness's trace ``start_call`` (the two stamps around ``start_trace``)
+and ``profile_start_host()`` (the xplane's own start, where it has one): the
+join chooses its pairing by them.  They raise when the two records cannot be
+put on one clock (``span_join.JoinError``): a traced run must not print a
+wrong share.
 """
 
 from __future__ import annotations
@@ -59,14 +62,18 @@ def _joined(state, events, track, module):
         device = min(trace.module_events)
         batches = span_join.batches_inside(spans, *trace.host_span)
         runs = span_join.device_runs(trace.module_events[device], module)
-        joined = span_join.join(batches, runs, trace.host_span[0])
+        joined = span_join.join(batches, runs, trace.start_call, trace.profile_start_host())
         lo, hi = (ns / 1e9 for ns in trace.window_ns)
         gaps = span_join.idle_gaps(trace.device_events[device], trace.module_events[device], lo, hi)
         booked = span_join.book_gaps(gaps, spans, joined)
         parts = span_join.split_in_flight(joined)
+        read = joined["read_offset"]
         print(f"span join: {len(joined['pairs'])} batches of {len(runs)} runs, shift {joined['shift']}, "
-              f"profile began {(trace.host_span[0] + joined['offset']) * 1e3:.1f} ms before start_trace returned, "
-              f"offsets allowed over {(joined['bracket'][1] - joined['bracket'][0]) * 1e3:.3f} ms, "
+              f"profile began {(trace.host_span[0] + joined['offset']) * 1e3:.1f} ms before start_trace returned "
+              f"(the call took {(trace.start_call[1] - trace.start_call[0]) * 1e3:.1f} ms), "
+              + ("no start in the xplane, " if read is None else
+                 f"the offset read lies {(read - joined['offset']) * 1e3:.3f} ms past the min-filter's, ")
+              + f"offsets allowed over {(joined['bracket'][1] - joined['bracket'][0]) * 1e3:.3f} ms, "
               f"tight edge iqr {joined['tight_iqr_s'] * 1e3:.3f} ms; medians ms: "
               + ", ".join(f"{k} {statistics.median(v) * 1e3:.2f}" for k, v in parts.items())
               + f"; between-program idle {sum(b - a for a, b in gaps):.4f} s booked "

@@ -26,6 +26,13 @@ SLACK_S = 1e-3
 #: The widest the true pairing's tight edge (``end - done``) may spread
 #: between its quartiles.
 TIGHT_IQR_S = 3e-3
+#: How far a pairing's offset (the least ``done - end`` over its pairs) may lie
+#: from the offset read off the clocks and still be the pairing read.  The
+#: min-filter is short of the read offset by the quickest pair's copy back:
+#: 1.91-3.28 ms in 15 traced stream runs (PERF.md 6, PR 28).  Pairings lie one
+#: period apart, so this has to stay well under half the shortest period
+#: joined (162.6 ms, the Inception window's own run).
+CLOCK_TOL_S = 20e-3
 
 
 class JoinError(ValueError):
@@ -74,30 +81,48 @@ def edges(batches, runs):
     return tight, loose
 
 
-def join(batches, runs, trace_on: float, slack_s: float = SLACK_S) -> dict:
+def join(batches, runs, start_call, profile_start=None, slack_s: float = SLACK_S,
+         tol_s: float = CLOCK_TOL_S) -> dict:
     """Pair ``batches`` with ``runs`` by order and put them on one clock.
 
-    The xplane's clock starts with the profile (step 0 of PERF.md's PR 27), so
-    the offset cannot be read from Python and is estimated from the pairs: the
-    least ``done - end`` over them (a min-filter: a late wake-up only ever
-    adds to a pair's, so the least is the cleanest), which leaves every
-    ``dispatch_to_start`` long and every ``end_to_fetched`` short by the copy
-    back of the quickest pair, at most one d2h of the results.
-
     The trace may hold a run more at either edge (of a batch cut by the
-    span's edge), so every pairing ``batch[i] <-> run[i + shift]`` is tried.
-    Causality alone cannot choose: in a steady pipeline a pairing shifted by
-    one moves both edges by one period and still holds.  What tells them apart
-    is the tight edge: in the true pairing ``end - done`` is the same in every
-    pair to within a wake-up (0.1-0.2 ms between its quartiles on the chip),
-    in a shifted one it carries the jitter of the period (15-30 ms).  So the
-    pairing with the narrowest tight edge is taken, if it is narrow
-    (``TIGHT_IQR_S``) and the runner-up at least four times as wide; then
-    every pair is held to causality within ``slack_s``, and the profile to
-    have started before ``trace_on``, the host's stamp after ``start_trace``.
+    span's edge), so every pairing ``batch[i] <-> run[i + shift]`` is a
+    candidate.  Each has the offset it would cut with, the least ``done -
+    end`` over its pairs (a min-filter: a late wake-up only ever adds to a
+    pair's, so the least is the cleanest), and the bracket causality allows,
+    ``[max(end - done), min(start - dispatched)]``.  Neither chooses: in a
+    steady pipeline a pairing shifted by one moves both edges by one period
+    and still holds, and once the device sets the period (programs back to
+    back) a shifted pairing's tight edge is as narrow as the true one's.  The
+    brackets are as wide as the shortest ``dispatch_to_start``, which behind a
+    queue of programs is over a period, so they overlap too.
+
+    What chooses is the clock.  The xplane's zero is the profile's start,
+    which lies inside the ``start_trace`` call (40-76 us into it on the chip:
+    PERF.md 6, PR 28), and the candidates' offsets lie one period apart:
+
+    - ``profile_start``, the xplane's own ``profile_start_time`` put on the
+      host's clock (``trace_reduce.Trace.profile_start_host``), gives the
+      offset read, ``-profile_start``.  The one candidate whose offset lies
+      within ``tol_s`` of it is taken.
+    - Without it (a recorded table, an xplane without the stat) the one
+      candidate whose offset puts the profile's start inside ``start_call``,
+      the host's two stamps ``(t_call, t_on)`` around ``start_trace``, to
+      within ``tol_s``.  That decides only while the period is longer than
+      the call (45-184 ms measured).
+
+    None or more than one: :class:`JoinError` with every candidate's numbers.
+    The read clock chooses and does not cut: the offset returned is the
+    chosen pairing's min-filter, which leaves every ``dispatch_to_start``
+    long and every ``end_to_fetched`` short by the copy back of the quickest
+    pair, at most one d2h of the results.  The chosen pairing is then held to
+    a narrow tight edge (``TIGHT_IQR_S`` between its quartiles: in a true
+    pairing ``end - done`` is the same in every pair to within a wake-up) and
+    every pair to causality within ``slack_s``.
 
     Returns ``{"offset", "shift", "pairs": [(batch, (start, end))], "bracket",
-    "tight_iqr_s"}``.  Raises :class:`JoinError` with the numbers otherwise.
+    "tight_iqr_s", "read_offset"}`` (``read_offset`` None without
+    ``profile_start``).  Raises :class:`JoinError` with the numbers otherwise.
     """
     if len(batches) < 4:
         raise JoinError(f"{len(batches)} whole batches inside the traced span: too few to pair")
@@ -105,29 +130,42 @@ def join(batches, runs, trace_on: float, slack_s: float = SLACK_S) -> dict:
     if spare < 0:
         raise JoinError(f"{len(batches)} whole batches in the traced span but only "
                         f"{len(runs)} runs of the step's program in the trace")
+    t_call, t_on = start_call
+    if profile_start is None:
+        want_lo, want_hi = -t_on, -t_call
+        want = (f"a profile started inside start_trace: offset in [{want_lo:.6f}, {want_hi:.6f}] "
+                f"(the call took {(t_on - t_call) * 1e3:.1f} ms)")
+    elif t_call - tol_s <= profile_start <= t_on + tol_s:
+        want_lo = want_hi = -profile_start
+        want = f"the offset read off the clocks, {want_lo:.6f}"
+    else:
+        raise JoinError(f"the profile's start as read, {profile_start:.6f} on the host's clock, lies outside "
+                        f"the start_trace call [{t_call:.6f}, {t_on:.6f}]: the clocks were not read together")
     tried = []
     for shift in range(spare + 1):
         tight, loose = edges(batches, runs[shift:])
         q1, _, q3 = statistics.quantiles(tight, n=4)
-        tried.append((q3 - q1, shift, max(tight), min(loose)))
-    tried.sort()
-    told = ", ".join(f"shift {s}: tight edge iqr {iqr * 1e3:.3f} ms, offsets allowed "
-                     f"[{lo:.6f}, {hi:.6f}]" for iqr, s, lo, hi in tried)
-    iqr, shift, lo, hi = tried[0]
-    if iqr > TIGHT_IQR_S or (len(tried) > 1 and tried[1][0] < 4 * iqr):
-        raise JoinError(f"cannot tell which run served which batch ({len(batches)} batches, "
-                        f"{len(runs)} runs): {told}")
+        tried.append((shift, max(tight), min(loose), q3 - q1))
+    told = (f"{len(batches)} batches, {len(runs)} runs; wanted {want} to within {tol_s * 1e3:.1f} ms; "
+            + ", ".join(f"shift {s}: offset {lo:.6f}, allowed up to {hi:.6f}, tight edge iqr "
+                        f"{iqr * 1e3:.3f} ms" for s, lo, hi, iqr in tried))
+    fit = [t for t in tried if want_lo - tol_s <= t[1] <= want_hi + tol_s]
+    if len(fit) != 1:
+        raise JoinError(f"cannot tell which run served which batch: {len(fit)} pairings fit the clock "
+                        f"({told})")
+    shift, lo, hi, iqr = fit[0]
+    if iqr > TIGHT_IQR_S:
+        raise JoinError(f"the pairing the clock chose, shift {shift}, is not a steady one: its tight "
+                        f"edge spreads {iqr * 1e3:.3f} ms between its quartiles ({told})")
     if lo > hi + slack_s:
-        tight, loose = edges(batches, runs[shift:])
+        _, loose = edges(batches, runs[shift:])
         worst = max(range(len(batches)), key=lambda i: lo - loose[i])
         raise JoinError(
             f"batch seq {batches[worst]['seq']} breaks causality by {(lo - loose[worst]) * 1e3:.3f} ms: "
             f"its program started {(loose[worst] - lo) * 1e3:.3f} ms after its dispatch on the joined "
-            f"clock; {told}")
-    if lo < -trace_on - slack_s:
-        raise JoinError(f"the joined clock puts the profile's start {(-lo - trace_on) * 1e3:.3f} ms "
-                        f"after start_trace had returned; {told}")
+            f"clock ({told})")
     return {"offset": lo, "shift": shift, "bracket": (lo, hi), "tight_iqr_s": iqr,
+            "read_offset": None if profile_start is None else -profile_start,
             "pairs": list(zip(batches, runs[shift:shift + len(batches)]))}
 
 

@@ -19,29 +19,35 @@ import re
 
 DEVICE_PLANE = re.compile(r"/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+ENVIRONMENT_PLANE, START_STAT = "Task Environment", "profile_start_time"
 COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute")
 
 
-def read_xplane(path: str) -> list:
-    """Every event of the device planes' op and module lines."""
+def read_xplane(path: str):
+    """``(rows, profile_start_unix_ns)``: every event of the device planes' op
+    and module lines, and the instant the events' ``start_ns`` count from, on
+    the Unix clock: the stat ``profile_start_time`` of the plane ``Task
+    Environment``, None where the xplane has none."""
     from jax.profiler import ProfileData
 
-    rows = []
+    rows, profile_start = [], None
     for plane in ProfileData.from_file(path).planes:
+        if plane.name == ENVIRONMENT_PLANE:
+            profile_start = next((int(v) for k, v in plane.stats if k == START_STAT), None)
         if not DEVICE_PLANE.search(plane.name):
             continue
         for line in plane.lines:
             if line.name in (OPS_LINE, MODULES_LINE):
                 rows.extend((plane.name, line.name, ev.name, int(ev.start_ns), int(ev.duration_ns))
                             for ev in line.events)
-    return rows
+    return rows, profile_start
 
 
 def load(path: str) -> "Trace":
     if path.endswith(".json.gz"):
         with gzip.open(path, "rt") as f:
             return Trace([tuple(r) for r in json.load(f)])
-    return Trace(read_xplane(path))
+    return Trace(*read_xplane(path))
 
 
 def union_ns(intervals) -> int:
@@ -65,8 +71,9 @@ def short_op(name: str) -> str:
 
 
 class Trace:
-    def __init__(self, rows):
+    def __init__(self, rows, profile_start_unix_ns=None):
         self.rows = rows
+        self.profile_start_unix_ns = profile_start_unix_ns
         self.device_events = {}  # device id -> [(name, start, end)] of ops
         self.module_events = {}  # device id -> [(name, start, end)] of programs
         for plane, line, name, start, dur in rows:
@@ -79,11 +86,23 @@ class Trace:
             raise ValueError("the trace holds no device op: nothing ran on the chip while it was on")
         every = [e for evs in self.device_events.values() for e in evs]
         self.window_ns = (min(e[1] for e in every), max(e[2] for e in every))
-        self.host_span = None  # (on, off) on the host's monotonic clock, set by the harness
+        # Set by the harness, on the host's monotonic clock: the span the
+        # profiler was on, (on, off); the start_trace call's two stamps,
+        # (t_call, t_on); one Unix instant in ns and the monotonic instant it
+        # was read at.
+        self.host_span = self.start_call = self.unix_at = None
 
     @property
     def window_s(self) -> float:
         return (self.window_ns[1] - self.window_ns[0]) / 1e9
+
+    def profile_start_host(self):
+        """The instant the events count from, on the host's monotonic clock;
+        None where the xplane did not say or no Unix instant was kept."""
+        if self.profile_start_unix_ns is None or self.unix_at is None:
+            return None
+        unix_ns, monotonic = self.unix_at
+        return monotonic + (self.profile_start_unix_ns - unix_ns) / 1e9
 
     def busy_by_device(self) -> dict:
         return {d: union_ns((s, e) for _, s, e in evs) / 1e9

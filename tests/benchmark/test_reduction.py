@@ -94,3 +94,33 @@ def test_a_device_kind_without_peaks_raises():
     assert trace_reduce.peaks_for(ROOT, "TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError):
         trace_reduce.peaks_for(ROOT, "cpu")
+
+
+def test_the_profiles_start_is_read_from_an_xplane_and_put_on_the_hosts_clock(tmp_path):
+    import glob
+    import time
+
+    import jax
+
+    from benchmark import harness
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = options.host_tracer_level = 0
+    unix_ns, monotonic = unix_at = harness.unix_instant()
+    again = harness.unix_instant()
+    assert (again[0] - unix_ns) / 1e9 == pytest.approx(again[1] - monotonic, abs=1e-3)
+    t_call = time.monotonic()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    t_on = time.monotonic()
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    rows, start_ns = trace_reduce.read_xplane(path)
+    assert rows == []  # no TPU here: no device plane, but the profile says when it began
+    trace = trace_reduce.Trace(_table(), start_ns)
+    assert trace.profile_start_host() is None  # no Unix instant kept beside it
+    trace.unix_at = unix_at
+    assert t_call - 1e-3 <= trace.profile_start_host() <= t_on + 1e-3
+    # A recorded table, or an xplane without the stat, has no start to read.
+    trace = trace_reduce.load(RECORDED)
+    trace.unix_at = unix_at
+    assert trace.profile_start_unix_ns is None and trace.profile_start_host() is None
