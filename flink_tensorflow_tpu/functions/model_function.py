@@ -476,15 +476,26 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     Windows larger than the policy's biggest bucket are chunked into
     multiple calls rather than failing batch assembly.
 
-    Dispatch is pipelined (``pipeline_depth`` batches in flight): while
-    the device runs window k, the host batches and ships window k+1 —
-    the host->device transfer hides under compute.  ``transfer_lanes > 1``
-    additionally overlaps the transfers of in-flight batches on a thread pool
-    (the lever when single-stream transfer bandwidth is the ceiling);
-    ``pipeline_depth`` defaults to ``2 * transfer_lanes`` so the lanes
-    stay fed.  In-flight batches are flushed at end of input and before
-    every state snapshot, so barriers never have results in limbo
-    (exactly-once, SURVEY.md §7 hard part 5).
+    Dispatch is pipelined (``pipeline_depth`` windows in flight).  A
+    window passes three stages that can overlap: it crosses the link
+    (host->device transfer), it runs on the device, and its results are
+    fetched and emitted while the next window fills.  After dispatching
+    window k a fire waits only until at most ``pipeline_depth - 1``
+    windows are still unfetched, so with the default of three, k-1 runs
+    on the device while k crosses the link and k+1 fills: the transfer
+    hides under compute and the device, not the host's chain, sets the
+    period.  ``pipeline_depth=2`` keeps one window fewer in flight (a
+    fire then waits for window k-1, and the transfer is exposed wherever
+    it is not short against the step).  ``transfer_lanes > 1``
+    additionally overlaps the transfers of in-flight batches on a thread
+    pool (the lever when single-stream transfer bandwidth is the
+    ceiling); ``pipeline_depth`` defaults to ``max(3, 2 *
+    transfer_lanes)`` so the lanes stay fed.  The cost of each window in
+    flight is one window of inputs on the chip and one in the host's
+    arena (275 MB each for 1024 Inception-v3 images).  In-flight batches
+    are flushed at end of input and before every state snapshot, so
+    barriers never have results in limbo (exactly-once, SURVEY.md §7
+    hard part 5).
 
     **Zero-copy ring buffering** (``use_ring``): with a static input
     schema and a ``fixed_batch`` policy, arriving records are written
@@ -493,8 +504,12 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     claims ``[B, ...]`` numpy views onto the arena that feed
     ``jax.device_put`` directly — no stacking copy on the steady-state
     path (BASELINE.json "zero-copy Row<->DeviceArray marshalling").
-    Slots recycle when the batch's results are fetched, so the arena is
-    sized ``(pipeline_depth + 2) * fixed_batch`` slots.  Default: auto
+    Slots recycle when the batch's results are collected, so the arena
+    is sized ``(pipeline_depth + 2) * fixed_batch`` slots (five windows
+    at the default depth): the windows in flight, the one filling, and
+    room for results fetched and not yet collected.  The ring rounds
+    that up to a power of two (8 windows of 1024 for 5), so where the
+    batch is a power of two no batch splits at the arena's end.  Default: auto
     (on when eligible); pass ``use_ring=False`` to force the list path.
     """
 
@@ -512,10 +527,13 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
                  ring_capacity: typing.Optional[int] = None, **kw):
         super().__init__(model, method, **kw)
         if pipeline_depth is None:
-            pipeline_depth = 2 * self._transfer_lanes
+            pipeline_depth = max(3, 2 * self._transfer_lanes)
         if pipeline_depth < 1:
             raise ValueError("pipeline_depth must be >= 1")
         self._max_in_flight = pipeline_depth - 1
+        #: Most windows in flight right after a dispatch of the fire under
+        #: way, before it collected (the ``fire`` span's ``in_flight``).
+        self._fire_in_flight = 0
         self._idle_flush_s = idle_flush_s
         self._last_dispatch: typing.Optional[float] = None
         self._last_poll: typing.Optional[float] = None
@@ -566,6 +584,10 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             from flink_tensorflow_tpu.native.ring import TensorRing
 
             self._ring = TensorRing(schema, self._ring_capacity)
+            # Page the whole arena in here, not as the first windows fill:
+            # the ring rounds its capacity up to a power of two, so it can
+            # hold more windows than a job's warm-up passes through it.
+            self._ring.prefault()
 
     def clone(self) -> "fn.Function":
         dup = super().clone()
@@ -573,6 +595,16 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         dup._last_ingested = None
         dup._fill_t0 = None
         return dup
+
+    def open(self, ctx) -> None:
+        super().open(ctx)
+        if self._metrics is not None:
+            self._metrics.gauge("windows_in_flight", self._windows_in_flight)
+
+    def _windows_in_flight(self) -> int:
+        """Windows dispatched and not yet fetched (what ``pipeline_depth``
+        bounds); 0 once closed."""
+        return len(self.runner._pending) if self.runner is not None else 0
 
     def close(self) -> None:
         super().close()
@@ -694,6 +726,8 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         elements = list(elements)
         self._close_fill(t_fire, len(elements))
         self._out = out
+        self._fire_in_flight = 0
+        blocked0 = self.runner.collect_wait_total_s
         tokens = all(isinstance(e, _RingToken) for e in elements) and bool(elements)
         if tokens and self._ring is not None:
             self._fire_ring(elements, out)
@@ -706,7 +740,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             cap = policy.fixed_batch or policy.batch.sizes[-1]
             for i in range(0, len(elements), cap):
                 self.runner.dispatch(elements[i:i + cap])
-                self._emit(self.runner.collect_batches(self._max_in_flight), out)
+                self._hold_depth(out)
         self._last_dispatch = now = time.monotonic()
         if self._spans is not None:
             policy = self.runner.policy
@@ -714,7 +748,16 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             tail = len(elements) % cap
             self._spans.span(self._track, "fire", t_fire, now, {
                 "seq": self.runner._batch_seq, "records": len(elements),
-                "padded": policy.batch_bucket(tail) - tail if tail else 0})
+                "padded": policy.batch_bucket(tail) - tail if tail else 0,
+                "in_flight": self._fire_in_flight,
+                "blocked_s": self.runner.collect_wait_total_s - blocked0})
+
+    def _hold_depth(self, out: fn.Collector) -> None:
+        """A batch has just been dispatched: note how many are in flight,
+        then emit what is ready and block until at most ``pipeline_depth
+        - 1`` are still unfetched."""
+        self._fire_in_flight = max(self._fire_in_flight, len(self.runner._pending))
+        self._emit(self.runner.collect_batches(self._max_in_flight), out)
 
     def _fire_ring(self, tokens, out: fn.Collector):
         """Claim contiguous arena views per chunk and dispatch them —
@@ -765,7 +808,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             batch = Batch(arrays=arrays, valid=valid, lengths={},
                           metas=[t.meta for t in chunk])
             self.runner.dispatch_batch(batch, on_done=release)
-            self._emit(self.runner.collect_batches(self._max_in_flight), out)
+            self._hold_depth(out)
 
     # Timer hooks (WindowOperator.next_deadline/fire_due): while batches
     # are in flight, poll every idle_flush_s and emit whatever is READY —

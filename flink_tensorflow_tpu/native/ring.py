@@ -450,6 +450,16 @@ class TensorRing:
         self._claim_ahead = 0
         self._claim_idx = 0
 
+    def prefault(self) -> None:
+        """Touch every page of the (still empty) arena.  The allocation is
+        lazy: otherwise the first write into each fresh slot maps its pages
+        on the producer's path, and the first trip around the ring is slow
+        (0.19 s more to fill a 275 MB window on a v5e's host, where touching
+        2.2 GB takes 2.1 s: PERF.md 6, PR 29)."""
+        arena = self._ring.arena_view().reshape(-1)
+        arena[::mmap.PAGESIZE] = 0
+        arena[-1] = 0
+
     # -- producer ----------------------------------------------------------
     def try_push(self, record: typing.Mapping[str, np.ndarray]) -> bool:
         """Write one record into the ring; False if full (caller backs off).

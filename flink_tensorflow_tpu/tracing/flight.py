@@ -41,7 +41,13 @@ On the model operator's track (``<task>.<subtask>``), by thread:
   as those), ``park_s`` inside it and ``park_before_s`` between the fill
   before and it, ``park_n``/``park_over_max_s`` over both, ``self_s`` =
   the fill less its ``emit``/``collect_wait`` children and its parks: the
-  ingest), ``fire`` (``process_window`` entered .. returned),
+  ingest), ``fire`` (``process_window`` entered .. returned; ``args``:
+  ``records``, ``padded``, ``in_flight`` = windows dispatched and not
+  yet fetched right after this fire's dispatch, before it collects: it
+  reaches ``pipeline_depth`` on a backlog and never passes it; and
+  ``blocked_s`` = seconds of this fire inside ``collect_wait``.  The
+  operator's metric group has the same level as the gauge
+  ``windows_in_flight``, read when a report is taken),
   ``collect_wait`` (each blocking stretch of ``collect_ready``),
   ``emit`` (one fetched batch handed downstream), ``open`` with children
   ``params_to_device`` and ``jit_warmup_compile``, and on the chain

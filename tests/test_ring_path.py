@@ -43,6 +43,14 @@ def expected_labels(lenet_model, images):
     return {i: int(x) for i, x in enumerate(np.asarray(out["label"]))}
 
 
+def _ctx():
+    from flink_tensorflow_tpu.core.runtime_context import RuntimeContext
+    from flink_tensorflow_tpu.core.state import KeyedStateStore
+    from flink_tensorflow_tpu.metrics.registry import MetricRegistry
+
+    return RuntimeContext("t", 0, 1, KeyedStateStore(), MetricRegistry().group("t.0"))
+
+
 def _run(fn_kwargs, images, window=B, timeout_s=None, parallelism=1):
     env = StreamExecutionEnvironment(parallelism=parallelism)
     stream = env.from_collection(images)
@@ -81,13 +89,7 @@ class TestRingWindowPath:
         """White-box: ingest_element returns tokens once opened with a
         fixed-batch policy (guards against the ring silently not wiring)."""
         f = ModelWindowFunction(lenet_model, policy=BucketPolicy(fixed_batch=B))
-        from flink_tensorflow_tpu.core.runtime_context import RuntimeContext
-        from flink_tensorflow_tpu.core.state import KeyedStateStore
-        from flink_tensorflow_tpu.metrics.registry import MetricRegistry
-
-        reg = MetricRegistry()
-        ctx = RuntimeContext("t", 0, 1, KeyedStateStore(), reg.group("t.0"))
-        f.open(ctx)
+        f.open(_ctx())
         try:
             assert f._ring is not None
             token = f.ingest_element(images[0], None)
@@ -96,6 +98,74 @@ class TestRingWindowPath:
             assert f._ring.poppable() == 1
         finally:
             f.close()
+
+    @pytest.mark.parametrize("lanes, depth", [(1, 3), (2, 4), (3, 6)])
+    def test_default_depth_and_the_ring_sized_with_it(self, lenet_model, lanes, depth):
+        """``pipeline_depth`` defaults to max(3, 2 * transfer_lanes), and
+        the auto-sized arena follows it: (depth + 2) batches of slots, which
+        the ring rounds up to a power of two."""
+        f = ModelWindowFunction(lenet_model, policy=BucketPolicy(fixed_batch=B),
+                                transfer_lanes=lanes)
+        assert f._max_in_flight == depth - 1
+        f.open(_ctx())
+        try:
+            assert f._ring is not None
+            assert f._ring_capacity == (depth + 2) * B
+            assert f._ring.capacity == 32  # 20, 24 and 32 asked
+        finally:
+            f.close()
+        # An explicit depth still wins (2 is the behaviour before PR 29).
+        two = ModelWindowFunction(lenet_model, policy=BucketPolicy(fixed_batch=B),
+                                  transfer_lanes=lanes, pipeline_depth=2)
+        assert two._max_in_flight == 1
+
+    def test_open_pages_the_whole_arena_in(self, lenet_model):
+        """The arena is allocated lazily; ``open`` touches every page of it, so
+        no window's first fill pays for mapping its slots (the arena holds
+        more windows than a job's warm-up passes through it)."""
+        import os
+
+        if not os.path.exists("/proc/self/statm"):
+            pytest.skip("needs /proc/self/statm to read the resident set")
+
+        def resident_bytes():
+            with open("/proc/self/statm") as f:
+                return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+
+        f = ModelWindowFunction(lenet_model, policy=BucketPolicy(fixed_batch=B),
+                                ring_capacity=1 << 15)  # 32768 slots of 3136 B: 103 MB
+        before = resident_bytes()
+        f.open(_ctx())
+        try:
+            arena_bytes = f._ring._ring.arena_view().nbytes
+            assert arena_bytes > 100e6
+            assert resident_bytes() - before > 0.9 * arena_bytes
+            assert f._ring.poppable() == 0
+        finally:
+            f.close()
+
+    def test_auto_sized_ring_never_splits_a_batch(self, lenet_model, images,
+                                                  expected_labels, monkeypatch):
+        """Five batches of slots asked, eight got (a power of two), and every
+        claim one whole batch: at the default depth no fire takes the
+        wraparound copy-out, which would drain every window in flight."""
+        from flink_tensorflow_tpu.functions.runner import CompiledMethodRunner
+
+        zero_copy = []
+        dispatch_batch = CompiledMethodRunner.dispatch_batch
+
+        def spy(self, batch, *, assemble_s=None, on_done=None):
+            zero_copy.append(on_done is not None)
+            return dispatch_batch(self, batch, assemble_s=assemble_s, on_done=on_done)
+
+        monkeypatch.setattr(CompiledMethodRunner, "dispatch_batch", spy)
+        # Windows of 1 in batches of 4, padded in the ring: 80 slots claimed
+        # of 32, two and a half trips around the arena.
+        results = _run(dict(model=lenet_model, policy=BucketPolicy(fixed_batch=B)),
+                       images, window=1)
+        assert [r.meta["i"] for r in results] == list(range(N))
+        assert {r.meta["i"]: int(r["label"]) for r in results} == expected_labels
+        assert zero_copy == [True] * N
 
     def test_partial_window_timeout_pads_in_ring(self, lenet_model, images, expected_labels):
         """Count-or-timeout fires partial windows: ring pads to the fixed
@@ -136,14 +206,8 @@ class TestRingWindowPath:
         model = mdef.to_model(params)
         f = ModelWindowFunction(model, policy=BucketPolicy(fixed_batch=B),
                                 use_ring=True)
-        from flink_tensorflow_tpu.core.runtime_context import RuntimeContext
-        from flink_tensorflow_tpu.core.state import KeyedStateStore
-        from flink_tensorflow_tpu.metrics.registry import MetricRegistry
-
-        reg = MetricRegistry()
-        ctx = RuntimeContext("t", 0, 1, KeyedStateStore(), reg.group("t.0"))
         with pytest.raises(ValueError, match="static"):
-            f.open(ctx)
+            f.open(_ctx())
         f.close()
 
 
