@@ -191,8 +191,10 @@ class _ModelFunctionBase(fn.RichFunction):
                 name: jax.ShapeDtypeStruct((1, *shapes[name]), spec.dtype)
                 for name, spec in expected.fields.items()
             }
-            params = self._source.params
-            outputs = jax.eval_shape(lambda x: method.fn(params, x), struct)
+            # The params go in as an argument: closed over, their concrete
+            # leaves would be computed on wherever the trace touches them
+            # alone (a cast of a device-resident table, eagerly).
+            outputs = jax.eval_shape(method.fn, self._source.params, struct)
             names = self._outputs or method.output_names or sorted(outputs)
             fields = {}
             for name in names:

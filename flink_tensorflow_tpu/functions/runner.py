@@ -995,6 +995,36 @@ class PagedDecodeStepRunner(DecodeStepRunner):
         return self.snapshot_block(slot, length)
 
 
+def _place_params(params, device):
+    """``(params on device, {"bytes", "resident_bytes"})``.  A leaf that
+    already lives on ``device`` alone is taken as it is — the same buffer:
+    no copy, no cast, no round trip through the host — and counted under
+    ``resident_bytes``; the rest are transferred, and waited for so that the
+    caller's span is the transfer and not its enqueue."""
+    import jax
+
+    seen = {"bytes": 0, "resident_bytes": 0}
+
+    def place(leaf):
+        nbytes = int(getattr(leaf, "nbytes", 0))
+        seen["bytes"] += nbytes
+        if isinstance(leaf, jax.Array) and (device is None or leaf.devices() == {device}):
+            seen["resident_bytes"] += nbytes
+            return leaf
+        return jax.device_put(leaf, device)
+
+    return jax.block_until_ready(jax.tree.map(place, params)), seen
+
+
+def _real_tokens(batch: Batch) -> int:
+    """Positions of the field ``tokens`` that belong to real records: the
+    true lengths where the field is dynamic, never batch or length padding."""
+    lengths = batch.lengths.get("tokens")
+    if lengths is not None:
+        return int(lengths[batch.valid].sum())
+    return batch.num_records * int(batch.arrays["tokens"][0].size)
+
+
 def _flat(batches) -> typing.List[TensorValue]:
     """``[(seq, results)]`` as one list of results."""
     return [r for _, results in batches for r in results]
@@ -1056,6 +1086,12 @@ class CompiledMethodRunner:
         #: device->host fetch only moves what the job consumes.
         self.output_names = tuple(output_names) if output_names is not None else None
         self._params_on_device = None
+        #: Bytes of the parameter tree this runner holds on its device
+        #: (the gauge ``param_bytes``); set at :meth:`open`.
+        self.param_bytes = 0
+        #: Real positions of the input field ``tokens`` (where the method
+        #: has one) are counted a batch: never padding.
+        self._counts_tokens = "tokens" in self.method.input_schema.names
         self._jit_fn = None
         self._transfer: typing.Optional[DeviceTransfer] = None
         self._metrics = None
@@ -1120,9 +1156,11 @@ class CompiledMethodRunner:
         self._transfer = DeviceTransfer(device, self.wire_dtype)
         # Params to HBM once — the Session-owns-variables analogue.
         t_params = time.monotonic()
-        # Blocked on, so that the span is the transfer and not its enqueue.
-        self._params_on_device = jax.block_until_ready(
-            jax.device_put(self.model.params, device))
+        self._params_on_device, placed = _place_params(self.model.params, device)
+        # What this runner holds on the device, and how much of it was
+        # there already (taken by reference: a tree of ten gigabytes
+        # cannot be copied beside itself).
+        self.param_bytes = placed["bytes"]
         spans = getattr(ctx, "spans", None)
         if spans is not None:
             # Track name computed only on the recorded path — bare test
@@ -1130,7 +1168,7 @@ class CompiledMethodRunner:
             self._spans = spans
             self._trace_track = f"{ctx.task_name}.{ctx.subtask_index}"
             spans.span(self._trace_track, "params_to_device", t_params,
-                       time.monotonic())
+                       time.monotonic(), placed)
 
         method = self.method
         select = self.output_names
@@ -1202,6 +1240,8 @@ class CompiledMethodRunner:
             self._fetcher.start()
         if ctx is not None:
             self._metrics = ctx.metrics
+            if self._metrics is not None:
+                self._metrics.gauge("param_bytes", lambda: self.param_bytes)
             plane = getattr(ctx, "roofline", None)
             if plane is not None:
                 self._roofline = plane.probe(ctx.task_name,
@@ -1388,6 +1428,7 @@ class CompiledMethodRunner:
             # Bytes that actually crossed (narrowed when wire_dtype set).
             "h2d_bytes": h2d_bytes,
             "wire_saved": wire_saved,
+            "tokens": _real_tokens(batch) if self._counts_tokens else None,
             # Span boundaries: t0 -> t_lane_start is lane-pool queueing
             # (and assembly on the list path), t_lane_start ->
             # t_dispatched the enqueue of transfer and launch.
@@ -1557,6 +1598,8 @@ class CompiledMethodRunner:
                 self._metrics.counter("wire_bytes_saved").inc(
                     timings["wire_saved"])
             self._metrics.counter("batches").inc()
+            if timings.get("tokens") is not None:
+                self._metrics.counter("tokens").inc(timings["tokens"])
             self._metrics.counter("padded_records").inc(batch.padded_size - batch.num_records)
         if self._roofline is not None:
             # Busy time = the compute span (launch -> fetch reached);
@@ -1578,6 +1621,8 @@ class CompiledMethodRunner:
         guard greps for exactly this shape: zero transfers between fused
         model ops)."""
         spans, track, seq = self._spans, self._trace_track, timings["seq"]
+        # ``tokens``: the batch's real positions, where the method takes tokens.
+        tokens = {} if timings.get("tokens") is None else {"tokens": timings["tokens"]}
         if self._pool is not None:
             # On the list path the lane assembles before it launches:
             # ``assemble_s`` is a part of this span (None on the ring path).
@@ -1591,13 +1636,13 @@ class CompiledMethodRunner:
         else:
             spans.span(track, "enqueue", timings["t_lane_start"],
                        timings["t_dispatched"],
-                       {"seq": seq, "bytes": timings["h2d_bytes"], "batch": n})
+                       {"seq": seq, "bytes": timings["h2d_bytes"], "batch": n, **tokens})
         # Launched .. results on the host.  Where the fetch thread stood
         # when it reached the batch is an accident of its schedule, so it
         # is a number here and no longer a cut between two spans.
         spans.span(track, "in_flight", timings["t_dispatched"], t_done,
                    {"seq": seq, "batch": n, "fetch_reached_s":
-                    round(t_fetch_start - timings["t_dispatched"], 6)})
+                    round(t_fetch_start - timings["t_dispatched"], 6), **tokens})
 
     def _complete_device(self, batch, outputs, timings, on_done,
                          t_fetch_start: float):
@@ -1639,6 +1684,8 @@ class CompiledMethodRunner:
                     timings["wire_saved"])
             self._metrics.counter("fetch_elided_batches").inc()
             self._metrics.counter("batches").inc()
+            if timings.get("tokens") is not None:
+                self._metrics.counter("tokens").inc(timings["tokens"])
             self._metrics.counter("padded_records").inc(
                 batch.padded_size - batch.num_records)
         if self._roofline is not None:

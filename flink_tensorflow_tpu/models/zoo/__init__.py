@@ -12,6 +12,11 @@ behavioral, not mechanism parity.  One module per BASELINE.json workload:
 - :mod:`resnet`    — ResNet-50 (BASELINE.json:11, DP training)
 - :mod:`bilstm`    — BiLSTM text classifier (BASELINE.json:9)
 - :mod:`widedeep`  — Wide&Deep recommender (BASELINE.json:10)
+
+Beyond the reference's workloads: :mod:`chartransformer` (the serving
+plane's char-level decoder) and :mod:`falcon_h1` (a hybrid Mamba-2 +
+grouped-query attention language model, scored a record at a time on the
+stream path).
 """
 
 from flink_tensorflow_tpu.models.zoo.registry import ModelDef, get_model_def, register_model_def

@@ -55,7 +55,7 @@ def register_model_def(name: str):
 
 
 _ZOO_MODULES = ("lenet", "inception", "resnet", "bilstm", "widedeep",
-                "chartransformer")
+                "chartransformer", "falcon_h1")
 
 
 def get_model_def(architecture: str, **config) -> ModelDef:

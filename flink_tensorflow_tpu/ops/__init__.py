@@ -13,6 +13,7 @@ from flink_tensorflow_tpu.ops.paged_attention import (
     pages_to_dense,
     scatter_pages,
 )
+from flink_tensorflow_tpu.ops.ssd import causal_conv1d, ssd_scan
 from flink_tensorflow_tpu.ops.preprocessing import (
     central_crop,
     inception_normalize,
@@ -30,6 +31,8 @@ __all__ = [
     "pages_per_session",
     "pages_to_dense",
     "scatter_pages",
+    "causal_conv1d",
+    "ssd_scan",
     "central_crop",
     "inception_normalize",
     "mnist_normalize",

@@ -42,6 +42,11 @@ def flash_attention(
     parallel.full_attention).  Block sizes shrink automatically for short
     sequences; the stream layer's power-of-two buckets keep them aligned.
 
+    Grouped queries: ``k`` and ``v`` may carry fewer heads than ``q``
+    (``[B, T, Hkv, D]``, ``H`` a multiple of ``Hkv``); query head ``i``
+    reads key/value head ``i // (H / Hkv)`` through the kernel's block
+    index, so no repeated copy of K or V is ever made in HBM.
+
     ``return_lse=True`` also returns the per-row log-sum-exp
     ``[B, H, T]`` (f32) — the residual that lets callers combine partial
     attention over K/V shards, which is how the seq-axis ring
@@ -50,7 +55,9 @@ def flash_attention(
     import jax
 
     b, t, h, d = q.shape
-    tk = k.shape[1]
+    tk, hkv = k.shape[1], k.shape[2]
+    if h % hkv or v.shape[2] != hkv:
+        raise ValueError(f"{h} query heads cannot share {hkv} key / {v.shape[2]} value heads")
     block_q = _tileable_block(t, block_q)
     block_k = _tileable_block(tk, block_k)
     if interpret is None:
@@ -58,10 +65,10 @@ def flash_attention(
 
     # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head).
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
 
     out, lse = _flash_bh(
-        to_bh(q), to_bh(k), to_bh(v),
+        to_bh(q), to_bh(k), to_bh(v), group=h // hkv,
         causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
     )
     out = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
@@ -158,21 +165,23 @@ def _vma(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
-def _flash_bh(q, k, v, *, causal, block_q, block_k, interpret):
+def _flash_bh(q, k, v, *, causal, block_q, block_k, interpret, group=1):
+    """``q`` ``[B*H, T, D]``; ``k``, ``v`` ``[B*H/group, Tk, D]``: row ``i`` of
+    ``q`` reads row ``i // group`` of ``k`` and ``v``."""
     import jax
 
     bh, t, d = q.shape
     # Dtype keyed by NAME: ml_dtypes (bfloat16) have no portable .str.
     fn = _build_flash_call(
         bh, t, k.shape[1], d, jax.numpy.dtype(q.dtype).name, causal,
-        block_q, block_k, interpret, _vma(q, k, v),
+        block_q, block_k, interpret, _vma(q, k, v), group,
     )
     return fn(q, k, v)
 
 
 @functools.lru_cache(maxsize=256)
 def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
-                      interpret, vma):
+                      interpret, vma, group=1):
     """Jitted pallas_call per static configuration.  Building a fresh
     closure per invocation would defeat jax.jit's cache (keyed on the
     function object) and recompile the Mosaic kernel on EVERY eager call."""
@@ -185,9 +194,20 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
     nq, nk = t // block_q, tk // block_k
     scale = 1.0 / math.sqrt(d)
     # Mosaic's default contraction feeds the MXU one bf16 pass whatever
-    # the operand dtype: right for bf16 callers, a silent downcast of
-    # q/k/v/p for float32 ones.
-    precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    # the operand dtype: a silent downcast of q/k/v/p for float32 callers,
+    # who get float32 operands at HIGHEST; narrower callers' tiles go to the
+    # MXU as they are (no widened copy in VMEM), the scores' scale applied
+    # to the float32 product.
+    wide = dtype == jnp.float32
+    precision = jax.lax.Precision.HIGHEST if wide else None
+
+    def kv_tile(b_, qi, j):
+        # Row b_ of q reads row b_ // group of k and v (grouped queries).
+        # Causal: a tile wholly above the diagonal is not computed, and
+        # asking again for the last visible one keeps it from being copied.
+        if causal:
+            j = jnp.minimum(j, (qi * block_q + block_q - 1) // block_k)
+        return (b_ // group, j, 0)
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
         # Grid (bh, nq, nk): the innermost k dimension iterates
@@ -208,11 +228,14 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
 
         @pl.when(visible)
         def _update():
-            q_blk = q_ref[0].astype(jnp.float32) * scale       # [bq, d]
-            k_blk = k_ref[0].astype(jnp.float32)               # [bk, d]
-            v_blk = v_ref[0].astype(jnp.float32)
-            s = jnp.dot(q_blk, k_blk.T, precision=precision,
-                        preferred_element_type=jnp.float32)
+            q_blk, k_blk, v_blk = q_ref[0], k_ref[0], v_ref[0]  # [bq, d], [bk, d] x 2
+            if wide:
+                q_blk = q_blk * scale
+            s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
+                                    precision=precision,
+                                    preferred_element_type=jnp.float32)
+            if not wide:
+                s = s * scale
             if causal:
                 q_pos = qi * block_q + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
@@ -232,7 +255,7 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
             m_scr[:] = m_new[:, None]
             l_scr[:] = (l * alpha + jnp.sum(p, axis=-1))[:, None]
             acc_scr[:] = acc_scr[:] * alpha[:, None] + jnp.dot(
-                p, v_blk, precision=precision,
+                p.astype(v_blk.dtype), v_blk, precision=precision,
                 preferred_element_type=jnp.float32)
 
         @pl.when(j == nk - 1)
@@ -250,10 +273,8 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, qi, j: (b_, qi, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b_, qi, j: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b_, qi, j: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, qi, j: (b_, qi, 0),
@@ -280,5 +301,6 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_attention",
     )
     return jax.jit(fn)

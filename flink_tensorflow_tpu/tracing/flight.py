@@ -55,7 +55,10 @@ On the model operator's track (``<task>.<subtask>``), by thread:
   than 50 ms after the timeout it asked for);
 - lane thread: ``lane_wait`` (dispatch call .. lane picked it up, only
   where a lane pool exists), ``enqueue`` (``device_put`` + jit launch);
-- fetch thread: ``in_flight`` (launched .. results on the host),
+- fetch thread: ``in_flight`` (launched .. results on the host; it and
+  ``enqueue`` carry ``tokens``, the batch's real positions of the input
+  field ``tokens``, where the method takes one: never padding, and what
+  the operator's counter ``tokens`` sums beside ``batches``),
   ``unbatch`` (results built), ``handoff_wait`` (results queued ..
   popped by the subtask thread).
 
