@@ -292,9 +292,11 @@ class _ModelFunctionBase(fn.RichFunction):
         # waiting out the poll interval) when the runtime provides a
         # gate wakeup hook.
         self.runner.on_results_ready = getattr(ctx, "wakeup", None)
+        # Before the warm-up: where the subclass has the runner take its
+        # batches in chunks, the warm-up compiles that signature.
+        self._open_buffers()
         if self._warmup:
             self.runner.warmup(self._warmup, self._warmup_length_bucket)
-        self._open_buffers()
         # The ``open`` span (children: the runner's ``params_to_device``
         # and ``jit_warmup_compile``) and ``open_s``.  Whether the compile
         # cache was hit is not recorded: jax tells only a process-wide
@@ -472,6 +474,25 @@ class _RingToken:
         self.meta = meta
 
 
+class _EarlyWindow:
+    """The window now filling on the ring path, as far as it has been
+    shipped ahead of its fire: ``fill`` ring tokens ingested, ``rows`` of
+    them claimed (``claims``: ``({field: [n, ...] view}, n)`` a claim,
+    oldest first), the whole chunks among them on their way to the device
+    (``device``).  ``off``: nothing more is shipped early — a claim came
+    back short at the arena's end, or a record of the window was
+    list-buffered — and the window is copied out at its fire."""
+
+    __slots__ = ("fill", "rows", "claims", "device", "off")
+
+    def __init__(self):
+        self.fill = 0
+        self.rows = 0
+        self.claims: typing.List[typing.Tuple[typing.Dict[str, np.ndarray], int]] = []
+        self.device: typing.List[typing.Dict[str, typing.Any]] = []
+        self.off = False
+
+
 class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     """Micro-batch inference: one jitted call per fired window.
 
@@ -513,6 +534,20 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     that up to a power of two (8 windows of 1024 for 5), so where the
     batch is a power of two no batch splits at the arena's end.  Default: auto
     (on when eligible); pass ``use_ring=False`` to force the list path.
+
+    **Early shipping**: a record lies in the arena from its arrival, so
+    on the ring path a large window need not wait for its fire to start
+    crossing the link.  Where the runner finds it worth while
+    (``CompiledMethodRunner.chunk_input``: a fixed batch of 64 MB or more
+    of inputs, no narrowed wire), every ``chunk_rows`` records that have
+    arrived are claimed and put at once, the fire puts what is left (the
+    last chunk of a full window; the rest and the padding rows of one
+    fired by its timeout) and the jitted call joins the chunks.  Same
+    records, same order, same padding and ``valid`` mask, same trigger;
+    slots are still released when the batch's results are collected.
+    The filling window's chunks are one more window of inputs on the
+    chip.  Anything else - the list path, a small window - crosses in one
+    put at the fire.
     """
 
     #: A window operator counts ELEMENTS into its buffer — one
@@ -543,6 +578,10 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         self._ring_capacity = ring_capacity
         self._ring = None
         self._last_ingested: typing.Optional[TensorValue] = None
+        #: The window now filling, where windows are shipped as they fill
+        #: (the runner's ``chunk_rows``, decided at open); None: not shipped
+        #: early, or given up at a snapshot.
+        self._early: typing.Optional[_EarlyWindow] = None
         #: When the window now filling got its first record (None: no
         #: window is filling), and the running sums as they stood then:
         #: (emit + collect_wait, ring wait, park seconds since the window
@@ -590,11 +629,13 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             # the ring rounds its capacity up to a power of two, so it can
             # hold more windows than a job's warm-up passes through it.
             self._ring.prefault()
+            self.runner.chunk_input(self._ring.capacity)
 
     def clone(self) -> "fn.Function":
         dup = super().clone()
         dup._ring = None
         dup._last_ingested = None
+        dup._early = None
         dup._fill_t0 = None
         return dup
 
@@ -609,8 +650,19 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         return len(self.runner._pending) if self.runner is not None else 0
 
     def close(self) -> None:
+        # The runner's close collects what is in flight, oldest first, and
+        # with it those batches' ring releases; a half-shipped window's
+        # claims are then the oldest left.  Its device chunks go with it,
+        # once their transfers have read the arena that is freed below.
         super().close()
         if self._ring is not None:
+            early, self._early = self._early, None
+            if early is not None and early.rows:
+                try:
+                    self._await_puts(early.device)
+                except Exception:  # noqa: BLE001 - cancellation teardown
+                    pass
+                self._ring.release(early.rows)
             self._ring.close()
             self._ring = None
 
@@ -624,10 +676,51 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             return None
         tv = value if isinstance(value, TensorValue) else coerce(
             value, self.runner.method.input_schema)
+        early = self._early
         if not self._ring.try_push(tv.fields) and not self._wait_for_slot(tv, out):
+            if early is not None:
+                early.off = True  # a mixed window is copied out at its fire
             return None
         self._last_ingested = tv
+        if early is not None:
+            early.fill += 1
+            # The last chunk of a full batch is the fire's own.
+            if (early.fill % self.runner.chunk_rows == 0 and not early.off
+                    and early.fill < self.runner.policy.fixed_batch):
+                self._ship_chunk(early)
         return _RingToken(tv.meta)
+
+    def _ship_chunk(self, early: _EarlyWindow) -> None:
+        """``chunk_rows`` more records of the filling window lie in the
+        arena: claim them and start their transfer (subtask thread, like
+        every claim and release)."""
+        rows = self.runner.chunk_rows
+        t0 = time.monotonic()
+        views, got = self._ring.claim_batch(rows)
+        if got == 0:
+            raise RuntimeError("ring out of sync with window buffer")
+        early.claims.append((views, got))
+        early.rows += got
+        if got < rows:
+            early.off = True  # split at the arena's end
+            return
+        early.device.append(self.runner.put_chunk(views))
+        if self._spans is not None:
+            self._spans.span(self._track, "early_put", t0, time.monotonic(), {
+                "seq": self.runner._batch_seq + 1, "chunk": len(early.device) - 1,
+                "bytes": sum(v.nbytes for v in views.values())})
+
+    @staticmethod
+    def _await_puts(device_chunks) -> None:
+        """Wait until the transfers of chunks shipped early have read their
+        rows.  A ``device_put`` returns at once and the host goes on reading
+        the rows for tens of ms (it re-lays them for the device), so rows
+        that were put and whose batch is never dispatched — nothing then
+        collects it and runs an ``on_done`` — are released, and the arena
+        freed, only after this."""
+        import jax
+
+        jax.block_until_ready(device_chunks)
 
     def _wait_for_slot(self, tv, out: fn.Collector) -> bool:
         """Ring full: completed-but-uncollected batches hold slots
@@ -657,6 +750,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         parked = self._spans.park_s if self._spans is not None else 0.0
         self._fill_marks = (self._emit_total_s + self.runner.collect_wait_total_s,
                             self._ring_wait_total_s, parked)
+        self._early = _EarlyWindow() if self.runner.chunk_rows is not None else None
         self._fill_t0 = time.monotonic()
 
     def _close_fill(self, now: float, records: int) -> None:
@@ -700,11 +794,20 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             drained = self.runner.collect_batches(0)
             if self._out is not None:
                 self._emit(drained, self._out)
+        # What the fill has claimed already comes first (the flush above
+        # made those claims the oldest); the chunks on the device are
+        # dropped once they have crossed (their rows are released below),
+        # and the rest of this window crosses whole.
+        early, self._early = self._early, None
+        claims = []
+        if early is not None:
+            self._await_puts(early.device)
+            claims = early.claims
         values = {}
         remaining = len(tokens)
         idx = 0
         while remaining > 0:
-            views, n = self._ring.claim_batch(remaining)
+            views, n = claims.pop(0) if claims else self._ring.claim_batch(remaining)
             if n == 0:
                 raise RuntimeError("ring out of sync with window buffer")
             for i in range(n):
@@ -731,8 +834,9 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         self._fire_in_flight = 0
         blocked0 = self.runner.collect_wait_total_s
         tokens = all(isinstance(e, _RingToken) for e in elements) and bool(elements)
+        early_chunks = 0
         if tokens and self._ring is not None:
-            self._fire_ring(elements, out)
+            early_chunks = self._fire_ring(elements, out)
         else:
             if any(isinstance(e, _RingToken) for e in elements):
                 # Mixed (restored values + fresh tokens): copy tokens out
@@ -752,7 +856,8 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
                 "seq": self.runner._batch_seq, "records": len(elements),
                 "padded": policy.batch_bucket(tail) - tail if tail else 0,
                 "in_flight": self._fire_in_flight,
-                "blocked_s": self.runner.collect_wait_total_s - blocked0})
+                "blocked_s": self.runner.collect_wait_total_s - blocked0,
+                "early_chunks": early_chunks})
 
     def _hold_depth(self, out: fn.Collector) -> None:
         """A batch has just been dispatched: note how many are in flight,
@@ -761,14 +866,18 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         self._fire_in_flight = max(self._fire_in_flight, len(self.runner._pending))
         self._emit(self.runner.collect_batches(self._max_in_flight), out)
 
-    def _fire_ring(self, tokens, out: fn.Collector):
-        """Claim contiguous arena views per chunk and dispatch them —
-        the zero-copy fire path."""
+    def _fire_ring(self, tokens, out: fn.Collector) -> int:
+        """Claim contiguous arena views per batch and dispatch them — the
+        zero-copy fire path.  Returns how many chunks of the window had been
+        shipped before the fire (the ``fire`` span's ``early_chunks``)."""
         from flink_tensorflow_tpu.tensors.batching import Batch
 
         policy = self.runner.policy
         cap = policy.fixed_batch or policy.batch.sizes[-1]
         n_total = len(tokens)
+        # What the fill claimed and shipped is the head of the first batch.
+        early, self._early = self._early, None
+        early_chunks = 0
         for start in range(0, n_total, cap):
             chunk = tokens[start:start + cap]
             n = len(chunk)
@@ -779,38 +888,69 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
                 if not self._ring.try_push(self._last_ingested.fields):
                     raise RuntimeError("ring cannot hold batch padding; "
                                        "raise ring_capacity")
-            views, got = self._ring.claim_batch(b)
-            if got < b:
-                # Arena wraparound split this batch: copy out (rare; at
-                # most once per trip around the ring).  Ring releases are
-                # strictly oldest-claim-first, so the immediate releases
-                # below would free a still-dispatched batch's slots if
-                # any were in flight OR completed-but-uncollected — drain
-                # both (their deferred on_done releases run FIFO at
-                # collection), making our claim the oldest.
-                if self.runner._pending or self.runner.has_completed():
-                    self._emit(self.runner.collect_batches(0), out)
-                arrays = {f: np.empty((b, *v.shape[1:]), v.dtype)
-                          for f, v in views.items()}
-                filled = 0
-                while filled < b:
-                    if filled:
-                        views, got = self._ring.claim_batch(b - filled)
-                    for f, v in views.items():
-                        arrays[f][filled:filled + got] = v[:got]
-                    self._ring.release(got)
-                    filled += got
-                release = None
+            # A batch is claimed ``rows`` at a time: whole, or a chunk where
+            # it crosses the link in chunks (K claims, the fill's first).
+            rows = self.runner.chunk_rows or b
+            head = early if early is not None and start == 0 else _EarlyWindow()
+            claims, claimed, shipped = head.claims, head.rows, ()
+            whole = claimed % rows == 0
+            while whole and claimed < b:
+                views, got = self._ring.claim_batch(rows)
+                if got == 0:
+                    raise RuntimeError("ring out of sync with window buffer")
+                claims.append((views, got))
+                claimed += got
+                whole = got == rows
+            if not whole:
+                arrays, chunks, release = self._copy_out(head, b, out), None, None
             else:
-                arrays = views
                 ring = self._ring
                 release = (lambda nn=b, r=ring: r.release(nn))
+                if rows == b:
+                    arrays, chunks = claims[0][0], None
+                else:
+                    shipped = head.device
+                    arrays = {}
+                    chunks = [v for v, _ in claims[len(shipped):]]
+                    early_chunks += len(shipped)
             valid = np.zeros((b,), dtype=bool)
             valid[:n] = True
             batch = Batch(arrays=arrays, valid=valid, lengths={},
                           metas=[t.meta for t in chunk])
-            self.runner.dispatch_batch(batch, on_done=release)
+            self.runner.dispatch_batch(batch, on_done=release, chunks=chunks,
+                                       shipped=shipped)
             self._hold_depth(out)
+        return early_chunks
+
+    def _copy_out(self, head: _EarlyWindow, b: int, out: fn.Collector):
+        """A claim came back short: the arena's end splits this batch.  Copy
+        it out (rare; at most once per trip around the ring), the rows
+        already claimed (``head.claims``) first, and return it as plain
+        ``[b, ...]`` arrays.
+
+        Ring releases are strictly oldest-claim-first, so the immediate
+        releases below would free a still-dispatched batch's slots if any
+        were in flight OR completed-but-uncollected — drain both (their
+        deferred on_done releases run FIFO at collection), making our
+        claims the oldest.  Chunks of it already on the device are dropped,
+        once they have crossed: the copy crosses again, in the runner's own
+        puts."""
+        if self.runner._pending or self.runner.has_completed():
+            self._emit(self.runner.collect_batches(0), out)
+        self._await_puts(head.device)
+        claims = head.claims
+        arrays = {f: np.empty((b, *v.shape[1:]), v.dtype)
+                  for f, v in claims[0][0].items()}
+        filled = 0
+        while filled < b:
+            views, got = claims.pop(0) if claims else self._ring.claim_batch(b - filled)
+            if got == 0:
+                raise RuntimeError("ring out of sync with window buffer")
+            for f, v in views.items():
+                arrays[f][filled:filled + got] = v[:got]
+            self._ring.release(got)
+            filled += got
+        return arrays
 
     # Timer hooks (WindowOperator.next_deadline/fire_due): while batches
     # are in flight, poll every idle_flush_s and emit whatever is READY —

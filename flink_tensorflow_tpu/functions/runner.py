@@ -47,6 +47,15 @@ hot loop).  The TPU-native engine room:
   per direction end to end.  ``wire_dtype`` ("bf16"/"f16") narrows the
   h2d bytes of batches that DO cross, with the declared dtype restored
   inside the jitted call (the upcast fuses into the executable).
+- **chunked input** (``chunk_input``): a caller that holds a window's
+  records in place while it fills — the ring path of
+  ``ModelWindowFunction`` — may ship it ``chunk_rows`` records at a time
+  (:meth:`CompiledMethodRunner.put_chunk`), so the link works during the
+  fill and only the last chunk is outstanding when the window fires.  The
+  jitted call then takes the batch as ``K`` chunks and joins them as its
+  first operation.  Chosen from what is known at ``open()``; a window too
+  small to be worth ``EARLY_MIN_CHUNKS`` puts of ``EARLY_CHUNK_MIN_BYTES``
+  crosses whole, in one put, as it always did.
 """
 
 from __future__ import annotations
@@ -54,6 +63,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import functools
+import math
 import threading
 import time
 import typing
@@ -67,6 +77,17 @@ from flink_tensorflow_tpu.utils.profiling import annotate_batch
 
 if typing.TYPE_CHECKING:
     from flink_tensorflow_tpu.core.runtime_context import RuntimeContext
+
+#: Chunked input: a chunk carries at least this many bytes (a put costs the
+#: subtask thread 0.4 ms), a batch is cut into at most EARLY_MAX_CHUNKS of
+#: them (the step's join is cheapest with few: on a TPU the batch is the lane
+#: dimension, and 1024 rows in 8 chunks of 128 are whole lane tiles, where 16
+#: of 64 made the step 4% slower: PERF.md 6, PR 32), and a batch that would
+#: not give EARLY_MIN_CHUNKS crosses whole: with fewer, most of the transfer
+#: would still come after the fire.
+EARLY_CHUNK_MIN_BYTES = 16 << 20
+EARLY_MIN_CHUNKS = 4
+EARLY_MAX_CHUNKS = 8
 
 
 @functools.lru_cache(maxsize=64)
@@ -1016,13 +1037,16 @@ def _place_params(params, device):
     return jax.block_until_ready(jax.tree.map(place, params)), seen
 
 
-def _real_tokens(batch: Batch) -> int:
+def _real_tokens(batch: Batch, record_shape) -> int:
     """Positions of the field ``tokens`` that belong to real records: the
-    true lengths where the field is dynamic, never batch or length padding."""
+    true lengths where the field is dynamic, never batch or length padding
+    (``record_shape``: the schema's, read where it is static)."""
     lengths = batch.lengths.get("tokens")
     if lengths is not None:
         return int(lengths[batch.valid].sum())
-    return batch.num_records * int(batch.arrays["tokens"][0].size)
+    # A dynamic field is always batched with its lengths.
+    assert None not in record_shape, record_shape
+    return batch.num_records * math.prod(record_shape)
 
 
 def _flat(batches) -> typing.List[TensorValue]:
@@ -1094,6 +1118,9 @@ class CompiledMethodRunner:
         self._counts_tokens = "tokens" in self.method.input_schema.names
         self._jit_fn = None
         self._transfer: typing.Optional[DeviceTransfer] = None
+        #: Rows of one input chunk where batches cross the link in chunks
+        #: (:meth:`chunk_input`); None: a batch is one put.
+        self.chunk_rows: typing.Optional[int] = None
         self._metrics = None
         #: In-flight dispatched batches: (batch, output futures, t0).
         #: Appended by the dispatching thread, consumed (FIFO) by the
@@ -1187,6 +1214,16 @@ class CompiledMethodRunner:
             # jitted call; int8-narrowed fields also multiply their
             # absmax scale back in (the companion __scale__ inputs ride
             # the same device_put pytree and never reach the model).
+            if isinstance(inputs, tuple):
+                # A batch that crossed in chunks (chunk_input), every
+                # record a flat run (DeviceTransfer.put_chunk): shaped and
+                # joined here, beside the convert, so the model sees one
+                # ``[B, ...]`` operand.
+                import jax.numpy as jnp
+
+                inputs = {k: jnp.concatenate(
+                    [c[k].reshape(-1, *schema[k].shape) for c in inputs], axis=0)
+                    for k in inputs[0]}
             out = {}
             for k, v in inputs.items():
                 if is_scale_key(k):
@@ -1246,6 +1283,42 @@ class CompiledMethodRunner:
             if plane is not None:
                 self._roofline = plane.probe(ctx.task_name,
                                              metrics=ctx.metrics)
+
+    def chunk_input(self, arena_slots: int) -> typing.Optional[int]:
+        """Decide, once, whether this runner's batches cross the link in
+        chunks, and return the rows of a chunk (``chunk_rows``) or None.
+
+        For a caller that keeps a filling window's records in an arena of
+        ``arena_slots`` rows and can ship it as it fills.  A chunk is a
+        divisor of the fixed batch that also divides the arena (so that no
+        aligned chunk straddles the arena's end), of EARLY_CHUNK_MIN_BYTES
+        or more, and there are EARLY_MIN_CHUNKS to EARLY_MAX_CHUNKS of them
+        (the most that fit): 1024 records of 268 KB go in 8 chunks of 128,
+        34 MB a put.  A narrowed wire keeps the batch whole (int8's scale is
+        its absmax over the batch); so does anything smaller.  An arena
+        holds records of one static shape, so the schema is static here.
+        Call before :meth:`warmup`, which then compiles the chunked
+        signature."""
+        fixed = self.policy.fixed_batch
+        if fixed is None or self.wire_dtype is not None:
+            return None
+        row_bytes = sum(math.prod(spec.shape) * spec.dtype.itemsize
+                        for _, spec in self.method.input_schema)
+        for k in range(EARLY_MAX_CHUNKS, EARLY_MIN_CHUNKS - 1, -1):
+            rows = fixed // k
+            if (fixed % k == 0 and arena_slots % rows == 0
+                    and rows * row_bytes >= EARLY_CHUNK_MIN_BYTES):
+                self.chunk_rows = rows
+                break
+        return self.chunk_rows
+
+    def put_chunk(self, arrays: typing.Mapping[str, typing.Any]):
+        """Start the transfer of one chunk (``{field: [chunk_rows, ...]}``)
+        of a batch that has not been dispatched yet; what it returns goes
+        back to :meth:`dispatch_batch` in ``chunks``.  Asynchronous, like
+        every ``device_put``: the caller keeps the rows alive and unchanged
+        until the batch's ``on_done``."""
+        return self._transfer.put_chunk(arrays)
 
     def warmup(self, batch_sizes: typing.Iterable[int], length_bucket: int = 128) -> None:
         """Pre-compile executables for the given batch buckets (open-time,
@@ -1353,9 +1426,17 @@ class CompiledMethodRunner:
 
     def dispatch_batch(self, batch: Batch, *,
                        assemble_s: typing.Optional[float] = None,
-                       on_done: typing.Optional[typing.Callable[[], None]] = None) -> None:
+                       on_done: typing.Optional[typing.Callable[[], None]] = None,
+                       chunks: typing.Optional[typing.Sequence[
+                           typing.Mapping[str, typing.Any]]] = None,
+                       shipped: typing.Sequence[typing.Any] = ()) -> None:
         """Transfer + launch a pre-assembled :class:`Batch` (zero-copy ring
         path: ``batch.arrays`` are views onto the ring arena).
+
+        With ``chunk_rows`` set the caller may hand the batch over in row
+        order as ``shipped``, what :meth:`put_chunk` returned for its leading
+        chunks, and ``chunks``, views of the rest (``batch.arrays`` is then
+        unused).
 
         ``on_done`` fires when the batch's results are COLLECTED on the
         subtask thread — by then the fetch completed, so the arena slots
@@ -1372,9 +1453,9 @@ class CompiledMethodRunner:
         seq = self._batch_seq
         if self._pool is not None:
             item = self._pool.submit(
-                self._launch_batch, batch, t0, seq, assemble_s, on_done)
+                self._launch_batch, batch, t0, seq, assemble_s, on_done, chunks, shipped)
         else:
-            item = self._launch_batch(batch, t0, seq, assemble_s, on_done)
+            item = self._launch_batch(batch, t0, seq, assemble_s, on_done, chunks, shipped)
         self._enqueue(item, t0)
 
     def _enqueue(self, item, t0: float) -> None:
@@ -1394,13 +1475,25 @@ class CompiledMethodRunner:
         return self._launch_batch(batch, t0, seq, time.monotonic() - t_a, None)
 
     def _launch_batch(self, batch: Batch, t0: float, seq: int,
-                      assemble_s: typing.Optional[float], on_done):
+                      assemble_s: typing.Optional[float], on_done, chunks=None,
+                      shipped=()):
         """Transfer + launch; returns (batch, output futures, timings, on_done)."""
         import jax
 
+        rows = self.chunk_rows
+        if chunks is None and rows is not None and batch.padded_size == self.policy.fixed_batch:
+            # An assembled batch where batches cross in chunks: the same
+            # puts and the same executable, none of them early.
+            chunks = [{n: a[i:i + rows] for n, a in batch.arrays.items()}
+                      for i in range(0, batch.padded_size, rows)]
         with annotate_batch(f"{self.model.name}.{self.method.name}", seq):
             t_b = time.monotonic()
-            inputs, h2d_bytes, wire_saved = self._transfer.ship(batch)
+            if chunks is not None:
+                inputs, h2d_bytes, early_bytes = self._transfer.ship_chunks(shipped, chunks)
+                wire_saved = 0
+            else:
+                inputs, h2d_bytes, wire_saved = self._transfer.ship(batch)
+                early_bytes = 0
             if self.method.needs_lengths:
                 lengths = self._transfer.lengths_to_device(batch)
                 outputs = self._jit_fn(self._params_on_device, inputs, lengths)
@@ -1427,8 +1520,11 @@ class CompiledMethodRunner:
             "dispatch_s": t_c - t_b,
             # Bytes that actually crossed (narrowed when wire_dtype set).
             "h2d_bytes": h2d_bytes,
+            # Those of them that crossed before the dispatch (put_chunk).
+            "h2d_early_bytes": early_bytes,
             "wire_saved": wire_saved,
-            "tokens": _real_tokens(batch) if self._counts_tokens else None,
+            "tokens": (_real_tokens(batch, self.method.input_schema["tokens"].shape)
+                       if self._counts_tokens else None),
             # Span boundaries: t0 -> t_lane_start is lane-pool queueing
             # (and assembly on the list path), t_lane_start ->
             # t_dispatched the enqueue of transfer and launch.
@@ -1594,6 +1690,8 @@ class CompiledMethodRunner:
                 self._metrics.histogram("assemble_s").record(timings["assemble_s"])
             self._metrics.histogram("dispatch_s").record(timings["dispatch_s"])
             self._metrics.counter("h2d_bytes").inc(timings["h2d_bytes"])
+            self._metrics.counter("h2d_early_bytes").inc(
+                timings.get("h2d_early_bytes", 0))
             if timings.get("wire_saved"):
                 self._metrics.counter("wire_bytes_saved").inc(
                     timings["wire_saved"])
@@ -1636,7 +1734,8 @@ class CompiledMethodRunner:
         else:
             spans.span(track, "enqueue", timings["t_lane_start"],
                        timings["t_dispatched"],
-                       {"seq": seq, "bytes": timings["h2d_bytes"], "batch": n, **tokens})
+                       {"seq": seq, "bytes": timings["h2d_bytes"],
+                        "early_bytes": timings["h2d_early_bytes"], "batch": n, **tokens})
         # Launched .. results on the host.  Where the fetch thread stood
         # when it reached the batch is an accident of its schedule, so it
         # is a number here and no longer a cut between two spans.
@@ -1679,6 +1778,8 @@ class CompiledMethodRunner:
                 self._metrics.histogram("assemble_s").record(timings["assemble_s"])
             self._metrics.histogram("dispatch_s").record(timings["dispatch_s"])
             self._metrics.counter("h2d_bytes").inc(timings["h2d_bytes"])
+            self._metrics.counter("h2d_early_bytes").inc(
+                timings.get("h2d_early_bytes", 0))
             if timings.get("wire_saved"):
                 self._metrics.counter("wire_bytes_saved").inc(
                     timings["wire_saved"])

@@ -148,6 +148,38 @@ class DeviceTransfer:
         nbytes = sum(a.nbytes for a in arrays.values())
         return jax.device_put(arrays, self.device), nbytes, saved
 
+    def put_chunk(self, arrays: typing.Mapping[str, np.ndarray]) -> typing.Dict[str, typing.Any]:
+        """Start the transfer of one chunk ``{field: [C, ...]}`` of a batch
+        (never narrowed: an absmax scale is per batch).  Each field crosses
+        as ``[C, bytes of a record's field]``, every record one flat run,
+        which is how its rows lie in memory anyway; the jitted call gives
+        the chunks their shape back as it joins them.  On a TPU the host
+        re-lays every array into the device's tiles on one thread an array,
+        and an innermost dimension of 3 channels makes that slow: 34 MB as
+        ``u8[128, 299, 299, 3]`` take 45 ms, as ``u8[128, 268203]`` 19.5 ms,
+        for a step 0.4% longer (as ONE run of bytes, 6 ms, but the device
+        then re-lays it inside the step: +55%; PERF.md 6, PR 32)."""
+        import jax
+
+        return jax.device_put({n: a.reshape(a.shape[0], -1) for n, a in arrays.items()},
+                              self.device)
+
+    def ship_chunks(
+        self, shipped: typing.Sequence[typing.Mapping[str, typing.Any]],
+        rest: typing.Sequence[typing.Mapping[str, np.ndarray]],
+    ) -> typing.Tuple[typing.Tuple[typing.Dict[str, typing.Any], ...], int, int]:
+        """Transfer a batch that comes as row-ordered chunks ``[C, ...]``:
+        ``shipped``, its leading chunks as :meth:`put_chunk` returned them
+        while their window was still filling, are taken as they are; the
+        ``rest`` cross now, side by side.
+
+        Returns ``(device_chunks, h2d_bytes, early_bytes)``: every byte of
+        the batch, and those of them that had crossed before this call.
+        """
+        early = sum(a.nbytes for c in shipped for a in c.values())
+        late = sum(a.nbytes for c in rest for a in c.values())
+        return (*shipped, *(self.put_chunk(c) for c in rest)), early + late, early
+
     def to_device(self, batch: Batch) -> typing.Dict[str, typing.Any]:
         """Ship all batch fields to HBM in one transfer.
 

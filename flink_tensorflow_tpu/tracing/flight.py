@@ -44,18 +44,30 @@ On the model operator's track (``<task>.<subtask>``), by thread:
   ingest), ``fire`` (``process_window`` entered .. returned; ``args``:
   ``records``, ``padded``, ``in_flight`` = windows dispatched and not
   yet fetched right after this fire's dispatch, before it collects: it
-  reaches ``pipeline_depth`` on a backlog and never passes it; and
-  ``blocked_s`` = seconds of this fire inside ``collect_wait``.  The
-  operator's metric group has the same level as the gauge
-  ``windows_in_flight``, read when a report is taken),
+  reaches ``pipeline_depth`` on a backlog and never passes it;
+  ``blocked_s`` = seconds of this fire inside ``collect_wait``; and
+  ``early_chunks`` = chunks of the window that were on their way to the
+  device before it fired: K - 1 of a full window's K, 0 where windows
+  cross whole.  The operator's metric group has the same level as the
+  gauge ``windows_in_flight``, read when a report is taken),
+  ``early_put`` (one chunk of the window now filling claimed and its
+  ``device_put`` issued, inside that window's ``fill``; ``args``: ``seq``
+  of the batch it will be part of, ``chunk`` = its place in the batch,
+  ``bytes``),
   ``collect_wait`` (each blocking stretch of ``collect_ready``),
   ``emit`` (one fetched batch handed downstream), ``open`` with children
   ``params_to_device`` and ``jit_warmup_compile``, and on the chain
   head's track the instant ``park.overslept`` (a park that returned more
   than 50 ms after the timeout it asked for);
 - lane thread: ``lane_wait`` (dispatch call .. lane picked it up, only
-  where a lane pool exists), ``enqueue`` (``device_put`` + jit launch);
-- fetch thread: ``in_flight`` (launched .. results on the host; it and
+  where a lane pool exists), ``enqueue`` (``device_put`` of what had not
+  been put before the fire + jit launch; ``bytes`` = all the batch's
+  input bytes, ``early_bytes`` = those put before the fire.  The
+  operator's counters ``h2d_bytes`` and ``h2d_early_bytes`` sum the two
+  over fetched batches);
+- fetch thread: ``in_flight`` (launched .. results on the host: where a
+  window was shipped early, only its last chunks' transfer is still in
+  front of the step; it and
   ``enqueue`` carry ``tokens``, the batch's real positions of the input
   field ``tokens``, where the method takes one: never padding, and what
   the operator's counter ``tokens`` sums beside ``batches``),

@@ -273,3 +273,177 @@ class TestMetrics:
         assert result.metrics["infer.0.records"]["count"] == 10
         assert result.metrics["infer.0.batches"] == 2
         assert result.metrics["infer.0.record_latency_s"]["p50"] > 0
+
+
+def _shape_model(shape, dtype, name="shapes"):
+    """A model whose ``serve`` sums each record: what matters is its schema."""
+    from flink_tensorflow_tpu.models.base import Model, ModelMethod
+    from flink_tensorflow_tpu.tensors.schema import RecordSchema, spec
+
+    schema = RecordSchema({"x": spec(shape, dtype)})
+
+    def serve(params, inputs):
+        x = inputs["x"]
+        return {"sum": x.astype(jnp.float32).reshape(x.shape[0], -1).sum(axis=1)
+                + params["bias"]}
+
+    return Model(name, {"bias": jnp.float32(1.0)},
+                 {"serve": ModelMethod("serve", schema, ("sum",), serve)})
+
+
+class TestEarlyShippingRule:
+    """Whether a window crosses the link in chunks is decided at ``open()``
+    from the batch, the schema, the wire and the arena: no argument names it."""
+
+    @pytest.mark.parametrize("shape, dtype, fixed, arena, kw, rows", [
+        # The flagship: 1024 x 268 KB, an arena of 8192 slots: 8 chunks of 34 MB.
+        pytest.param((299, 299, 3), np.uint8, 1024, 8192, {}, 128, id="inception_1024"),
+        # 17 MB a chunk at 16 would do, but 8 chunks are the most.
+        pytest.param((299, 299, 3), np.uint8, 2048, 16384, {}, 256, id="inception_2048"),
+        # 64 MB and more in 4: the fewest chunks that still engage.
+        pytest.param((299, 299, 3), np.uint8, 256, 2048, {}, 64, id="inception_256_in_4"),
+        pytest.param((299, 299, 3), np.uint8, 128, 1024, {}, None, id="inception_128_too_small"),
+        # 6 is the largest count that divides 768 and leaves a chunk the arena's
+        # 4096 slots are a multiple of (8 x 96 and 7 are not).
+        pytest.param((299, 299, 3), np.uint8, 768, 4096, {}, 128, id="batch_of_768_in_6"),
+        pytest.param((299, 299, 3), np.uint8, 1000, 8192, {}, None, id="batch_no_chunk_divides"),
+        # Two records of int32[4096] (the language-model cell): 32 KB a window.
+        pytest.param((4096,), np.int32, 2, 16, {}, None, id="two_int32_4096"),
+        pytest.param((299, 299, 3), np.float32, 1024, 8192, {"wire_dtype": "bf16"}, None,
+                     id="narrowed_wire"),
+        pytest.param((299, 299, 3), np.float32, 1024, 8192, {"wire_dtype": "int8"}, None,
+                     id="int8_wire_scale_is_per_batch"),
+        pytest.param((299, 299, 3), np.uint8, None, 8192, {}, None, id="no_fixed_batch"),
+    ])
+    def test_chunk_rows(self, shape, dtype, fixed, arena, kw, rows):
+        from flink_tensorflow_tpu.functions.runner import CompiledMethodRunner
+
+        runner = CompiledMethodRunner(_shape_model(shape, dtype),
+                                      policy=BucketPolicy(fixed_batch=fixed), **kw)
+        assert runner.chunk_rows is None
+        assert runner.chunk_input(arena) == rows
+        assert runner.chunk_rows == rows
+
+    def test_chunks_cross_as_flat_runs_and_the_early_ones_are_taken_as_they_are(self):
+        """``ship_chunks``: the leading chunks already on the device pass
+        through untouched, the rest are put, every record of a chunk a flat
+        run, in row order; all bytes counted, the early ones apart."""
+        from flink_tensorflow_tpu.tensors.transfer import DeviceTransfer
+
+        rows = np.arange(8 * 3 * 5, dtype=np.int16).reshape(8, 3, 5)
+        transfer = DeviceTransfer(jax.devices()[0])
+        early = [transfer.put_chunk({"x": rows[i:i + 2]}) for i in (0, 2)]
+        device, nbytes, early_bytes = transfer.ship_chunks(
+            early, [{"x": rows[4:6]}, {"x": rows[6:8]}])
+        assert device[0] is early[0] and device[1] is early[1]
+        assert nbytes == rows.nbytes and early_bytes == rows.nbytes // 2
+        for i, chunk in enumerate(device):
+            assert isinstance(chunk["x"], jax.Array) and chunk["x"].shape == (2, 3 * 5)
+            assert np.array_equal(np.asarray(chunk["x"]), rows[2 * i:2 * i + 2].reshape(2, -1))
+
+    def _served(self, function, records, window):
+        env = StreamExecutionEnvironment(parallelism=1)
+        results = (
+            env.from_collection(records).count_window(window)
+            .apply(function, name="model").sink_to_list()
+        )
+        return results, env.execute(timeout=120).metrics
+
+    def _puts(self, monkeypatch):
+        """Every ``device_put`` of input rows: ``("whole", rows)`` a batch in
+        one put, ``("chunks", K, early)`` a batch in chunks."""
+        from flink_tensorflow_tpu.tensors.transfer import DeviceTransfer
+
+        seen = []
+        ship, ship_chunks = DeviceTransfer.ship, DeviceTransfer.ship_chunks
+
+        def whole(self, batch):
+            seen.append(("whole", batch.padded_size))
+            return ship(self, batch)
+
+        def chunked(self, shipped, rest):
+            out = ship_chunks(self, shipped, rest)
+            seen.append(("chunks", len(out[0]), out[2]))
+            return out
+
+        monkeypatch.setattr(DeviceTransfer, "ship", whole)
+        monkeypatch.setattr(DeviceTransfer, "ship_chunks", chunked)
+        return seen
+
+    def test_large_windows_cross_7_of_8_chunks_early_on_one_executable(
+            self, lenet_model, monkeypatch):
+        """Full windows of a schema large enough (here: the floor under a chunk
+        lowered to lenet's size): ``h2d_early_bytes / h2d_bytes`` is (K - 1) /
+        K, every ``fire`` says 7 chunks, every ``early_put`` names its window's
+        batch, and ``warmup`` and the live windows share one executable."""
+        from flink_tensorflow_tpu.functions import runner as runner_module
+
+        monkeypatch.setattr(runner_module, "EARLY_CHUNK_MIN_BYTES", 1 << 10)
+        seen = self._puts(monkeypatch)
+        compiled = []
+
+        class Function(ModelWindowFunction):
+            def close(self):
+                compiled.append(self.runner._jit_fn._cache_size())
+                super().close()
+
+        rng = np.random.RandomState(3)
+        records = [TensorValue({"image": rng.rand(28, 28, 1).astype(np.float32)}, {"i": i})
+                   for i in range(4 * 32)]
+        env = StreamExecutionEnvironment(parallelism=1)
+        results = (
+            env.from_collection(records).count_window(32)
+            .apply(Function(lenet_model, policy=BucketPolicy(fixed_batch=32),
+                            warmup_batches=(32,)), name="model").sink_to_list()
+        )
+        handle = env.execute_async("early")
+        handle.wait(120)
+        metrics = handle.executor.metrics.report()
+        assert [r.meta["i"] for r in results] == list(range(len(records)))
+        assert metrics["model.0.h2d_bytes"] == 4 * 32 * 28 * 28 * 4
+        assert metrics["model.0.h2d_early_bytes"] * 8 == metrics["model.0.h2d_bytes"] * 7
+        # The warm-up's batch is assembled: the same 8 puts, none early.
+        assert seen == [("chunks", 8, 0)] + [("chunks", 8, 7 * 4 * 28 * 28 * 4)] * 4
+        assert compiled == [1]
+        events = [e for e in handle.executor.flight.events() if e[0] == "model.0"]
+        fires = [e[5] for e in events if e[1] == "fire"]
+        assert [a["early_chunks"] for a in fires] == [7] * 4
+        puts = [e[5] for e in events if e[1] == "early_put"]
+        assert [(a["seq"], a["chunk"]) for a in puts] == [
+            (seq, chunk) for seq in (a["seq"] for a in fires) for chunk in range(7)]
+        assert all(a["bytes"] == 4 * 28 * 28 * 4 for a in puts)
+        enqueues = [e[5] for e in events if e[1] == "enqueue"]
+        assert [a["early_bytes"] * 8 for a in enqueues] == [a["bytes"] * 7 for a in enqueues]
+
+    @pytest.mark.parametrize("case", ["two_int32_4096", "list_path", "wire_dtype"])
+    def test_anything_else_crosses_whole_in_one_put(self, lenet_model, monkeypatch, case):
+        """A window of two ``int32[4096]`` records, the list path, a narrowed
+        wire: one whole put a window, as before, and the counter reads 0."""
+        from flink_tensorflow_tpu.functions import runner as runner_module
+
+        seen = self._puts(monkeypatch)
+        if case == "two_int32_4096":
+            window = 2
+            model = _shape_model((4096,), np.int32)
+            records = [TensorValue({"x": np.full(4096, i, np.int32)}, {"i": i})
+                       for i in range(3 * window)]
+            kw = {}
+        else:
+            # Lenet's windows with the floor lowered to their size: only the
+            # list path, or the wire, keeps them whole.
+            monkeypatch.setattr(runner_module, "EARLY_CHUNK_MIN_BYTES", 1 << 10)
+            window = 32
+            model = lenet_model
+            rng = np.random.RandomState(5)
+            records = [TensorValue({"image": rng.rand(28, 28, 1).astype(np.float32)}, {"i": i})
+                       for i in range(3 * window)]
+            kw = {"use_ring": False} if case == "list_path" else {"wire_dtype": "bf16"}
+        results, metrics = self._served(
+            ModelWindowFunction(model, policy=BucketPolicy(fixed_batch=window), **kw),
+            records, window)
+        assert [r.meta["i"] for r in results] == list(range(len(records)))
+        assert seen == [("whole", window)] * 3
+        assert metrics["model.0.h2d_early_bytes"] == 0
+        assert metrics["model.0.h2d_bytes"] > 0
+        if case == "two_int32_4096":
+            assert [float(r["sum"]) for r in results] == [4096.0 * i + 1 for i in range(6)]

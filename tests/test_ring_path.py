@@ -154,9 +154,9 @@ class TestRingWindowPath:
         zero_copy = []
         dispatch_batch = CompiledMethodRunner.dispatch_batch
 
-        def spy(self, batch, *, assemble_s=None, on_done=None):
+        def spy(self, batch, *, on_done=None, **kw):
             zero_copy.append(on_done is not None)
-            return dispatch_batch(self, batch, assemble_s=assemble_s, on_done=on_done)
+            return dispatch_batch(self, batch, on_done=on_done, **kw)
 
         monkeypatch.setattr(CompiledMethodRunner, "dispatch_batch", spy)
         # Windows of 1 in batches of 4, padded in the ring: 80 slots claimed
@@ -261,3 +261,269 @@ class TestRingCheckpoint:
         # The restored run must re-serve at least the buffered (materialized)
         # window contents — it cannot be empty unless the stream finished.
         assert out2, "restored run emitted nothing"
+
+
+# -- early shipping: a filling window crosses the link chunk by chunk ----------
+EB = 32                      # the fixed batch of these tests: 8 chunks of 4 rows
+ROW_BYTES = 28 * 28 * 4
+
+
+@pytest.fixture(scope="module")
+def many_images():
+    rng = np.random.RandomState(23)
+    return [
+        TensorValue({"image": rng.rand(28, 28, 1).astype(np.float32)}, {"i": i})
+        for i in range(10 * EB + 9)
+    ]
+
+
+def _engage(monkeypatch):
+    """Lenet's windows are a hundred kilobytes: lower the floor under a chunk,
+    so that the runner's rule engages (the test steers; the program has no
+    option for it)."""
+    from flink_tensorflow_tpu.functions import runner
+
+    monkeypatch.setattr(runner, "EARLY_CHUNK_MIN_BYTES", 1 << 10)
+
+
+def _opened(model, **kw):
+    f = ModelWindowFunction(model, policy=BucketPolicy(fixed_batch=EB),
+                            warmup_batches=(EB,), **kw)
+    f.open(_ctx())
+    return f
+
+
+def _collector(results):
+    from flink_tensorflow_tpu.core import functions as fn
+
+    return fn.Collector(lambda value, timestamp=None: results.append(value))
+
+
+def _fill(f, records, out):
+    """What ``WindowOperator.process_record`` does with a window function that
+    ingests: the ring token where the ring took the record, else the record."""
+    elements = []
+    for r in records:
+        token = f.ingest_element(r, out)
+        elements.append(r if token is None else token)
+    return elements
+
+
+def _drive(f, records, sizes, *, head=()):
+    """Windows of ``sizes`` records, filled and fired by hand (a count that
+    is reached, or a timeout that fires a window short of it, is the same
+    call); ``head``: elements already buffered for the first window.
+    Returns the answers in order and the operator's counters."""
+    results = []
+    out = _collector(results)
+    it = iter(records)
+    elements = list(head)
+    try:
+        for n in sizes:
+            elements += _fill(f, [next(it) for _ in range(n)], out)
+            f.process_window(None, None, elements, out)
+            elements = []
+        f.on_finish(out)
+        counters = {k: f._metrics.counter(k).value
+                    for k in ("h2d_bytes", "h2d_early_bytes", "padded_records", "batches")}
+    finally:
+        f.close()
+    return results, counters
+
+
+def _same_answers(got, want):
+    assert [r.meta["i"] for r in got] == [r.meta["i"] for r in want]
+    for g, w in zip(got, want):
+        for name in ("logits", "label", "prob"):
+            assert np.array_equal(np.asarray(g[name]), np.asarray(w[name])), (g.meta, name)
+
+
+def _early_rows(sizes, rows=EB // 8):
+    """Rows of all-token windows that cross before their fire: whole chunks,
+    never the last of a full batch."""
+    return sum(min(n // rows, EB // rows - 1) * rows for n in sizes)
+
+
+class TestEarlyShipping:
+    @pytest.mark.parametrize("sizes, kw", [
+        pytest.param([EB] * 3, {}, id="full_windows"),
+        # Fired by the timeout short of the count: the tail chunks hold the
+        # padding rows; 13 records have 3 chunks shipped, 3 records none.
+        pytest.param([EB, 13, EB, 3, 4], {}, id="timeout_fired_short_windows"),
+        # 128 slots hold four windows: ten go two and a half times around.
+        pytest.param([EB] * 10, {"ring_capacity": 4 * EB}, id="across_the_arenas_wrap"),
+        pytest.param([EB] * 6, {"pipeline_depth": 2, "ring_capacity": 2 * EB},
+                     id="tiny_ring_depth_2"),
+    ])
+    def test_answers_bit_equal_to_one_put_a_window(self, lenet_model, many_images,
+                                                   monkeypatch, sizes, kw):
+        """Early shipping engaged against K = 1 (the window whole, in one put):
+        same records in the same order, every answer bit for bit, the same
+        padding; and every byte counted, (K - 1) / K of a full window early."""
+        whole_f = _opened(lenet_model, **kw)
+        assert whole_f.runner.chunk_rows is None
+        want, base = _drive(whole_f, many_images, sizes)
+        _engage(monkeypatch)
+        early_f = _opened(lenet_model, **kw)
+        assert early_f.runner.chunk_rows == EB // 8
+        got, counters = _drive(early_f, many_images, sizes)
+        assert len(got) == sum(sizes)
+        _same_answers(got, want)
+        assert base["h2d_early_bytes"] == 0
+        assert counters["h2d_bytes"] == base["h2d_bytes"] == len(sizes) * EB * ROW_BYTES
+        assert counters["padded_records"] == base["padded_records"] == len(sizes) * EB - sum(sizes)
+        assert counters["h2d_early_bytes"] == _early_rows(sizes) * ROW_BYTES
+
+    def test_through_end_of_input(self, lenet_model, many_images, monkeypatch):
+        """Through the window operator: full windows by count, the last one
+        (9 records, 2 chunks shipped) by end of input."""
+        images = many_images[:5 * EB + 9]
+        kw = dict(model=lenet_model, policy=BucketPolicy(fixed_batch=EB))
+        want = _run(kw, images, window=EB)
+        _engage(monkeypatch)
+        got = _run(kw, images, window=EB)
+        assert len(got) == len(images)
+        _same_answers(got, want)
+
+    def test_short_claim_at_the_arenas_end_copies_that_window_out(
+            self, lenet_model, many_images, monkeypatch):
+        """A snapshot's copy-out and a short window leave every later chunk one
+        row off the arena's grid: once a trip around, a chunk's claim comes
+        back short, and that window takes the copy path (drain, copy - the
+        rows already claimed first -, plain arrays, the runner's own puts)."""
+        sizes = [10] + [EB] * 6
+        want, _ = _drive(_opened(lenet_model, ring_capacity=2 * EB), many_images,
+                         [13] + sizes[1:])
+        _engage(monkeypatch)
+        f = _opened(lenet_model, ring_capacity=2 * EB)
+        copied = []
+        copy_out = f._copy_out
+
+        def spy(head, b, out):
+            copied.append(sum(n for _, n in head.claims))
+            return copy_out(head, b, out)
+
+        monkeypatch.setattr(f, "_copy_out", spy)
+        values = f.materialize_tokens(_fill(f, many_images[:3], _collector([])))
+        got, counters = _drive(f, many_images[3:], sizes, head=values)
+        _same_answers(got, want)
+        # The full windows start at slot 13 or 45 of 64: from 45, the fifth
+        # chunk is slots 61-63 and one more after the wrap.
+        assert copied == [4 * (EB // 8) + 3] * 3
+        assert counters["h2d_early_bytes"] == 3 * (EB - EB // 8) * ROW_BYTES
+
+    def test_snapshot_with_3_of_8_chunks_shipped(self, lenet_model, many_images, monkeypatch):
+        """An operator snapshot in the middle of a fill: the rows already
+        claimed are copied out of the retained views, then the rest; every
+        claim is released, the chunks on the device dropped.  The run goes on,
+        and a restored run starts, from those values: no record lost or
+        doubled, the same answers."""
+        n = 3 * (EB // 8) + 2
+        sizes = [EB, EB, EB]
+        want, _ = _drive(_opened(lenet_model), many_images, sizes)
+        _engage(monkeypatch)
+        f = _opened(lenet_model)
+        results = []
+        out = _collector(results)
+        f.process_window(None, None, _fill(f, many_images[:EB], out), out)
+        buffered = _fill(f, many_images[EB:EB + n], out)
+        assert len(f._early.device) == 3 and f._early.rows == 12
+        f._out = out
+        f.snapshot_state()                      # what is in flight is emitted first
+        assert [r.meta["i"] for r in results] == list(range(EB))
+        values = f.materialize_tokens(buffered)
+        assert f._early is None
+        assert f._ring.poppable() == 0 and f._ring._claim_ahead == 0
+        assert all(isinstance(v, TensorValue) for v in values)
+        for v, r in zip(values, many_images[EB:EB + n]):
+            assert v.meta == r.meta and np.array_equal(v["image"], r["image"])
+        # The run goes on: fresh tokens behind the values, a mixed window.
+        got, counters = _drive(f, many_images[EB + n:], [EB - n, EB], head=values)
+        _same_answers(results + got, want)
+        assert counters["h2d_early_bytes"] == 2 * (EB - EB // 8) * ROW_BYTES  # windows 1 and 3
+        # A restored run: the snapshot's values are its first window's head.
+        restored, _ = _drive(_opened(lenet_model), many_images[EB + n:], [EB - n, EB],
+                             head=values)
+        _same_answers(restored, want[EB:])
+
+    def test_window_turned_mixed_by_a_full_ring(self, lenet_model, many_images, monkeypatch):
+        """A window larger than the arena with nothing in flight: the ring
+        fills, the rest is list-buffered, and the mixed window is copied out
+        (the 28 rows shipped early first) and served from the list path."""
+        n = EB + 8
+        images = many_images[:n]
+        want = _run(dict(model=lenet_model, policy=BucketPolicy(fixed_batch=EB),
+                         use_ring=False), images, window=n)
+        _engage(monkeypatch)
+        f = _opened(lenet_model, ring_capacity=EB)
+        assert f._ring.capacity == EB
+        out = _collector([])
+        elements = _fill(f, images, out)
+        assert [isinstance(e, _RingToken) for e in elements] == [True] * EB + [False] * 8
+        assert len(f._early.device) == 7 and f._early.off
+        got, counters = _drive(f, [], [0], head=elements)
+        assert f._early is None
+        _same_answers(got, want)
+        assert counters["h2d_early_bytes"] == 0 and counters["batches"] == 2
+
+    @pytest.mark.parametrize("corner", ["close", "snapshot", "short_claim"])
+    def test_rows_put_early_are_released_only_once_their_puts_are_ready(
+            self, lenet_model, many_images, monkeypatch, corner):
+        """A ``device_put`` returns before the host has read the rows (on the
+        chip it re-lays them for tens of ms; on the CPU it aliases them, which
+        hides this).  Where a half-shipped window's chunks are dropped and
+        their rows released with no batch to collect - ``close()``, which then
+        frees the arena, a snapshot's copy-out, the copy path of a window split
+        at the arena's end - every chunk put is waited for first."""
+        _engage(monkeypatch)
+        log = []
+
+        class Pending:
+            nbytes = 0
+
+            def block_until_ready(self):
+                log.append("ready")
+                return self
+
+        f = _opened(lenet_model, ring_capacity=2 * EB)
+        out = _collector([])
+        if corner == "short_claim":
+            # 45 slots of 64 on, the window's fifth chunk is split by the arena's end.
+            values = f.materialize_tokens(_fill(f, many_images[:45], out))
+            f.process_window(None, None, values[:EB], out)
+        monkeypatch.setattr(f.runner, "put_chunk", lambda views: {"image": Pending()})
+        ring = f._ring
+        release, destroy = ring.release, ring.close
+        monkeypatch.setattr(ring, "release", lambda n: log.append("release") or release(n))
+        monkeypatch.setattr(ring, "close", lambda: log.append("close") or destroy())
+        try:
+            elements = _fill(f, many_images[:14], out)
+            assert len(f._early.device) == 3 and not log
+            if corner == "snapshot":
+                f.materialize_tokens(elements)
+            elif corner == "short_claim":
+                f.process_window(None, None, elements, out)
+        finally:
+            f.close()
+        assert log[:4] == ["ready"] * 3 + ["release"] and log[-1] == "close"
+        assert log.count("ready") == 3
+
+    def test_close_with_a_half_shipped_window(self, lenet_model, many_images, monkeypatch):
+        """``close()`` in the middle of a fill: the window's claims are
+        released (after the in-flight batches', oldest first) and its device
+        chunks dropped; the ring is left empty with no claim outstanding."""
+        _engage(monkeypatch)
+        f = _opened(lenet_model)
+        out = _collector([])
+        f.process_window(None, None, _fill(f, many_images[:EB], out), out)  # in flight
+        _fill(f, many_images[EB:EB + 12], out)
+        ring = f._ring
+        assert len(f._early.device) == 3
+        assert ring._claim_ahead in (12, EB + 12)  # the window in flight may be collected
+        left = []
+        destroy = ring.close
+        monkeypatch.setattr(ring, "close", lambda: left.append(
+            (ring.poppable(), ring._claim_ahead)) or destroy())
+        f.close()
+        assert left == [(0, 0)]
+        assert f._early is None and f._ring is None
