@@ -13,8 +13,8 @@ continuous ``roofline.*`` gauges (achieved FLOP/s, MFU, bound
 classification) and to diff the predicted compile-signature ladder
 against runtime jit cache misses.
 
-Estimation contract (kept honest by the predicted-vs-measured bench
-leg, BENCH_r14):
+Estimation contract (kept honest at run time by the predicted-vs-measured
+join of ``metrics/roofline.py``):
 
 - FLOPs: ``dot_general`` = 2·batch·M·N·K from the invar avals;
   ``conv_general_dilated`` = 2·out_elems·(kernel elems / out features);
@@ -409,7 +409,7 @@ def _cost_serving(t, op) -> OperatorCost:
                 kc, kc)
             # Mirrors decode_step under padding buckets: [S] int32
             # tokens + [S] int32 lengths + [S] bool mask up, [S]
-            # next-tokens down — the BENCH_r13 72 B check, generalized.
+            # next-tokens down (72 B a step at S=8).
             step_h2d = S * 4 + S * 4 + S * 1
         cost.entries.append(_entry_of(
             "decode_step", serving_signature("decode", S, 1), st_closed,

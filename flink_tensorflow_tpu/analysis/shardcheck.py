@@ -285,8 +285,7 @@ def _level_peak_bytes(jaxpr) -> int:
 def peak_activation_bytes(closed) -> int:
     """Max per-level liveness peak across the whole closed jaxpr — a
     static stand-in for XLA's temp-buffer high-water mark (XLA fuses and
-    rematerializes, so this is an upper-ish bound, not an exact figure;
-    the predicted-vs-measured bench leg keeps it honest)."""
+    rematerializes, so this is an upper-ish bound, not an exact figure)."""
     return max((_level_peak_bytes(level)
                 for level in _iter_levels(closed.jaxpr)), default=0)
 
@@ -699,9 +698,9 @@ def _audit_serving_operator(
                     "copies (2x HBM); align the model's cache dtype"),
                 node=t.name))
         # Predicted steady-state per-step h2d bytes — must mirror
-        # DecodeStepRunner.decode_step's accounting exactly (the
-        # predicted-vs-measured bench leg diffs this against the
-        # runtime step_h2d_bytes counter): padding_buckets on ships
+        # DecodeStepRunner.decode_step's accounting exactly
+        # (tests/test_roofline.py joins it against the runtime's
+        # measured bytes): padding_buckets on ships
         # [S] int32 tokens + [S] int32 lengths + [S] bool mask; the
         # paged runner ships the [S, C/page_tokens] int32 block tables
         # instead of the mask (liveness rides the sentinel page id).

@@ -405,7 +405,7 @@ class Connection:
         connection's lifetime — the sender-side memory (RSS proxy) a
         slow peer cost at its worst.  The flow-control acceptance bound
         (queue stays ≤ credit window × frame size under a stalled
-        consumer) and the overload bench read THIS, not the instant
+        consumer) reads THIS, not the instant
         depth, so a transient between two polls can't hide growth."""
         return self._peak_out_bytes
 

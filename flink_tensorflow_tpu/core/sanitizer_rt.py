@@ -577,9 +577,8 @@ class ConcurrencySanitizer:
         - ``align.block`` / ``align.unblock`` — barrier-alignment windows
           (recorded by the gate hooks above).
 
-        Lock-free: one dict bump + one deque append, so the capture cost
-        prices at tens of ns (bench.py's ``hb_record_ns`` row) and the
-        hook sites keep their single is-None guard when the sanitizer is
+        Lock-free: one dict bump + one deque append, and the hook
+        sites keep their single is-None guard when the sanitizer is
         off.
         """
         key = (kind, edge, conn)

@@ -94,7 +94,7 @@ EARLY_MAX_CHUNKS = 8
 def _build_decode_calls(prefill_fn, decode_fn, capacity: int):
     """Jitted (prefill_into, step_full, step_exact) per (model methods,
     capacity) — cached at MODULE level so every DecodeStepRunner built
-    over the same model (a restarted job, the bench's comparison arms,
+    over the same model (a restarted job, comparison arms,
     parallel subtasks) reuses the same callables and therefore jax's
     compiled executables: the 1-3s decode/prefill compiles are paid
     once per process, not once per operator open()."""
@@ -506,7 +506,7 @@ def _build_paged_calls(prefill_fn, decode_fn, capacity: int,
     """Jitted (paged_prefill_into, paged_step, copy_page) per (model
     methods, capacity, page geometry) — module-level cache for the same
     reason as :func:`_build_decode_calls`: restarted jobs, comparison
-    bench arms, and parallel subtasks all reuse the compiled
+    arms, and parallel subtasks all reuse the compiled
     executables.
 
     The paged step is gather -> dense kernel -> scatter

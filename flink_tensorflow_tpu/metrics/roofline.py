@@ -17,8 +17,8 @@ against it to publish continuous per-operator ``roofline.*`` gauges:
   memory by the larger busy-time utilization fraction.
 - ``roofline.busy_s`` — device-busy seconds attributed so far.
 - ``roofline.measured_h2d_per_call`` / ``roofline.predicted_h2d_per_call``
-  / ``roofline.h2d_drift_frac`` — the BENCH_r13 72 B = 72.0 B check,
-  generalized into a continuous signal.
+  / ``roofline.h2d_drift_frac`` — measured against predicted
+  host-to-device bytes per priced call, as a continuous signal.
 - ``roofline.compile_events`` / ``roofline.unpredicted_compiles`` —
   every runtime jit cache miss (first sight of a compile signature)
   lands on the flight recorder's ``compile`` track and the tracer's
@@ -34,8 +34,7 @@ evidence subset (metrics snapshot, Chrome trace, CostTable).
 
 Zero-cost-when-off, repo-wide convention: runners hold ``None`` and the
 hot path pays one ``is None`` test; the per-step ``observe()`` join is
-a dict lookup plus a handful of integer adds (priced next to
-``span_record_ns``/``flight_record_ns`` by the bench overhead probes).
+a dict lookup plus a handful of integer adds.
 """
 
 from __future__ import annotations

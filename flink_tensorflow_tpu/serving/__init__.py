@@ -16,7 +16,7 @@ other keyed state.  The pieces:
   decode-step loop) and :func:`continuous_batching` (the DataStream
   entry point).
 - :mod:`baseline` — ``FixedWindowGenerateFunction``, the fixed
-  count-window comparison arm the bench measures against.
+  count-window comparison arm.
 - :mod:`paged` — ``PagedKVPool`` (page-granular HBM cache economy with
   per-session block tables) and ``RadixPrefixIndex`` (sessions sharing
   a prompt prefix share pages, copy-on-write at divergence).

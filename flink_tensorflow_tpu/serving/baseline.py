@@ -3,7 +3,7 @@
 What the repo's five baseline workloads would do with generation today:
 buffer requests into a count window (the BiLSTM micro-batch idiom),
 then run the WHOLE batch to completion before emitting anything.  Two
-structural costs the bench exposes against continuous batching:
+structural costs against continuous batching:
 
 - **time-to-first-token** pays the window fill wait plus a full batch
   generation (every session waits for the batch's LONGEST sequence);
@@ -12,8 +12,8 @@ structural costs the bench exposes against continuous batching:
   next window.
 
 Shares the model, DecodeStepRunner, and bucket config with the
-continuous path, so the bench's arm delta is attributable to the
-scheduling policy alone.
+continuous path, so a difference between the two arms is
+attributable to the scheduling policy alone.
 """
 
 from __future__ import annotations

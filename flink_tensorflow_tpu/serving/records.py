@@ -3,7 +3,7 @@
 Requests ride the record plane like any other value: picklable, keyed by
 ``session_id``, and carrying their scheduling metadata in ``meta`` (the
 open-loop paced sources stamp ``meta["sched_ts"]`` through the same
-``with_meta`` hook TensorValue exposes, so the bench measures serving
+``with_meta`` hook TensorValue exposes, so a sink measures serving
 latency against the arrival schedule, coordinated-omission-free).
 Responses stream back as one :class:`TokenEvent` per generated token —
 time-to-first-token is simply the latency of ``index == 0``.
@@ -50,8 +50,8 @@ class TokenEvent:
     ``index`` is the 0-based position within the continuation (so
     ``index == 0`` marks first-token latency); ``finished`` is True on
     the session's LAST token (max_new_tokens reached or eos emitted).
-    ``meta`` carries the request's meta through (``sched_ts`` for the
-    bench's open-loop latency accounting).
+    ``meta`` carries the request's meta through (``sched_ts`` for
+    open-loop latency accounting).
     """
 
     session_id: typing.Any

@@ -55,8 +55,8 @@ class ServingConfig:
     #: pays a d2h and re-admission an h2d per block.
     device_resident_blocks: bool = True
     #: Pre-compile every prefill bucket + the decode step at open(), so
-    #: no live session pays an XLA compile inside its latency (the
-    #: bench arms run warmed; tests keep it off for speed).
+    #: no live session pays an XLA compile inside its latency (tests
+    #: keep it off for speed).
     warmup_compile: bool = False
     #: Admission hysteresis: with a deep backlog, hold admissions until
     #: this many slots are free so waiting prefills batch into ONE

@@ -5,8 +5,7 @@ stride (subtask i decodes records ``i, i+N, ...`` of the concatenation),
 each FILE — or, with ``records_per_split``, each record RANGE within a
 file — is one :class:`FileSplit` that any reader can pull.  Skewed file
 sizes stop mattering: the reader stuck on the big file keeps reading it
-while its peers drain the small ones (the bench's work-stealing
-demonstration, ``bench.py --workload filesplit``).
+while its peers drain the small ones (work stealing).
 
 Replay skips cheaply: frames are length-prefixed, so seeking to
 ``start + offset`` walks headers without decoding payloads (the same

@@ -1,6 +1,6 @@
 """PacedSplitSource — open-loop arrival process on the split API.
 
-The split-based successor of ``io.sources.PacedSource`` (the bench's
+The split-based successor of ``io.sources.PacedSource`` (the
 coordinated-omission-free arrival model): records are due on a schedule
 regardless of pipeline progress, and each emitted record carries its
 SCHEDULED time in ``meta[ts_key]`` so sinks measure latency against the
@@ -17,7 +17,7 @@ timer-driven members in source chains.
 
 ``cycles=None`` makes the source UNBOUNDED: the enumerator re-issues the
 data's range splits cycle after cycle until the job is cancelled — the
-bench's run-forever open-loop mode.
+run-forever open-loop mode.
 """
 
 from __future__ import annotations

@@ -5,8 +5,8 @@ opt-in; the flight recorder is the black box that is on by DEFAULT
 (``JobConfig.flight_recorder``): a bounded per-process ring of recent
 CONTROL-RATE events — job/subtask lifecycle, barrier injections and
 snapshots, failures, and per-report metric deltas — recorded at a cost
-bounded by one tuple append (priced next to ``span_record_ns`` in
-BENCH_r08.json).  When something goes wrong the ring is dumped to disk:
+bounded by one tuple append.  When something goes wrong the ring is
+dumped to disk:
 
 - **crash** — the first subtask failure (extends PR 6's crash-time
   reporter flush);

@@ -1,5 +1,5 @@
-"""Platform selection shared by every entry point (bench, graft hooks,
-examples, tests, chip_smoke).
+"""Platform selection shared by every entry point (benchmark, graft
+hooks, examples, tests, chip_smoke).
 
 Tests and the CPU children of the cohort launchers need N virtual CPU
 devices whatever platform the machine exports; everything else runs on

@@ -290,6 +290,52 @@ class TestManualTriggerForbidden:
             handle.wait(60)
 
 
+class TestFirstCommitGate:
+    def test_first_commit_gate_keeps_full_connect_window(self, monkeypatch):
+        """ADVICE r4: the durability gate's 5s fast-fail connect cap must
+        not apply to the FIRST cohort-wide exchange — a peer's shuffle
+        server can legitimately still be in its cold-compile window, and
+        a spuriously failed gate withholds the first 2PC commit.  Once an
+        announce reached every peer, later (re)connects fail fast."""
+        import threading as _threading
+
+        from flink_tensorflow_tpu.core import distributed as dist_mod
+        from flink_tensorflow_tpu.core.distributed import (
+            DistributedConfig, DistributedExecutor)
+
+        seen_timeouts = []
+
+        class _StubWriter:
+            def __init__(self, host, port, task, sender, channel,
+                         connect_timeout_s, epoch=0):
+                seen_timeouts.append(connect_timeout_s)
+
+            def write(self, payload):
+                pass
+
+        monkeypatch.setattr(dist_mod, "RemoteChannelWriter", _StubWriter)
+        ex = DistributedExecutor.__new__(DistributedExecutor)
+        ex.dist = DistributedConfig(
+            process_index=0, num_processes=2,
+            peers=("127.0.0.1:1", "127.0.0.1:2"),
+            connect_timeout_s=60.0).validate()
+        ex.cancelled = _threading.Event()
+        ex._control_writers = {}
+        ex._control_writers_lock = _threading.Lock()
+        ex._participants = {0, 1}
+        ex._durable_cv = _threading.Condition()
+        ex._durable_acks = {1: {1}, 2: {1}}  # peer already announced
+        ex.checkpoint_timeout_s = 5.0
+        ex._gate_warmed = False
+
+        assert ex._global_commit_gate(1) is True
+        assert seen_timeouts == [60.0]  # first gate: full window
+        assert ex._gate_warmed is True
+        ex._control_writers.clear()  # simulate a dropped cached writer
+        assert ex._global_commit_gate(2) is True
+        assert seen_timeouts == [60.0, 5.0]  # warmed: fast-fail cap
+
+
 def _spawn(index, ports, out, chk=None, n=80, every=20, restore_id=-1,
            throttle=0.0, job="keyed_sum", window=5, par=2):
     cmd = [

@@ -2,6 +2,9 @@
 end-to-end shape (SURVEY.md §4): a bounded stream through a model operator
 with a tiny model, asserting exact outputs."""
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -447,3 +450,289 @@ class TestEarlyShippingRule:
         assert metrics["model.0.h2d_bytes"] > 0
         if case == "two_int32_4096":
             assert [float(r["sum"]) for r in results] == [4096.0 * i + 1 for i in range(6)]
+
+
+class TestStageTiling:
+    def test_stage_boundaries_telescope(self):
+        """The runner's stamps must tile t0..t_done with no overlap and
+        no gap — and lane_wait must CONTAIN assemble (the review found a
+        double-count where h2d_dispatch re-added assemble_s)."""
+        import jax
+
+        from flink_tensorflow_tpu.functions.runner import CompiledMethodRunner
+        from flink_tensorflow_tpu.models import get_model_def
+        from flink_tensorflow_tpu.tensors import (
+            BucketLadder,
+            BucketPolicy,
+            TensorValue,
+        )
+
+        mdef = get_model_def("lenet", num_classes=10)
+        model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
+        from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
+
+        r = CompiledMethodRunner(
+            model, policy=BucketPolicy(batch=BucketLadder.up_to(4)),
+            dispatch_lanes=2)
+        r.open(None)
+        try:
+            r.warmup([1, 2, 4])
+            ring = FlightRecorder()
+            r._spans, r._trace_track = SpanHook(ring), "lenet.0"
+            rng = np.random.RandomState(0)
+            r.run_batch([
+                TensorValue({"image": rng.rand(28, 28, 1).astype(np.float32)})
+                for _ in range(3)
+            ])
+            # The cuts the stamps carried, read from the batch's spans.
+            st = {e[1]: (e[3], e[3] + e[4], e[5]) for e in ring.events()}
+            t0, t_lane_start, lane = st["lane_wait"]
+            t_lane_start_2, t_dispatched, _ = st["enqueue"]
+            t_dispatched_2, t_done, fly = st["in_flight"]
+            t_fetch_start = t_dispatched_2 + fly["fetch_reached_s"]
+            # Boundaries are monotone and the intervals tile exactly.
+            assert t0 <= t_lane_start <= t_dispatched
+            assert t_dispatched_2 <= t_fetch_start <= t_done + 1e-6
+            assert abs(t_lane_start_2 - t_lane_start) < 1e-9
+            assert abs(t_dispatched_2 - t_dispatched) < 1e-9
+            tiled = ((t_lane_start - t0) + (t_dispatched - t_lane_start_2)
+                     + (t_done - t_dispatched_2))
+            assert abs(tiled - (t_done - t0)) < 1e-9
+            # assemble happens INSIDE the lane interval, not after it.
+            assert 0 < lane["assemble_s"] <= t_lane_start - t0 + 1e-9
+        finally:
+            r.close()
+
+
+def _lenet_runner(**kw):
+    import jax
+
+    from flink_tensorflow_tpu.functions.runner import CompiledMethodRunner
+    from flink_tensorflow_tpu.models import get_model_def
+    from flink_tensorflow_tpu.tensors import BucketLadder, BucketPolicy
+
+    mdef = get_model_def("lenet", num_classes=10)
+    model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
+    r = CompiledMethodRunner(
+        model, policy=BucketPolicy(batch=BucketLadder.up_to(8)), **kw)
+    r.open(None)
+    r.warmup([1, 2, 4, 8])
+    return r
+
+
+def _recs(n):
+    from flink_tensorflow_tpu.tensors import TensorValue
+
+    rng = np.random.RandomState(0)
+    return [
+        TensorValue({"image": rng.rand(28, 28, 1).astype(np.float32)},
+                    {"id": i})
+        for i in range(n)
+    ]
+
+
+class TestBackgroundFetch:
+    """VERDICT r4 #2 / weak #1: the d2h fetch must overlap the wait, not
+    serialize after it — a background fetch thread completes batches
+    with NO collect call from the subtask thread."""
+
+    def test_results_complete_without_any_collect_call(self):
+        r = _lenet_runner(dispatch_lanes=2)
+        try:
+            r.dispatch(_recs(2))
+            deadline = time.monotonic() + 10.0
+            # has_completed flips by background action alone.
+            while not r.has_completed() and time.monotonic() < deadline:
+                time.sleep(0.002)
+            assert r.has_completed()
+            out = r.collect_available()
+            assert len(out) == 2
+        finally:
+            r.close()
+
+    def test_on_results_ready_fires_per_completed_batch(self):
+        r = _lenet_runner(dispatch_lanes=1)
+        hits = []
+        r.on_results_ready = lambda: hits.append(time.monotonic())
+        try:
+            r.dispatch(_recs(2))
+            r.dispatch(_recs(1))
+            deadline = time.monotonic() + 10.0
+            while len(hits) < 2 and time.monotonic() < deadline:
+                time.sleep(0.002)
+            assert len(hits) == 2
+            assert len(r.collect_available()) == 3
+        finally:
+            r.close()
+
+    def test_deferred_on_done_runs_on_collecting_thread(self):
+        """Ring releases must stay on the SPSC consumer thread: on_done
+        runs at COLLECTION (subtask thread), not on the fetch thread."""
+        from flink_tensorflow_tpu.tensors.batching import assemble, BucketPolicy
+
+        r = _lenet_runner(dispatch_lanes=1)
+        done_threads = []
+        try:
+            recs = _recs(2)
+            batch = assemble(recs, r.method.input_schema,
+                             BucketPolicy(fixed_batch=2))
+            r.dispatch_batch(
+                batch, on_done=lambda: done_threads.append(
+                    threading.current_thread()))
+            deadline = time.monotonic() + 10.0
+            while not r.has_completed() and time.monotonic() < deadline:
+                time.sleep(0.002)
+            assert not done_threads  # fetched, but release deferred
+            out = r.collect_available()
+            assert len(out) == 2
+            assert done_threads == [threading.main_thread()]
+        finally:
+            r.close()
+
+    def test_stage_cuts_are_per_batch_and_records_share_nothing(self):
+        """VERDICT r4 weak #5, after the stamps went: a batch's cuts live
+        in its own spans (one ``args`` dict a span, none shared between
+        batches), and each record still owns its metadata."""
+        from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
+
+        r = _lenet_runner(dispatch_lanes=1)
+        ring = FlightRecorder()
+        r._spans, r._trace_track = SpanHook(ring), "lenet.0"
+        try:
+            out = r.run_batch(_recs(3))
+            out[0].meta["t0"] = -1.0
+            assert "t0" not in out[1].meta and "t0" not in out[2].meta
+            r.run_batch(_recs(2))
+            flights = [e[5] for e in ring.events() if e[1] == "in_flight"]
+            assert [a["batch"] for a in flights] == [3, 2]
+            assert flights[0]["seq"] + 1 == flights[1]["seq"]
+            args = [e[5] for e in ring.events() if e[5] is not None]
+            assert len({id(a) for a in args}) == len(args)
+        finally:
+            r.close()
+
+    def test_next_deadline_immediate_when_results_wait(self):
+        """Completed results make the window function due in the past
+        (0.0), so the subtask loop's earlier `now` still fires it."""
+        import jax
+
+        from flink_tensorflow_tpu.functions import ModelWindowFunction
+        from flink_tensorflow_tpu.models import get_model_def
+        from flink_tensorflow_tpu.tensors import BucketLadder, BucketPolicy
+        from flink_tensorflow_tpu.core import functions as fn
+
+        mdef = get_model_def("lenet", num_classes=10)
+        model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
+        svc = ModelWindowFunction(
+            model, policy=BucketPolicy(batch=BucketLadder.up_to(8)),
+            warmup_batches=(2,), transfer_lanes=2, pipeline_depth=8,
+            idle_flush_s=30.0)  # poll interval alone would strand results
+        emitted = []
+        out = fn.Collector(lambda v, ts=None: emitted.append(v))
+        svc.open(None)
+        try:
+            svc._out = out
+            svc.process_window(None, None, _recs(2), out)
+            deadline = time.monotonic() + 10.0
+            while not svc.runner.has_completed() and time.monotonic() < deadline:
+                time.sleep(0.002)
+            assert svc.next_deadline() == 0.0
+            svc.fire_due(time.monotonic())
+            assert len(emitted) == 2
+        finally:
+            svc.close()
+
+    def test_completion_wake_does_not_flush_partial_microbatch(self):
+        """A completion-driven fire (deadline 0.0) must drain results
+        but NOT dispatch the async map's partial micro-batch — under
+        steady load that would flush a padded partial batch at every
+        completion, defeating micro-batching.  Only the idle-flush
+        deadline proper dispatches the buffer."""
+        import jax
+
+        from flink_tensorflow_tpu.functions import ModelMapFunction
+        from flink_tensorflow_tpu.models import get_model_def
+        from flink_tensorflow_tpu.core import functions as fn
+
+        mdef = get_model_def("lenet", num_classes=10)
+        model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
+        f = ModelMapFunction(model, micro_batch=8, idle_flush_s=0.5,
+                             transfer_lanes=1)
+        emitted = []
+        out = fn.Collector(lambda v, ts=None: emitted.append(v))
+        f.open(None)
+        try:
+            recs = _recs(11)
+            for r in recs[:8]:  # fills the micro-batch -> dispatches
+                f.map_async(r, out)
+            for r in recs[8:]:  # partial: stays buffered
+                f.map_async(r, out)
+            assert len(f._buf) == 3
+            deadline = time.monotonic() + 10.0
+            while not f.runner.has_completed() and time.monotonic() < deadline:
+                time.sleep(0.002)
+            # Completion wake: results drain, the partial buffer stays.
+            f.fire_due(time.monotonic())
+            assert len(emitted) == 8
+            assert len(f._buf) == 3
+            # Idle deadline passed: NOW the partial dispatches.
+            f.fire_due(time.monotonic() + f._idle_flush_s + 0.01)
+            assert not f._buf
+            f.flush(out)
+            assert len(emitted) == 11
+        finally:
+            f.close()
+
+    def test_fetch_thread_stress_fifo_and_completeness(self):
+        """Concurrency shakeout for the fetch-thread path: many small
+        batches through both lane modes with a mixed, randomly-timed
+        collect pattern (available/ready/progress/defer) must deliver
+        every record exactly once, in dispatch order, with nothing left
+        pending — and close() must not deadlock regardless of where the
+        pattern stopped."""
+        import random
+
+        rng = random.Random(7)
+        for lanes in (1, 3):
+            r = _lenet_runner(dispatch_lanes=lanes)
+            try:
+                total = 120
+                recs = _recs(total)
+                out = []
+                i = 0
+                while i < total:
+                    n = rng.choice((1, 2, 3))
+                    r.dispatch(recs[i:i + n])
+                    i += n
+                    mode = rng.random()
+                    if mode < 0.35:
+                        out.extend(r.collect_available())
+                    elif mode < 0.6:
+                        out.extend(r.collect_ready(rng.choice((1, 2, 4))))
+                    elif mode < 0.8:
+                        out.extend(r.collect_progress(rng.choice((1, 2, 4))))
+                    # else: defer — let batches pile up for later modes
+                    if rng.random() < 0.2:
+                        time.sleep(0.002)
+                out.extend(r.flush())
+                assert [v.meta["id"] for v in out] == list(range(total))
+                assert not r._pending and not r.has_completed()
+            finally:
+                r.close()
+
+    def test_gate_wake_breaks_poll_sleep(self):
+        """InputGate.wake() returns a blocked poll immediately, losing
+        no stream elements."""
+        from flink_tensorflow_tpu.core.channels import InputGate
+        from flink_tensorflow_tpu.core import elements as el
+
+        gate = InputGate(num_channels=1)
+        t0 = time.monotonic()
+        threading.Timer(0.05, gate.wake).start()
+        got = gate.poll(timeout=5.0)
+        waited = time.monotonic() - t0
+        assert got is None and waited < 2.0
+        # A real element queued after a wake still arrives intact.
+        gate.put(0, el.StreamRecord("x"))
+        idx, element = gate.poll(timeout=1.0)
+        assert idx == 0 and element.value == "x"

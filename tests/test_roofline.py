@@ -313,7 +313,7 @@ class TestServingEndToEnd:
         assert row["busy_s"] > 0
         assert row["compile_events"] >= 2  # prefill + decode signatures
         assert row["unpredicted_compiles"] == 0
-        # The BENCH_r13 h2d check, generalized: measured joins exactly.
+        # Measured h2d bytes a call join the plan's prediction exactly.
         assert row["predicted_h2d_per_call"] > 0
         assert (row["measured_h2d_per_call"]
                 == pytest.approx(row["predicted_h2d_per_call"]))

@@ -5,21 +5,17 @@
    never write proc-* shards, yet completeness required process indices
    {0..P-1}.  Shards now record the PARTICIPANT set (processes owning
    >= 1 subtask) and completeness is validated against it.
-2. (low) A degenerate compute probe put float('nan') into the bench
-   JSON (non-RFC-8259).  bench.py now emits None and dumps with
-   allow_nan=False behind a recursive NaN/inf sanitizer.
-3. (low) MapOperator flushes the async micro-batch before every
+2. (low) MapOperator flushes the async micro-batch before every
    watermark; with watermark_every=1 that silently degrades to
    batch-of-1 — now documented on ModelMapFunction (behavioral pin
    below: the flush itself must still happen, it is load-bearing for
    event-time safety).
-4. (low) The global commit gate could stall teardown: no cancellation
+3. (low) The global commit gate could stall teardown: no cancellation
    check before/between peer announcements, and a control writer's
    connect-retry loop ignored close().  Both paths now abort promptly.
 """
 
 import json
-import math
 import os
 import signal
 import socket
@@ -162,32 +158,6 @@ class TestOverprovisionedCohortEndToEnd:
             rc, log = _wait(p)
             assert rc == 0, f"restored worker failed:\n{log}"
         assert _read_sorted(out) == expected_emissions(240)
-
-
-class TestBenchJsonStrict:
-    def test_json_safe_maps_nan_inf_to_none(self):
-        import bench
-
-        dirty = {"a": float("nan"), "b": [1.0, float("inf")],
-                 "c": {"d": -float("inf"), "e": 2}, "f": "nan"}
-        clean = bench._json_safe(dirty)
-        assert clean == {"a": None, "b": [1.0, None],
-                         "c": {"d": None, "e": 2}, "f": "nan"}
-        # The pinned invariant: the emitted line parses under strict mode.
-        line = json.dumps(clean, allow_nan=False)
-        assert json.loads(line) == clean
-
-    def test_degenerate_compute_probe_emits_null_not_nan(self):
-        """The original finding's exact site: compute_rps=None must
-        produce device_compute_s: null."""
-        compute_rps = None
-        batch_compute_s = 64 / compute_rps if compute_rps else None
-        assert batch_compute_s is None
-        out = {"device_compute_s": (
-            round(batch_compute_s, 5) if batch_compute_s is not None else None)}
-        assert "NaN" not in json.dumps(out, allow_nan=False)
-        assert not any(
-            isinstance(v, float) and not math.isfinite(v) for v in out.values())
 
 
 class TestWatermarkFlushStillLoadBearing:
