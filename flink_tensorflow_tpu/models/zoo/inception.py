@@ -29,18 +29,28 @@ from flink_tensorflow_tpu.tensors.schema import RecordSchema, spec
 
 
 class ConvBN(nn.Module):
-    """conv -> batchnorm -> relu, the Inception "BasicConv2d" unit."""
+    """conv -> batchnorm -> relu, the Inception "BasicConv2d" unit.
+
+    ``avg_pool`` is the pool branch of the Inception blocks, a 3x3 average
+    in front of a 1x1 conv.  The average and a bias-free 1x1 conv commute
+    (one is linear over space, the other over channels), so the unit
+    projects first and averages the 32-192 channels the conv leaves, not
+    the block's 192-2,048: conv -> avg_pool -> batchnorm -> relu.
+    """
 
     features: int
     kernel: typing.Tuple[int, int]
     strides: typing.Tuple[int, int] = (1, 1)
     padding: typing.Any = "VALID"
     compute_dtype: jnp.dtype = jnp.bfloat16
+    avg_pool: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         x = nn.Conv(self.features, self.kernel, strides=self.strides,
                     padding=self.padding, use_bias=False, dtype=self.compute_dtype)(x)
+        if self.avg_pool:
+            x = _avg_pool_same(x)
         x = nn.BatchNorm(use_running_average=not train, momentum=0.9997,
                          epsilon=1e-3, dtype=self.compute_dtype)(x)
         return nn.relu(x)
@@ -63,7 +73,7 @@ class InceptionA(nn.Module):
         b3 = c(64, (1, 1))(x, train)
         b3 = c(96, (3, 3), padding="SAME")(b3, train)
         b3 = c(96, (3, 3), padding="SAME")(b3, train)
-        bp = c(self.pool_features, (1, 1))(_avg_pool_same(x), train)
+        bp = c(self.pool_features, (1, 1), avg_pool=True)(x, train)
         return jnp.concatenate([b1, b5, b3, bp], axis=-1)
 
 
@@ -100,7 +110,7 @@ class InceptionB(nn.Module):
         bd = c(c7, (1, 7), padding="SAME")(bd, train)
         bd = c(c7, (7, 1), padding="SAME")(bd, train)
         bd = c(192, (1, 7), padding="SAME")(bd, train)
-        bp = c(192, (1, 1))(_avg_pool_same(x), train)
+        bp = c(192, (1, 1), avg_pool=True)(x, train)
         return jnp.concatenate([b1, b7, bd, bp], axis=-1)
 
 
@@ -136,7 +146,7 @@ class InceptionC(nn.Module):
         bd = c(384, (3, 3), padding="SAME")(bd, train)
         bda = c(384, (1, 3), padding="SAME")(bd, train)
         bdb = c(384, (3, 1), padding="SAME")(bd, train)
-        bp = c(192, (1, 1))(_avg_pool_same(x), train)
+        bp = c(192, (1, 1), avg_pool=True)(x, train)
         return jnp.concatenate([b1, b3a, b3b, bda, bdb, bp], axis=-1)
 
 

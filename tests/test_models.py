@@ -6,12 +6,16 @@ Everything is jitted: eager per-op dispatch is pathologically slow in this
 environment, and the framework's production path is always-compiled anyway
 (the model runner jits per batch bucket)."""
 
+import json
+import pathlib
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from flink_tensorflow_tpu.analysis.shardcheck import _iter_levels
 from flink_tensorflow_tpu.models import (
     GraphLoader,
     SavedModelLoader,
@@ -19,6 +23,7 @@ from flink_tensorflow_tpu.models import (
     get_model_def,
     save_bundle,
 )
+from flink_tensorflow_tpu.models.zoo import inception
 
 
 @pytest.fixture(scope="module")
@@ -73,6 +78,72 @@ class TestZoo:
         outf = jax.jit(mdeff.methods["serve"].fn)(params, {"image": jnp.asarray(imgf)})
         np.testing.assert_allclose(np.asarray(out8["logits"]),
                                    np.asarray(outf["logits"]), atol=0.25)
+
+    @pytest.mark.parametrize("train", [False, True], ids=["serve", "train"])
+    @pytest.mark.parametrize("block,unit,features", [
+        (inception.InceptionA(32, jnp.float32), "ConvBN_6", 32),
+        (inception.InceptionB(128, jnp.float32), "ConvBN_9", 192),
+        (inception.InceptionC(jnp.float32), "ConvBN_8", 192),
+    ], ids=["A", "B", "C"])
+    def test_inception_pool_branch_is_average_then_project(self, block, unit,
+                                                           features, train):
+        """The pool branch projects first and averages second; the average
+        and the bias-free 1x1 commute, so it answers as the textbook order
+        (average the block's input, then conv, norm, relu) does from the
+        same variables, and in training leaves the same batch_stats."""
+        x = jax.random.normal(jax.random.key(1), (2, 9, 9, 24))
+        variables = jax.jit(lambda k: block.init(k, x))(jax.random.key(2))
+        # A fresh norm is the identity: give every leaf a value of its own
+        # (variances stay positive).
+        leaves, treedef = jax.tree_util.tree_flatten(variables)
+        keys = jax.random.split(jax.random.key(3), len(leaves))
+        variables = jax.tree_util.tree_unflatten(treedef, [
+            leaf + 0.5 * jax.random.uniform(k, leaf.shape) for leaf, k in zip(leaves, keys)])
+        plain = inception.ConvBN(features, (1, 1), compute_dtype=jnp.float32)
+        of_unit = {col: tree[unit] for col, tree in variables.items()}
+
+        @jax.jit
+        def both(variables, x):
+            out, new = block.apply(variables, x, train, mutable=["batch_stats"])
+            ref, ref_new = plain.apply(of_unit, inception._avg_pool_same(x), train,
+                                       mutable=["batch_stats"])
+            return out[..., -features:], new["batch_stats"][unit], ref, ref_new["batch_stats"]
+
+        got, got_stats, ref, ref_stats = both(variables, x)
+        assert float(jnp.max(ref)) > 0.1  # the relu has not zeroed the case
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+        for a, b in zip(jax.tree_util.tree_leaves(got_stats),
+                        jax.tree_util.tree_leaves(ref_stats)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        if train:  # and the norm did see a batch
+            was = variables["batch_stats"][unit]["BatchNorm_0"]["mean"]
+            assert float(jnp.max(jnp.abs(got_stats["BatchNorm_0"]["mean"] - was))) > 0
+
+    def test_inception_v3_variable_tree_is_the_recorded_one(self):
+        """Saved bundles, the TF import path and the benchmark's rename
+        rules address leaves by path: the 472 paths and shapes recorded from
+        the tree before the pool branches were reordered still hold."""
+        mdef = get_model_def("inception_v3")
+        tree = jax.eval_shape(mdef.init_fn, jax.random.key(0))
+        got = sorted(["/".join(k.key for k in path), list(leaf.shape), str(leaf.dtype)]
+                     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0])
+        want = json.loads(
+            pathlib.Path(__file__).with_name("inception_v3_tree.json").read_text())
+        assert len(want) == 472
+        assert got == want
+
+    def test_inception_v3_averages_only_projected_channels(self):
+        """Nine 3x3 averages a step, each over what its block's 1x1 leaves
+        (32, 64, 64, 192 x6 channels), none over a block's whole input."""
+        mdef = get_model_def("inception_v3", uint8_input=True)
+        params = jax.eval_shape(mdef.init_fn, jax.random.key(0))
+        image = jax.ShapeDtypeStruct((1, 299, 299, 3), jnp.uint8)
+        jaxpr = jax.make_jaxpr(mdef.methods["serve"].fn)(params, {"image": image})
+
+        pooled = [eqn.invars[0].aval.shape[-1]
+                  for level in _iter_levels(jaxpr.jaxpr) for eqn in level.eqns
+                  if eqn.primitive.name == "reduce_window_sum"]
+        assert pooled == [32, 64, 64] + [192] * 6
 
     def test_bilstm_padding_invariance(self, rng):
         """Same sequence padded to different buckets -> same logits: the
