@@ -1116,6 +1116,10 @@ class CompiledMethodRunner:
         #: Real positions of the input field ``tokens`` (where the method
         #: has one) are counted a batch: never padding.
         self._counts_tokens = "tokens" in self.method.input_schema.names
+        #: Outputs of the method that are counts made on the device
+        #: (ModelMethod.count_names): fetched with every batch whatever
+        #: ``output_names`` selects, added into counters, never emitted.
+        self._count_names = tuple(self.method.count_names)
         self._jit_fn = None
         self._transfer: typing.Optional[DeviceTransfer] = None
         #: Rows of one input chunk where batches cross the link in chunks
@@ -1199,6 +1203,8 @@ class CompiledMethodRunner:
 
         method = self.method
         select = self.output_names
+        if select is not None:
+            select = (*select, *(n for n in self._count_names if n not in select))
         schema = method.input_schema
         # Device-side dtype restore: fields a narrowed wire (or an
         # upstream device batch) delivers in a different dtype are cast
@@ -1665,6 +1671,7 @@ class CompiledMethodRunner:
                 batch, outputs, timings, on_done, t_fetch_start)
         host = DeviceTransfer.fetch(outputs)  # blocks on this batch only
         t_done = time.monotonic()
+        timings["counts"] = self._take_counts(host, batch.valid)
         results = batch.unbatch(host)
         t_unbatched = time.monotonic()
         dt = t_done - timings["t0"]
@@ -1696,8 +1703,7 @@ class CompiledMethodRunner:
                 self._metrics.counter("wire_bytes_saved").inc(
                     timings["wire_saved"])
             self._metrics.counter("batches").inc()
-            if timings.get("tokens") is not None:
-                self._metrics.counter("tokens").inc(timings["tokens"])
+            self._count(timings)
             self._metrics.counter("padded_records").inc(batch.padded_size - batch.num_records)
         if self._roofline is not None:
             # Busy time = the compute span (launch -> fetch reached);
@@ -1741,7 +1747,27 @@ class CompiledMethodRunner:
         # is a number here and no longer a cut between two spans.
         spans.span(track, "in_flight", timings["t_dispatched"], t_done,
                    {"seq": seq, "batch": n, "fetch_reached_s":
-                    round(t_fetch_start - timings["t_dispatched"], 6), **tokens})
+                    round(t_fetch_start - timings["t_dispatched"], 6), **tokens,
+                    **timings["counts"]})
+
+    def _take_counts(self, outputs: dict, valid) -> typing.Dict[str, int]:
+        """Takes the method's count outputs out of ``outputs`` (which then
+        holds answers only) and returns them as numbers: a ``[B]`` count
+        summed over the real records, a scalar as it is."""
+        import numpy as np
+
+        counts = {}
+        for name in self._count_names:
+            value = np.asarray(outputs.pop(name))
+            counts[name] = int(value[valid].sum() if value.ndim else value)
+        return counts
+
+    def _count(self, timings) -> None:
+        """One batch's real tokens and device-made counts into the registry."""
+        if timings.get("tokens") is not None:
+            self._metrics.counter("tokens").inc(timings["tokens"])
+        for name, value in timings["counts"].items():
+            self._metrics.counter(name).inc(value)
 
     def _complete_device(self, batch, outputs, timings, on_done,
                          t_fetch_start: float):
@@ -1756,6 +1782,8 @@ class CompiledMethodRunner:
 
         jax.block_until_ready(outputs)
         t_done = time.monotonic()
+        outputs = dict(outputs)
+        timings["counts"] = self._take_counts(outputs, batch.valid)
         n = batch.num_records
         dt = t_done - timings["t0"]
         self.service_ewma_s = (
@@ -1785,8 +1813,7 @@ class CompiledMethodRunner:
                     timings["wire_saved"])
             self._metrics.counter("fetch_elided_batches").inc()
             self._metrics.counter("batches").inc()
-            if timings.get("tokens") is not None:
-                self._metrics.counter("tokens").inc(timings["tokens"])
+            self._count(timings)
             self._metrics.counter("padded_records").inc(
                 batch.padded_size - batch.num_records)
         if self._roofline is not None:

@@ -40,6 +40,11 @@ class ModelMethod:
     needs_lengths: bool = False
     #: Preferred on-device compute dtype; bfloat16 keeps the MXU fed.
     compute_dtype: typing.Any = None
+    #: Outputs of ``fn`` that are counts made on the device, not answers:
+    #: ``[B]`` (one a record; padding rows are left out of the sum) or a
+    #: scalar (one a batch).  A runner fetches them with every batch, adds
+    #: them to counters of the same name and never puts them in a record.
+    count_names: typing.Tuple[str, ...] = ()
 
 
 class Model:

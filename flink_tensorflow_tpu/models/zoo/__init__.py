@@ -14,9 +14,10 @@ behavioral, not mechanism parity.  One module per BASELINE.json workload:
 - :mod:`widedeep`  — Wide&Deep recommender (BASELINE.json:10)
 
 Beyond the reference's workloads: :mod:`chartransformer` (the serving
-plane's char-level decoder) and :mod:`falcon_h1` (a hybrid Mamba-2 +
-grouped-query attention language model, scored a record at a time on the
-stream path).
+plane's char-level decoder), :mod:`falcon_h1` (a hybrid Mamba-2 +
+grouped-query attention language model) and :mod:`lfm2_moe` (gated short
+convolutions, grouped-query attention and routed experts), both scored a
+record at a time on the stream path.
 """
 
 from flink_tensorflow_tpu.models.zoo.registry import ModelDef, get_model_def, register_model_def

@@ -320,12 +320,37 @@ COUNTER_METRICS = [m for m in (json.loads(p.read_text()) for p in sorted(
     (REPO / "benchmark" / "layer_metrics").glob("*.json"))) if m["reader"] == "counters"]
 
 
+def _routed_job(name):
+    """token records -> count_window(2) -> a tiny routed language model ->
+    list, two windows: the counters a method makes on the device."""
+    import jax
+
+    from flink_tensorflow_tpu.models import get_model_def
+
+    mdef = get_model_def("lfm2_moe", seq_len=8, vocab_size=64, hidden_size=32, intermediate_size=64,
+                         moe_intermediate_size=16, num_hidden_layers=3, num_dense_layers=1,
+                         layer_types=("conv", "full_attention", "conv"), num_attention_heads=4,
+                         num_key_value_heads=2, num_experts=4, num_experts_per_tok=2)
+    rng = np.random.RandomState(0)
+    recs = [TensorValue({"tokens": rng.randint(0, 64, 8).astype(np.int32)}) for _ in range(4)]
+    env = StreamExecutionEnvironment(parallelism=1)
+    out = (env.from_collection(recs).count_window(2)
+           .apply(ModelWindowFunction(mdef.to_model(mdef.init_fn(jax.random.key(0))),
+                                      policy=BucketPolicy(fixed_batch=2), warmup_batches=(2,),
+                                      outputs=("label",)), name="model", parallelism=1)
+           .sink_to_list())
+    job = env.execute(name, timeout=300)
+    assert len(out) == 4
+    return job
+
+
 @pytest.fixture(scope="module")
 def registry(lenet):
     """What the registry reports after one job of each kind the benchmark
     runs, with the operators named as its jobs name them."""
     handle, _ = _job(lenet, "registry-stream", source=_paced_source(), source_name="offered")
-    return {**handle.executor.metrics.report(), **_train_job("registry-train").metrics}
+    return {**_routed_job("registry-routed").metrics, **handle.executor.metrics.report(),
+            **_train_job("registry-train").metrics}
 
 
 @pytest.mark.parametrize("metric", COUNTER_METRICS, ids=lambda m: m["name"])
