@@ -264,9 +264,13 @@ def phase2_serving(device, *, max_new_tokens=16, compiled_kernel=True):
             "capacity": capacity}
 
 
-def phase2_flash(device, *, interpret=False):
+def phase2_flash(device, *, interpret=False, cell_positions=4096):
     """The pallas flash kernel against ``parallel.full_attention`` at the
-    serving prefill shape and at the long-sequence bf16 shape.  With
+    serving prefill shape and at the long-sequence bf16 shape, then at the
+    two language-model cells' own shapes (``cell_positions`` long, the tile
+    the kernel chooses) against plain attention on the first 1,024 positions
+    and on every eighth after: a layout Mosaic takes and miscompiles shows
+    here, not in a benchmark run's ``logit_rms_err``.  With
     ``interpret=False`` a Mosaic refusal surfaces here as the compile
     error it is — nothing retries interpreted."""
     import jax
@@ -306,6 +310,26 @@ def phase2_flash(device, *, interpret=False):
                                        atol=1e-4)
             checked.append({"shape": list(shape), "dtype": jnp.dtype(dtype).name,
                             "causal": causal, "interpret": interpret})
+    t = cell_positions
+    rows = np.unique(np.concatenate([np.arange(min(t, 1024)), np.arange(0, t, 8)]))
+    for heads, kv_heads, d in ((20, 4, 128), (32, 8, 64)):  # Falcon-H1, LFM2
+        q, k, v = (jax.device_put(jnp.asarray(rng.randn(2, t, h, d), jnp.bfloat16), device)
+                   for h in (heads, kv_heads, kv_heads))
+        got = flash_attention(q, k, v, causal=True, interpret=interpret)
+        _check(got.shape == q.shape and got.dtype == q.dtype, got.shape, got.dtype)
+        with jax.default_matmul_precision("highest"):
+            k_all, v_all = (jnp.repeat(x[0].astype(jnp.float32), heads // kv_heads, axis=1)
+                            for x in (k, v))
+            s = jnp.einsum("qhd,khd->hqk", q[0, rows].astype(jnp.float32), k_all) / np.sqrt(d)
+            s = jnp.where(jnp.arange(t)[None, :] <= rows[:, None], s, -jnp.inf)
+            want = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v_all)
+        err = np.abs(np.asarray(got[0, rows], np.float32) - np.asarray(want))
+        _check(np.isfinite(err).all(), "non-finite attention output")
+        # a bfloat16 result of size 1-2 rounds by up to 2**-8 of itself
+        _check(err.max() < 1.2e-2, "flash against plain attention", float(err.max()))
+        checked.append({"shape": [2, t, heads, d], "kv_heads": kv_heads, "dtype": "bfloat16",
+                        "causal": True, "interpret": interpret,
+                        "max_err": float(err.max())})
     return {"checked": checked}
 
 

@@ -38,8 +38,6 @@ from flink_tensorflow_tpu.ops.ssd import causal_conv1d, ssd_scan
 from flink_tensorflow_tpu.tensors.schema import RecordSchema, TensorSpec
 
 F32 = jnp.float32
-#: The flash kernel's tile along queries and keys (it shrinks for a shorter record).
-ATTENTION_BLOCK = 512
 
 
 def _rms_norm(x, weight, eps):
@@ -160,8 +158,7 @@ def build(
             k = (dot(x, p["wk"]) * key_multiplier).reshape(b, t, num_key_value_heads, head_dim)
             v = dot(x, p["wv"]).reshape(b, t, num_key_value_heads, head_dim)
             q, k = _rope(q, float(rope_theta)), _rope(k, float(rope_theta))
-            out = flash_attention(q.astype(cdt), k.astype(cdt), v.astype(cdt), causal=True,
-                                  block_q=ATTENTION_BLOCK, block_k=ATTENTION_BLOCK)
+            out = flash_attention(q.astype(cdt), k.astype(cdt), v.astype(cdt), causal=True)
             return dot(out.reshape(b, t, q_dim), p["wo"])
 
     def mamba2(p, x):

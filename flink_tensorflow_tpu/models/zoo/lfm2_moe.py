@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from flink_tensorflow_tpu.models.base import ModelMethod
-from flink_tensorflow_tpu.models.zoo.falcon_h1 import ATTENTION_BLOCK, _rms_norm, _rope
+from flink_tensorflow_tpu.models.zoo.falcon_h1 import _rms_norm, _rope
 from flink_tensorflow_tpu.models.zoo.registry import ModelDef, register_model_def
 from flink_tensorflow_tpu.ops.flash_attention import flash_attention
 from flink_tensorflow_tpu.ops.moe import routed_experts
@@ -146,8 +146,7 @@ def build(
             v = dot(u, p["wv"]).reshape(b, t, num_key_value_heads, head_dim)
             q = _rope(_rms_norm(q, p["q_norm"], norm_eps), float(rope_theta))
             k = _rope(_rms_norm(k, p["k_norm"], norm_eps), float(rope_theta))
-            out = flash_attention(q.astype(cdt), k.astype(cdt), v.astype(cdt), causal=True,
-                                  block_q=ATTENTION_BLOCK, block_k=ATTENTION_BLOCK)
+            out = flash_attention(q.astype(cdt), k.astype(cdt), v.astype(cdt), causal=True)
             return dot(out.reshape(b, t, q_dim), p["wo"])
 
     def mlp(p, x):

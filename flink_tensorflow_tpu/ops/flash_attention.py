@@ -5,16 +5,20 @@ SURVEY.md §5 "Long-context": absent); this framework treats long-context
 as first-class, so the O(T^2)-memory-free attention primitive ships as a
 native TPU kernel (pallas) rather than a composed jnp graph:
 
-- one grid program per (batch*head, q-block): the q block and the
-  f32 accumulators live in VMEM; K/V stream through in ``block_k`` tiles
-- online softmax (running max/denominator) — no [T, T] score matrix ever
+- one grid program per (batch*head, q-block, K/V tile): the q block and
+  the f32 accumulators live in VMEM; a copied K/V tile is the whole key
+  sequence where that fits, and the program walks it in column chunks
+- online softmax (running max/denominator, kept lane-replicated so that a
+  row's work is plain vector ops) — no [T, T] score matrix ever
   materializes in HBM
+- the tile is read off the call's shape (:func:`tile_plan`), not named by
+  the caller; only the chunks the diagonal crosses are masked
 - ``jnp.dot(..., preferred_element_type=f32)`` keeps both matmuls on the
   MXU with f32 accumulation over bf16 inputs; float32 inputs contract at
   float32 precision (Mosaic's default would round them to bf16 — a
   0.01 output error the interpreter never shows)
-- causal grids skip fully-masked K/V tiles entirely (upper-triangle
-  blocks are never read)
+- causal grids skip fully-masked K/V tiles and chunks entirely
+  (upper-triangle blocks are never read or computed)
 
 Composes with the ``seq``-axis ring (parallel/ring_attention.py): ring
 moves K/V shards BETWEEN chips over ICI, this kernel computes each local
@@ -33,14 +37,15 @@ def flash_attention(
     q, k, v,
     *,
     causal: bool = False,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: typing.Optional[int] = None,
+    block_k: typing.Optional[int] = None,
     interpret: typing.Optional[bool] = None,
     return_lse: bool = False,
 ):
     """Attention over ``[B, T, H, D]`` tensors (same layout/semantics as
-    parallel.full_attention).  Block sizes shrink automatically for short
-    sequences; the stream layer's power-of-two buckets keep them aligned.
+    parallel.full_attention).  The kernel picks its tile from the shape
+    (:func:`tile_plan`); ``block_q`` / ``block_k`` name an edge instead, and
+    shrink for short sequences as the chosen ones do.
 
     Grouped queries: ``k`` and ``v`` may carry fewer heads than ``q``
     (``[B, T, Hkv, D]``, ``H`` a multiple of ``Hkv``); query head ``i``
@@ -48,13 +53,15 @@ def flash_attention(
     index, so no repeated copy of K or V is ever made in HBM.
 
     Head sizes it has run at on the chip (TPU v5e, bfloat16, causal,
-    blocks of 512 over 4,096 positions): 128 (20 query heads on 4,
+    4,096 positions: 512 query rows a program, K and V copied whole,
+    scores 512 columns at a time): 128 (20 query heads on 4,
     Falcon-H1) and 64 (32 on 8, LFM2: the block's last dimension is then
-    the whole head, half a lane tile wide).  The tests also run 16, 32
-    and 64 interpreted.
+    the whole head, half a lane tile wide).  The tests also run 16, 64
+    and 128 interpreted.
 
     ``return_lse=True`` also returns the per-row log-sum-exp
-    ``[B, H, T]`` (f32) — the residual that lets callers combine partial
+    ``[B, H, T]`` (f32; the call is built without that output otherwise)
+    — the residual that lets callers combine partial
     attention over K/V shards, which is how the seq-axis ring
     (parallel/ring_attention.py) folds this kernel's per-block outputs
     into a global softmax without ever materializing full scores."""
@@ -64,8 +71,7 @@ def flash_attention(
     tk, hkv = k.shape[1], k.shape[2]
     if h % hkv or v.shape[2] != hkv:
         raise ValueError(f"{h} query heads cannot share {hkv} key / {v.shape[2]} value heads")
-    block_q = _tileable_block(t, block_q)
-    block_k = _tileable_block(tk, block_k)
+    plan = tile_plan(t, tk, d, q.dtype, causal, block_q, block_k)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -75,7 +81,7 @@ def flash_attention(
 
     out, lse = _flash_bh(
         to_bh(q), to_bh(k), to_bh(v), group=h // hkv,
-        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
+        causal=causal, plan=plan, interpret=interpret, with_lse=return_lse,
     )
     out = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
     if return_lse:
@@ -154,13 +160,97 @@ def _tileable_block(t: int, pref: int) -> int:
     in interpret mode but crashes Mosaic on the real chip.)"""
     if t <= pref:
         return t  # one block spanning the dim — always legal
-    for b in (pref, 128, 64, 32, 16, 8):
-        if b <= pref and t % b == 0:
+    b = pref
+    while b >= 8:
+        if t % b == 0 and b % 8 == 0:
             return b
+        b = 1 << (b - 1).bit_length() - 1  # the next power of two below
     # No multiple-of-8 divisor (e.g. t odd): one whole-dim block.
     # Correct but VMEM-heavy for very long odd lengths — the stream
     # layer's power-of-two buckets keep production shapes off this path.
     return t
+
+
+#: Lanes of a vector register: the running statistics are kept this wide.
+_LANES = 128
+#: What the chooser asks of a shape (PERF.md 6, PR 36 has the table behind them):
+#: rows of queries a program holds, columns of scores it computes at once, and
+#: the bytes of one copied K (or V) tile, which is the whole sequence if it fits.
+_PREF_BLOCK_Q = 512
+_PREF_CHUNK = 512
+_KV_TILE_BYTES = 2 << 20
+#: Mosaic's scoped default, and what is left of the chip's 128 MiB to ask for.
+_VMEM_DEFAULT = 16 << 20
+_VMEM_MOST = 100 << 20
+
+
+class TilePlan(typing.NamedTuple):
+    """What one call does with a ``(batch x head)`` row: the edges of the copied
+    tiles, the columns of scores computed at once (a compute tile is ``block_q x
+    chunk``), how many compute tiles a row visits, how many of those the diagonal
+    crosses (they alone are masked), and an estimate of the VMEM the call needs
+    (asked of Mosaic only where it is over Mosaic's own default)."""
+
+    block_q: int
+    block_k: int
+    chunk: int
+    tiles_visited: int
+    tiles_masked: int
+    vmem_bytes: int
+
+
+def tile_plan(t: int, tk: int, d: int, dtype, causal: bool,
+              block_q: typing.Optional[int] = None,
+              block_k: typing.Optional[int] = None) -> TilePlan:
+    """The tile of a call, read off its shape.  An edge the caller names is kept
+    (as far as `_tileable_block` allows) and is then the compute tile's edge too;
+    an edge left out is chosen: up to 512 query rows a program, the whole key
+    sequence copied once if K and V fit 2 MiB each, scores 512 columns at a time."""
+    import numpy as np
+
+    itemsize = np.dtype(dtype).itemsize
+    lanes = lambda n: -(-n // _LANES) * _LANES  # noqa: E731  (VMEM pads the last dim)
+    bq = _tileable_block(t, block_q or _PREF_BLOCK_Q)
+    if block_k:
+        bk = chunk = _tileable_block(tk, block_k)
+    else:
+        bk = _tileable_block(tk, max(_PREF_CHUNK, _KV_TILE_BYTES // (lanes(d) * itemsize)))
+        # A chunk inside the copied tile starts at a multiple of itself: whole lane
+        # tiles of scores and whole sublane tiles of K, or the tile is one chunk.
+        chunk = next((c for c in (_PREF_CHUNK, 256, 128) if bk % c == 0), bk)
+    visited = masked = 0
+    for qi in range(t // bq):
+        for j in range(tk // bk):
+            whole, some = _chunk_counts(qi, j, bq, bk, chunk, causal)
+            visited, masked = visited + some, masked + some - whole
+    vmem = (
+        2 * 2 * bq * lanes(d) * itemsize        # q and out blocks, double-buffered
+        + 2 * 2 * bk * lanes(d) * itemsize      # K and V tiles, double-buffered
+        + bq * lanes(d) * (4 + itemsize)        # accumulator, scaled q
+        + 2 * bq * _LANES * 4                   # running max and denominator
+        + 2 * bq * _LANES * 4                   # the lse block, double-buffered
+        + 4 * bq * lanes(chunk) * 4             # scores, mask, exp and its cast
+    )
+    return TilePlan(bq, bk, chunk, visited, masked, vmem)
+
+
+def _chunk_counts(qi, j, block_q: int, block_k: int, chunk: int, causal: bool):
+    """Of K tile ``j``'s chunks, how many every row of q block ``qi`` sees whole
+    and how many any of its rows sees at all.  Python ints and traced scalars
+    alike: the kernel's loop bounds and `tile_plan`'s counts are this one rule."""
+    n = block_k // chunk
+    if not causal:
+        return n, n
+    if isinstance(qi, int):
+        most, least = max, min
+    else:
+        import jax.numpy as jnp
+
+        most, least = jnp.maximum, jnp.minimum
+    # Chunk c holds keys lo + c*chunk .. lo + (c+1)*chunk - 1; row r sees keys 0 .. r.
+    first_row, lo = qi * block_q, j * block_k
+    return (least(most(first_row + 1 - lo, 0) // chunk, n),
+            least((most(first_row + block_q - lo, 0) + chunk - 1) // chunk, n))
 
 
 def _vma(*xs):
@@ -171,23 +261,24 @@ def _vma(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
-def _flash_bh(q, k, v, *, causal, block_q, block_k, interpret, group=1):
+def _flash_bh(q, k, v, *, causal, plan, interpret, group=1, with_lse=True):
     """``q`` ``[B*H, T, D]``; ``k``, ``v`` ``[B*H/group, Tk, D]``: row ``i`` of
-    ``q`` reads row ``i // group`` of ``k`` and ``v``."""
+    ``q`` reads row ``i // group`` of ``k`` and ``v``.  Returns ``(out, lse)``,
+    ``lse`` ``None`` unless asked for."""
     import jax
 
     bh, t, d = q.shape
     # Dtype keyed by NAME: ml_dtypes (bfloat16) have no portable .str.
     fn = _build_flash_call(
         bh, t, k.shape[1], d, jax.numpy.dtype(q.dtype).name, causal,
-        block_q, block_k, interpret, _vma(q, k, v), group,
+        plan, interpret, _vma(q, k, v), group, with_lse,
     )
     return fn(q, k, v)
 
 
 @functools.lru_cache(maxsize=256)
-def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
-                      interpret, vma, group=1):
+def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
+                      group=1, with_lse=True):
     """Jitted pallas_call per static configuration.  Building a fresh
     closure per invocation would defeat jax.jit's cache (keyed on the
     function object) and recompile the Mosaic kernel on EVERY eager call."""
@@ -197,15 +288,18 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     dtype = jnp.dtype(dtype_str)
+    block_q, block_k, chunk = plan.block_q, plan.block_k, plan.chunk
     nq, nk = t // block_q, tk // block_k
     scale = 1.0 / math.sqrt(d)
     # Mosaic's default contraction feeds the MXU one bf16 pass whatever
     # the operand dtype: a silent downcast of q/k/v/p for float32 callers,
     # who get float32 operands at HIGHEST; narrower callers' tiles go to the
-    # MXU as they are (no widened copy in VMEM), the scores' scale applied
-    # to the float32 product.
+    # MXU as they are (no widened copy in VMEM).
     wide = dtype == jnp.float32
     precision = jax.lax.Precision.HIGHEST if wide else None
+    # The scale goes onto q once a q block where that rounds nothing (float32,
+    # or a power of two: 1/8 at a head of 64), else onto the float32 scores.
+    scale_q = wide or math.frexp(scale)[0] == 0.5
 
     def kv_tile(b_, qi, j):
         # Row b_ of q reads row b_ // group of k and v (grouped queries).
@@ -215,98 +309,121 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, block_q, block_k,
             j = jnp.minimum(j, (qi * block_q + block_q - 1) // block_k)
         return (b_ // group, j, 0)
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
-        # Grid (bh, nq, nk): the innermost k dimension iterates
-        # sequentially on TPU, so the VMEM scratch accumulators carry the
-        # online softmax across K/V tiles — only ONE (block_k, d) K and V
-        # tile is resident at a time, so VMEM use is O(block) not O(T).
+    def across(x, n):
+        """Lane-replicated ``(block_q, 128)`` statistics, ``n`` lanes wide."""
+        if n % _LANES == 0:
+            return jnp.tile(x, (1, n // _LANES))
+        return x[:, :n] if n < _LANES else jnp.broadcast_to(x[:, :1], (block_q, n))
+
+    def kernel(q_ref, k_ref, v_ref, o_ref, *rest):
+        # Grid (bh, nq, nk): the innermost k dimension iterates sequentially
+        # on TPU, so the VMEM scratch carries the online softmax across K/V
+        # tiles and across the chunks of one.  The running max and denominator
+        # stay two-dimensional and lane-replicated from the reduction to the
+        # store: `s - m` and `acc * alpha` are then plain vector ops.
+        lse_ref, (m_scr, l_scr, acc_scr, *q_scr) = (rest[0], rest[1:]) if with_lse else (None, rest)
+        q_scr = q_scr[0] if scale_q else None  # q times the scale, once a q block
         qi = pl.program_id(1)
         j = pl.program_id(2)
 
         @pl.when(j == 0)
         def _init():
-            m_scr[:] = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-            l_scr[:] = jnp.zeros((block_q, 1), jnp.float32)
-            acc_scr[:] = jnp.zeros((block_q, d), jnp.float32)
+            m_scr[...] = jnp.full((block_q, _LANES), -jnp.inf, jnp.float32)
+            l_scr[...] = jnp.zeros((block_q, _LANES), jnp.float32)
+            acc_scr[...] = jnp.zeros((block_q, d), jnp.float32)
+            if scale_q:
+                q_scr[...] = q_ref[0] * scale
 
-        # Causal: tiles strictly above the diagonal contribute nothing.
-        visible = True if not causal else (j * block_k <= qi * block_q + block_q - 1)
-
-        @pl.when(visible)
-        def _update():
-            q_blk, k_blk, v_blk = q_ref[0], k_ref[0], v_ref[0]  # [bq, d], [bk, d] x 2
-            if wide:
-                q_blk = q_blk * scale
+        def update(c, masked):
+            """Fold chunk ``c`` of this K tile into the running softmax.  No row
+            is ever empty here: key 0 is in the first chunk a row visits (causal
+            rows see keys 0..r, others see all), so the max is finite from then
+            on, ``exp(-inf - m)`` is 0 for a masked score and for the first
+            ``alpha``, and nothing needs a guard."""
+            if chunk == block_k:
+                k_blk, v_blk = k_ref[0], v_ref[0]
+            else:
+                cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                k_blk, v_blk = k_ref[0, cols, :], v_ref[0, cols, :]
+            q_blk = q_scr[...] if scale_q else q_ref[0]
             s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
                                     precision=precision,
                                     preferred_element_type=jnp.float32)
-            if not wide:
+            if not scale_q:
                 s = s * scale
-            if causal:
-                q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                k_pos = j * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(k_pos <= q_pos, s, -jnp.inf)
-            m = m_scr[:, 0]
-            l = l_scr[:, 0]
-            m_blk = jnp.max(s, axis=-1)
-            m_new = jnp.maximum(m, m_blk)
-            # Fully-masked rows keep m_new = -inf: guard the exps so they
-            # contribute 0 instead of NaN.
-            safe_m = jnp.where(jnp.isinf(m_new), 0.0, m_new)
-            p = jnp.exp(s - safe_m[:, None])
-            p = jnp.where(jnp.isinf(m_new)[:, None] | jnp.isinf(s), 0.0, p)
-            alpha = jnp.where(jnp.isinf(m), 0.0, jnp.exp(m - safe_m))
-            m_scr[:] = m_new[:, None]
-            l_scr[:] = (l * alpha + jnp.sum(p, axis=-1))[:, None]
-            acc_scr[:] = acc_scr[:] * alpha[:, None] + jnp.dot(
+            if masked:
+                # key j*block_k + c*chunk + col <= query qi*block_q + row
+                rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, chunk), 0)
+                cols_ = jax.lax.broadcasted_iota(jnp.int32, (block_q, chunk), 1)
+                ahead = qi * block_q - j * block_k - c * chunk
+                s = jnp.where(cols_ - rows <= ahead, s, -jnp.inf)
+            m_prev = m_scr[...]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - across(m_next, chunk))
+            alpha = jnp.exp(m_prev - m_next)
+            m_scr[...] = m_next
+            l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * across(alpha, d) + jnp.dot(
                 p.astype(v_blk.dtype), v_blk, precision=precision,
                 preferred_element_type=jnp.float32)
 
+        # Chunks below the diagonal first, with no mask; then those it crosses.
+        whole, some = _chunk_counts(qi, j, block_q, block_k, chunk, causal)
+        jax.lax.fori_loop(0, whole, lambda c, _: update(c, False), None)
+        if causal:
+            jax.lax.fori_loop(whole, some, lambda c, _: update(c, True), None)
+
         @pl.when(j == nk - 1)
         def _finalize():
-            l = l_scr[:, 0]
-            m = m_scr[:, 0]
+            l = l_scr[...]
             denom = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0] = (acc_scr[:] / denom[:, None]).astype(o_ref.dtype)
-            # log-sum-exp residual; fully-masked rows (l=0, m=-inf) -> -inf.
-            lse_ref[0] = jnp.where(l == 0.0, -jnp.inf, m + jnp.log(denom))[:, None]
+            o_ref[0] = (acc_scr[...] / across(denom, d)).astype(o_ref.dtype)
+            if with_lse:
+                # log-sum-exp residual; rows that saw no key (l=0, m=-inf) -> -inf.
+                lse = jnp.where(l == 0.0, -jnp.inf, m_scr[...] + jnp.log(denom))
+                lse_ref[0] = lse[:, :1]
 
-    fn = pl.pallas_call(
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b_, qi, j: (b_, qi, 0),
+                          memory_space=pltpu.VMEM)
+    kv_spec = pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM)
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma)]
+    if with_lse:
+        # Trailing unit dim keeps the block's last-two dims TPU-tileable
+        # ((block_q, 1) instead of (1, block_q)).
+        out_specs.append(pl.BlockSpec((1, block_q, 1), lambda b_, qi, j: (b_, qi, 0),
+                                      memory_space=pltpu.VMEM))
+        out_shape.append(jax.ShapeDtypeStruct((bh, t, 1), jnp.float32, vma=vma))
+    call = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, qi, j: (b_, qi, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, qi, j: (b_, qi, 0),
-                         memory_space=pltpu.VMEM),
-            # Trailing unit dim keeps the block's last-two dims TPU-tileable
-            # ((block_q, 1) instead of (1, block_q)).
-            pl.BlockSpec((1, block_q, 1), lambda b_, qi, j: (b_, qi, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32, vma=vma),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        ] + ([pltpu.VMEM((block_q, d), dtype)] if scale_q else []),
         # bh and q-blocks are independent programs (scratch re-inits at
         # j==0 per (bh, qi)): declaring them parallel lets Mosaic
         # megacore-partition the grid on v4/v5p; only the K sweep is
         # order-dependent (online-softmax carry).
+        # A limit is asked for only by a tile that needs more than Mosaic's own:
+        # naming one, even the default, makes XLA set that much VMEM aside for
+        # the whole program, and its other fusions then prefetch and tile with
+        # less (PERF.md 6, PR 36: 0.8% on every op of the Falcon step).
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=(min(plan.vmem_bytes, _VMEM_MOST)
+                              if plan.vmem_bytes > _VMEM_DEFAULT else None),
         ),
         interpret=interpret,
         name="flash_attention",
     )
+
+    def fn(q, k, v):
+        out, *lse = call(q, k, v)
+        return out, (lse[0] if with_lse else None)
+
     return jax.jit(fn)

@@ -29,9 +29,10 @@ def test_phase2_serving_and_interpreted_kernel():
     served = chip_smoke.phase2_serving(dev, max_new_tokens=4,
                                        compiled_kernel=False)
     assert served == {"sessions": 8, "tokens": 32, "capacity": 128}
-    flash = chip_smoke.phase2_flash(dev, interpret=True)
+    flash = chip_smoke.phase2_flash(dev, interpret=True, cell_positions=256)
     assert [c["shape"] for c in flash["checked"]] == [
-        [8, 128, 4, 16], [2, 256, 4, 64], [2, 256, 4, 64]]
+        [8, 128, 4, 16], [2, 256, 4, 64], [2, 256, 4, 64],
+        [2, 256, 20, 128], [2, 256, 32, 64]]  # the last two: the cells' heads
 
 
 def test_result_line_has_exactly_the_contract_keys():

@@ -1,0 +1,69 @@
+"""The flash kernel compiled for a described TPU v5e (no chip attached): what
+Mosaic refuses (a block it cannot tile, a slice off the tiling, more VMEM than
+the call asked for) fails here and not on the chip.  Nothing runs, so this says
+nothing of results or speed; ``chip_smoke.py`` phase 2 holds the results.
+
+The topology is described inside a fixture, never at import: one process at a
+time may load the TPU's library, and every xdist worker imports this file."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from flink_tensorflow_tpu.ops.flash_attention import flash_attention, tile_plan
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to hold
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+#: (q shape [B, T, H, D], key positions, key/value heads, dtype, causal, return_lse)
+_SHAPES = {
+    # the two language-model cells
+    "falcon_h1-20on4-head128": ((2, 4096, 20, 128), 4096, 4, "bfloat16", True, False),
+    "lfm2-32on8-head64": ((2, 4096, 32, 64), 4096, 8, "bfloat16", True, False),
+    # chartransformer's prefill and chip_smoke's long-sequence shape
+    "prefill-128": ((8, 128, 4, 16), 128, 4, "float32", True, True),
+    "bf16-256-full": ((2, 256, 4, 64), 256, 4, "bfloat16", False, True),
+    # the ring's blocks: lse out, causal on the diagonal, keys of another length off it
+    "ring-diagonal": ((1, 1024, 4, 128), 1024, 4, "bfloat16", True, True),
+    "ring-off-diagonal": ((1, 1024, 4, 128), 4096, 4, "bfloat16", False, True),
+    # K and V too long to copy whole: a grid over key tiles, chunks inside each
+    "keys-in-tiles": ((1, 16384, 8, 128), 16384, 8, "bfloat16", True, False),
+    "float32-4096": ((1, 4096, 2, 128), 4096, 2, "float32", True, True),
+    # TestTileableBlocks' lengths: no multiple of 128, no multiple of 8, mixed
+    "length-100": ((1, 100, 2, 16), 100, 2, "float32", True, True),
+    "length-264-136": ((1, 264, 2, 16), 136, 2, "float32", False, False),
+    "length-12-200": ((1, 12, 2, 16), 200, 2, "float32", False, False),
+    "length-1000-bf16": ((1, 1000, 2, 64), 1000, 2, "bfloat16", True, False),
+    "length-1001-odd": ((1, 1001, 2, 64), 1001, 2, "bfloat16", True, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_SHAPES), ids=list(_SHAPES))
+def test_chosen_tile_compiles_for_v5e(one_chip, case):
+    shape, tk, kv_heads, dtype, causal, return_lse = _SHAPES[case]
+    b, t, _, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, tk, kv_heads, d), jnp.dtype(dtype), sharding=one_chip)
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, causal=causal, interpret=False, return_lse=return_lse)
+
+    compiled = jax.jit(call).lower(q, kv, kv).compile()
+    # one kernel a call, under the name the benchmark's roofline share reads
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert "flash_attention" in compiled.as_text()
+    plan = tile_plan(t, tk, d, jnp.dtype(dtype), causal)
+    assert t % plan.block_q == 0 and tk % plan.block_k == 0 and plan.block_k % plan.chunk == 0

@@ -10,17 +10,104 @@ from flink_tensorflow_tpu.ops import flash_attention, flash_attention_decode
 from flink_tensorflow_tpu.parallel import full_attention
 
 
+def _plain_scores(q, k, causal):
+    """float64 scaled scores ``[B, H, T, Tk]`` of ``[B, T, H, D]`` q against k with
+    as many heads, masked to ``-inf`` above the diagonal when causal."""
+    s = np.einsum("bqhd,bkhd->bhqk", np.asarray(q, np.float64),
+                  np.asarray(k, np.float64)) / np.sqrt(q.shape[-1])
+    if causal:
+        s = np.where(np.arange(s.shape[-1])[None, :] <= np.arange(s.shape[-2])[:, None],
+                     s, -np.inf)
+    return s
+
+
+#: (b, t, tk, heads, kv heads, head size, dtype, causal, block_q, block_k, atol).
+#: ``None`` for a block leaves the edge to the kernel's chooser.
+_PARITY = {
+    # the two cases this test had before it was a table
+    "square16-full": (2, 64, 64, 2, 2, 16, "float32", False, 16, 16, 1e-5),
+    "square16-causal": (2, 64, 64, 2, 2, 16, "float32", True, 16, 16, 1e-5),
+    # a row of tiles with an unmasked tile, a diagonal one and a skipped one
+    "tall-causal": (1, 96, 96, 2, 2, 16, "float32", True, 32, 16, 1e-5),
+    "wide-causal": (1, 96, 96, 2, 2, 16, "float32", True, 16, 48, 1e-5),
+    "wide-full": (1, 96, 96, 2, 2, 16, "float32", False, 16, 48, 1e-5),
+    # keys and queries of different lengths
+    "short-keys-causal": (1, 64, 32, 2, 2, 16, "float32", True, 16, 16, 1e-5),
+    "long-keys-causal": (1, 32, 64, 2, 2, 16, "float32", True, 16, 16, 1e-5),
+    "long-keys-full": (2, 32, 80, 2, 2, 16, "float32", False, 16, 16, 1e-5),
+    # grouped queries, through the block index
+    "4on1-head64-causal": (1, 64, 64, 4, 1, 64, "float32", True, 32, 32, 1e-5),
+    "5on1-head128-causal": (2, 64, 64, 5, 1, 128, "float32", True, 32, 16, 1e-5),
+    "8on2-head64-full": (1, 48, 48, 8, 2, 64, "float32", False, 16, 16, 1e-5),
+    # bfloat16 tiles to the MXU, the scale folded into q at a head of 64 and not at 128
+    "bf16-4on1-head64-causal": (1, 64, 64, 4, 1, 64, "bfloat16", True, 32, 32, 3e-2),
+    "bf16-5on1-head128-causal": (1, 64, 64, 5, 1, 128, "bfloat16", True, 32, 32, 3e-2),
+    "bf16-head16-causal": (1, 64, 64, 2, 2, 16, "bfloat16", True, 16, 32, 3e-2),
+    # the chooser's own tile: one block; three chunks of 128 in one copied tile;
+    # chunks of 512 below, on and above the diagonal in a copied tile of 1,024
+    "chosen-one-block-causal": (2, 128, 128, 2, 2, 16, "float32", True, None, None, 1e-5),
+    "chosen-chunks-of-128-causal": (1, 384, 384, 2, 1, 16, "float32", True, None, None, 1e-5),
+    "chosen-chunks-of-128-full": (1, 128, 384, 2, 1, 16, "float32", False, None, None, 1e-5),
+    "chosen-chunks-of-512-causal": (1, 1024, 1024, 1, 1, 16, "float32", True, None, None, 1e-5),
+    "chosen-rows-given-causal": (1, 256, 256, 2, 2, 16, "float32", True, 64, None, 1e-5),
+    "chosen-bf16-head64-causal": (1, 256, 256, 4, 1, 64, "bfloat16", True, None, None, 3e-2),
+}
+
+
 class TestFlashAttention:
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_matches_full_attention(self, causal):
+    @pytest.mark.parametrize("return_lse", [False, True], ids=["out", "out+lse"])
+    @pytest.mark.parametrize("case", list(_PARITY), ids=list(_PARITY))
+    def test_matches_full_attention(self, case, return_lse):
+        """The kernel against plain attention, and its ``lse`` against the
+        log-sum-exp of the plain scores."""
+        b, t, tk, h, hkv, d, dtype, causal, block_q, block_k, atol = _PARITY[case]
         rng = np.random.RandomState(0)
-        b, t, h, d = 2, 64, 2, 16
-        q, k, v = (rng.randn(b, t, h, d).astype(np.float32) for _ in range(3))
-        want = full_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                              causal=causal)
-        got = flash_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                              causal=causal, block_q=16, block_k=16)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+        q = jnp.asarray(rng.randn(b, t, h, d), dtype)
+        k, v = (jnp.asarray(rng.randn(b, tk, hkv, d), dtype) for _ in range(2))
+        k_all, v_all = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
+        want = full_attention(q, k_all, v_all, causal=causal)
+        got = flash_attention(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+                              return_lse=return_lse)
+        if return_lse:
+            got, lse = got
+            assert lse.shape == (b, h, t) and lse.dtype == jnp.float32
+            s = _plain_scores(q, k_all, causal)
+            top = s.max(-1, keepdims=True)
+            want_lse = (top + np.log(np.exp(s - top).sum(-1, keepdims=True)))[..., 0]
+            np.testing.assert_allclose(np.asarray(lse), want_lse,
+                                       atol=1e-4 if dtype == "float32" else 2e-2)
+        assert got.shape == q.shape and got.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), atol=atol)
+
+    def test_tile_plan(self):
+        """The tile the kernel reads off a shape, and what a row of it costs."""
+        from flink_tensorflow_tpu.ops.flash_attention import (
+            _VMEM_MOST,
+            tile_plan,
+        )
+
+        for d in (128, 64):  # the two language-model cells: 4,096 causal positions
+            plan = tile_plan(4096, 4096, d, jnp.bfloat16, True)
+            assert plan[:5] == (512, 4096, 512, 36, 8), plan
+            assert plan.vmem_bytes <= _VMEM_MOST
+        # edges given: they are the copied tile's, and it is one chunk
+        assert tile_plan(4096, 4096, 128, jnp.bfloat16, True, 1024, 1024)[:5] == (1024, 1024, 1024, 10, 4)
+        assert tile_plan(4096, 4096, 128, jnp.bfloat16, False, 512, 256)[:5] == (512, 256, 256, 128, 0)
+        # K and V past 2 MiB each are copied in tiles
+        assert tile_plan(16384, 16384, 128, jnp.bfloat16, True)[:3] == (512, 8192, 512)
+        # shorter keys than queries: the rows past them see every key, unmasked
+        assert tile_plan(64, 32, 16, jnp.float32, True, 16, 16)[3:5] == (7, 2)
+        for t in [8, 12, 64, 100, 128, 136, 200, 264, 1000, 1001, 4096, 12288]:
+            for dtype in (jnp.float32, jnp.bfloat16):
+                plan = tile_plan(t, t, 64, dtype, True)
+                for edge in (plan.block_q, plan.block_k):  # Mosaic-legal
+                    assert t % edge == 0 and (edge % 8 == 0 or edge == t), (t, plan)
+                # a chunk is the copied tile or whole lane tiles of it
+                assert plan.block_k % plan.chunk == 0, (t, plan)
+                assert plan.chunk == plan.block_k or plan.chunk % 128 == 0, (t, plan)
+                assert plan.tiles_masked <= plan.tiles_visited
+                assert plan.vmem_bytes <= _VMEM_MOST, (t, plan)
 
     def test_odd_block_sizes_shrink(self):
         rng = np.random.RandomState(1)
