@@ -15,9 +15,10 @@ behavioral, not mechanism parity.  One module per BASELINE.json workload:
 
 Beyond the reference's workloads: :mod:`chartransformer` (the serving
 plane's char-level decoder), :mod:`falcon_h1` (a hybrid Mamba-2 +
-grouped-query attention language model) and :mod:`lfm2_moe` (gated short
-convolutions, grouped-query attention and routed experts), both scored a
-record at a time on the stream path.
+grouped-query attention language model), :mod:`lfm2_moe` (gated short
+convolutions, grouped-query attention and routed experts) and :mod:`kimi_k2`
+(latent attention, a chip's share of the routed experts beside a shared one),
+each scored a record at a time on the stream path.
 """
 
 from flink_tensorflow_tpu.models.zoo.registry import ModelDef, get_model_def, register_model_def

@@ -37,6 +37,7 @@ def flash_attention(
     q, k, v,
     *,
     causal: bool = False,
+    scale: typing.Optional[float] = None,
     block_q: typing.Optional[int] = None,
     block_k: typing.Optional[int] = None,
     interpret: typing.Optional[bool] = None,
@@ -47,6 +48,13 @@ def flash_attention(
     (:func:`tile_plan`); ``block_q`` / ``block_k`` name an edge instead, and
     shrink for short sequences as the chosen ones do.
 
+    ``v`` may have another head size than ``q`` and ``k`` (``[B, Tk, Hkv,
+    Dv]``: latent attention's keys carry a rotary part its values lack); the
+    output is then ``[B, T, H, Dv]``, and nothing is padded to the larger size
+    in HBM.  ``scale`` multiplies the scores (default ``1 / sqrt(D)``); it goes
+    onto ``q`` where that rounds nothing (float32, or a power of two) and onto
+    the float32 scores otherwise.
+
     Grouped queries: ``k`` and ``v`` may carry fewer heads than ``q``
     (``[B, T, Hkv, D]``, ``H`` a multiple of ``Hkv``); query head ``i``
     reads key/value head ``i // (H / Hkv)`` through the kernel's block
@@ -55,9 +63,12 @@ def flash_attention(
     Head sizes it has run at on the chip (TPU v5e, bfloat16, causal,
     4,096 positions: 512 query rows a program, K and V copied whole,
     scores 512 columns at a time): 128 (20 query heads on 4,
-    Falcon-H1) and 64 (32 on 8, LFM2: the block's last dimension is then
-    the whole head, half a lane tile wide).  The tests also run 16, 64
-    and 128 interpreted.
+    Falcon-H1), 64 (32 on 8, LFM2: the block's last dimension is then
+    the whole head, half a lane tile wide), and 192 on ``q`` and ``k``
+    with 128 on ``v`` (64 on 64, a scale of 0.1447 given: latent
+    attention, Kimi-K2; 6.0 ms a call, 58% of its roofline: a head of
+    192 takes two passes of the MXU's 128 on ``q k^T``).  The tests also
+    run 16, 64 and 128, and 24 with 16, interpreted.
 
     ``return_lse=True`` also returns the per-row log-sum-exp
     ``[B, H, T]`` (f32; the call is built without that output otherwise)
@@ -68,22 +79,25 @@ def flash_attention(
     import jax
 
     b, t, h, d = q.shape
-    tk, hkv = k.shape[1], k.shape[2]
+    tk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     if h % hkv or v.shape[2] != hkv:
         raise ValueError(f"{h} query heads cannot share {hkv} key / {v.shape[2]} value heads")
-    plan = tile_plan(t, tk, d, q.dtype, causal, block_q, block_k)
+    if k.shape[3] != d:
+        raise ValueError(f"queries of {d} cannot meet keys of {k.shape[3]}")
+    plan = tile_plan(t, tk, d, q.dtype, causal, block_q, block_k, dv=dv)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
     # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head).
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], x.shape[3])
 
     out, lse = _flash_bh(
         to_bh(q), to_bh(k), to_bh(v), group=h // hkv,
+        scale=1.0 / math.sqrt(d) if scale is None else float(scale),
         causal=causal, plan=plan, interpret=interpret, with_lse=return_lse,
     )
-    out = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
     if return_lse:
         return out, lse.reshape(b, h, t)  # drop the tiling-only unit dim
     return out
@@ -201,20 +215,25 @@ class TilePlan(typing.NamedTuple):
 
 def tile_plan(t: int, tk: int, d: int, dtype, causal: bool,
               block_q: typing.Optional[int] = None,
-              block_k: typing.Optional[int] = None) -> TilePlan:
+              block_k: typing.Optional[int] = None, *,
+              dv: typing.Optional[int] = None) -> TilePlan:
     """The tile of a call, read off its shape.  An edge the caller names is kept
     (as far as `_tileable_block` allows) and is then the compute tile's edge too;
     an edge left out is chosen: up to 512 query rows a program, the whole key
-    sequence copied once if K and V fit 2 MiB each, scores 512 columns at a time."""
+    sequence copied once if K and V fit 2 MiB each, scores 512 columns at a time.
+    ``d`` is the head size of ``q`` and ``k``, ``dv`` that of ``v`` and the output
+    (``d`` if left out): the larger of the two sets the copied tile's rows."""
     import numpy as np
 
     itemsize = np.dtype(dtype).itemsize
     lanes = lambda n: -(-n // _LANES) * _LANES  # noqa: E731  (VMEM pads the last dim)
+    dv = d if dv is None else dv
+    wide = lanes(max(d, dv))
     bq = _tileable_block(t, block_q or _PREF_BLOCK_Q)
     if block_k:
         bk = chunk = _tileable_block(tk, block_k)
     else:
-        bk = _tileable_block(tk, max(_PREF_CHUNK, _KV_TILE_BYTES // (lanes(d) * itemsize)))
+        bk = _tileable_block(tk, max(_PREF_CHUNK, _KV_TILE_BYTES // (wide * itemsize)))
         # A chunk inside the copied tile starts at a multiple of itself: whole lane
         # tiles of scores and whole sublane tiles of K, or the tile is one chunk.
         chunk = next((c for c in (_PREF_CHUNK, 256, 128) if bk % c == 0), bk)
@@ -224,9 +243,9 @@ def tile_plan(t: int, tk: int, d: int, dtype, causal: bool,
             whole, some = _chunk_counts(qi, j, bq, bk, chunk, causal)
             visited, masked = visited + some, masked + some - whole
     vmem = (
-        2 * 2 * bq * lanes(d) * itemsize        # q and out blocks, double-buffered
-        + 2 * 2 * bk * lanes(d) * itemsize      # K and V tiles, double-buffered
-        + bq * lanes(d) * (4 + itemsize)        # accumulator, scaled q
+        2 * bq * (lanes(d) + lanes(dv)) * itemsize    # q and out blocks, double-buffered
+        + 2 * bk * (lanes(d) + lanes(dv)) * itemsize  # K and V tiles, double-buffered
+        + bq * (lanes(dv) * 4 + lanes(d) * itemsize)  # accumulator, scaled q
         + 2 * bq * _LANES * 4                   # running max and denominator
         + 2 * bq * _LANES * 4                   # the lse block, double-buffered
         + 4 * bq * lanes(chunk) * 4             # scores, mask, exp and its cast
@@ -261,10 +280,11 @@ def _vma(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
-def _flash_bh(q, k, v, *, causal, plan, interpret, group=1, with_lse=True):
-    """``q`` ``[B*H, T, D]``; ``k``, ``v`` ``[B*H/group, Tk, D]``: row ``i`` of
-    ``q`` reads row ``i // group`` of ``k`` and ``v``.  Returns ``(out, lse)``,
-    ``lse`` ``None`` unless asked for."""
+def _flash_bh(q, k, v, *, scale, causal, plan, interpret, group=1, with_lse=True):
+    """``q`` ``[B*H, T, D]``; ``k`` ``[B*H/group, Tk, D]``, ``v`` ``[B*H/group,
+    Tk, Dv]``: row ``i`` of ``q`` reads row ``i // group`` of ``k`` and ``v``.
+    Returns ``(out, lse)``, ``out`` ``[B*H, T, Dv]``, ``lse`` ``None`` unless
+    asked for."""
     import jax
 
     bh, t, d = q.shape
@@ -272,13 +292,14 @@ def _flash_bh(q, k, v, *, causal, plan, interpret, group=1, with_lse=True):
     fn = _build_flash_call(
         bh, t, k.shape[1], d, jax.numpy.dtype(q.dtype).name, causal,
         plan, interpret, _vma(q, k, v), group, with_lse,
+        v.shape[2], scale,
     )
     return fn(q, k, v)
 
 
 @functools.lru_cache(maxsize=256)
 def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
-                      group=1, with_lse=True):
+                      group, with_lse, dv, scale):
     """Jitted pallas_call per static configuration.  Building a fresh
     closure per invocation would defeat jax.jit's cache (keyed on the
     function object) and recompile the Mosaic kernel on EVERY eager call."""
@@ -290,7 +311,6 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
     dtype = jnp.dtype(dtype_str)
     block_q, block_k, chunk = plan.block_q, plan.block_k, plan.chunk
     nq, nk = t // block_q, tk // block_k
-    scale = 1.0 / math.sqrt(d)
     # Mosaic's default contraction feeds the MXU one bf16 pass whatever
     # the operand dtype: a silent downcast of q/k/v/p for float32 callers,
     # who get float32 operands at HIGHEST; narrower callers' tiles go to the
@@ -330,7 +350,7 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
         def _init():
             m_scr[...] = jnp.full((block_q, _LANES), -jnp.inf, jnp.float32)
             l_scr[...] = jnp.zeros((block_q, _LANES), jnp.float32)
-            acc_scr[...] = jnp.zeros((block_q, d), jnp.float32)
+            acc_scr[...] = jnp.zeros((block_q, dv), jnp.float32)
             if scale_q:
                 q_scr[...] = q_ref[0] * scale
 
@@ -363,7 +383,7 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
             alpha = jnp.exp(m_prev - m_next)
             m_scr[...] = m_next
             l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[...] = acc_scr[...] * across(alpha, d) + jnp.dot(
+            acc_scr[...] = acc_scr[...] * across(alpha, dv) + jnp.dot(
                 p.astype(v_blk.dtype), v_blk, precision=precision,
                 preferred_element_type=jnp.float32)
 
@@ -377,7 +397,7 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
         def _finalize():
             l = l_scr[...]
             denom = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0] = (acc_scr[...] / across(denom, d)).astype(o_ref.dtype)
+            o_ref[0] = (acc_scr[...] / across(denom, dv)).astype(o_ref.dtype)
             if with_lse:
                 # log-sum-exp residual; rows that saw no key (l=0, m=-inf) -> -inf.
                 lse = jnp.where(l == 0.0, -jnp.inf, m_scr[...] + jnp.log(denom))
@@ -385,9 +405,11 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b_, qi, j: (b_, qi, 0),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM)
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma)]
+    k_spec = pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM)
+    v_spec = pl.BlockSpec((1, block_k, dv), kv_tile, memory_space=pltpu.VMEM)
+    out_specs = [pl.BlockSpec((1, block_q, dv), lambda b_, qi, j: (b_, qi, 0),
+                              memory_space=pltpu.VMEM)]
+    out_shape = [jax.ShapeDtypeStruct((bh, t, dv), dtype, vma=vma)]
     if with_lse:
         # Trailing unit dim keeps the block's last-two dims TPU-tileable
         # ((block_q, 1) instead of (1, block_q)).
@@ -397,13 +419,13 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
     call = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ] + ([pltpu.VMEM((block_q, d), dtype)] if scale_q else []),
         # bh and q-blocks are independent programs (scratch re-inits at
         # j==0 per (bh, qi)): declaring them parallel lets Mosaic

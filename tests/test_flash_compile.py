@@ -28,11 +28,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-#: (q shape [B, T, H, D], key positions, key/value heads, dtype, causal, return_lse)
+#: (q shape [B, T, H, D], key positions, key/value heads, dtype, causal, return_lse[, the values' head size])
 _SHAPES = {
-    # the two language-model cells
+    # the three language-model cells
     "falcon_h1-20on4-head128": ((2, 4096, 20, 128), 4096, 4, "bfloat16", True, False),
     "lfm2-32on8-head64": ((2, 4096, 32, 64), 4096, 8, "bfloat16", True, False),
+    "kimi_k2-64on64-head192-values128": ((2, 4096, 64, 192), 4096, 64, "bfloat16", True, False, 128),
+    "values-narrower-float32": ((1, 1024, 2, 192), 1024, 2, "float32", True, True, 128),
     # chartransformer's prefill and chip_smoke's long-sequence shape
     "prefill-128": ((8, 128, 4, 16), 128, 4, "float32", True, True),
     "bf16-256-full": ((2, 256, 4, 64), 256, 4, "bfloat16", False, True),
@@ -53,17 +55,21 @@ _SHAPES = {
 
 @pytest.mark.parametrize("case", list(_SHAPES), ids=list(_SHAPES))
 def test_chosen_tile_compiles_for_v5e(one_chip, case):
-    shape, tk, kv_heads, dtype, causal, return_lse = _SHAPES[case]
+    shape, tk, kv_heads, dtype, causal, return_lse, *dv = _SHAPES[case]
     b, t, _, d = shape
+    dv = dv[0] if dv else d
     q = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, tk, kv_heads, d), jnp.dtype(dtype), sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, tk, kv_heads, d), jnp.dtype(dtype), sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, tk, kv_heads, dv), jnp.dtype(dtype), sharding=one_chip)
 
     def call(q, k, v):
-        return flash_attention(q, k, v, causal=causal, interpret=False, return_lse=return_lse)
+        # A scale is given where the head sizes differ, as latent attention gives one.
+        return flash_attention(q, k, v, causal=causal, interpret=False, return_lse=return_lse,
+                               scale=0.1447 if dv != d else None)
 
-    compiled = jax.jit(call).lower(q, kv, kv).compile()
+    compiled = jax.jit(call).lower(q, k, v).compile()
     # one kernel a call, under the name the benchmark's roofline share reads
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
     assert "flash_attention" in compiled.as_text()
-    plan = tile_plan(t, tk, d, jnp.dtype(dtype), causal)
+    plan = tile_plan(t, tk, d, jnp.dtype(dtype), causal, dv=dv)
     assert t % plan.block_q == 0 and tk % plan.block_k == 0 and plan.block_k % plan.chunk == 0

@@ -320,17 +320,29 @@ COUNTER_METRICS = [m for m in (json.loads(p.read_text()) for p in sorted(
     (REPO / "benchmark" / "layer_metrics").glob("*.json"))) if m["reader"] == "counters"]
 
 
-def _routed_job(name):
+#: Tiny routed language models, one that holds every expert and one that holds
+#: a share of them (and so also counts the passes over its share's buffers).
+_ROUTED = {
+    "lfm2_moe": dict(seq_len=8, vocab_size=64, hidden_size=32, intermediate_size=64,
+                     moe_intermediate_size=16, num_hidden_layers=3, num_dense_layers=1,
+                     layer_types=("conv", "full_attention", "conv"), num_attention_heads=4,
+                     num_key_value_heads=2, num_experts=4, num_experts_per_tok=2),
+    "kimi_k2": dict(seq_len=8, vocab_size=64, hidden_size=32, intermediate_size=64,
+                    moe_intermediate_size=16, num_hidden_layers=2, num_attention_heads=2,
+                    num_key_value_heads=2, q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
+                    qk_rope_head_dim=8, v_head_dim=8, n_routed_experts=2, router_experts=8,
+                    num_experts_per_tok=2),
+}
+
+
+def _routed_job(name, architecture):
     """token records -> count_window(2) -> a tiny routed language model ->
     list, two windows: the counters a method makes on the device."""
     import jax
 
     from flink_tensorflow_tpu.models import get_model_def
 
-    mdef = get_model_def("lfm2_moe", seq_len=8, vocab_size=64, hidden_size=32, intermediate_size=64,
-                         moe_intermediate_size=16, num_hidden_layers=3, num_dense_layers=1,
-                         layer_types=("conv", "full_attention", "conv"), num_attention_heads=4,
-                         num_key_value_heads=2, num_experts=4, num_experts_per_tok=2)
+    mdef = get_model_def(architecture, **_ROUTED[architecture])
     rng = np.random.RandomState(0)
     recs = [TensorValue({"tokens": rng.randint(0, 64, 8).astype(np.int32)}) for _ in range(4)]
     env = StreamExecutionEnvironment(parallelism=1)
@@ -349,7 +361,8 @@ def registry(lenet):
     """What the registry reports after one job of each kind the benchmark
     runs, with the operators named as its jobs name them."""
     handle, _ = _job(lenet, "registry-stream", source=_paced_source(), source_name="offered")
-    return {**_routed_job("registry-routed").metrics, **handle.executor.metrics.report(),
+    return {**_routed_job("registry-routed", "lfm2_moe").metrics,
+            **_routed_job("registry-share", "kimi_k2").metrics, **handle.executor.metrics.report(),
             **_train_job("registry-train").metrics}
 
 
