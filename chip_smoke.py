@@ -269,7 +269,9 @@ def phase2_flash(device, *, interpret=False, cell_positions=4096):
     serving prefill shape and at the long-sequence bf16 shape, then at the
     two language-model cells' own shapes (``cell_positions`` long, the tile
     the kernel chooses) against plain attention on the first 1,024 positions
-    and on every eighth after: a layout Mosaic takes and miscompiles shows
+    and on every eighth after, and latent attention's call (operands in
+    ``[B, T, H x D]``, one rotary key head) against the plain call on
+    concatenated operands: a layout Mosaic takes and miscompiles shows
     here, not in a benchmark run's ``logit_rms_err``.  With
     ``interpret=False`` a Mosaic refusal surfaces here as the compile
     error it is — nothing retries interpreted."""
@@ -330,6 +332,36 @@ def phase2_flash(device, *, interpret=False, cell_positions=4096):
         checked.append({"shape": [2, t, heads, d], "kv_heads": kv_heads, "dtype": "bfloat16",
                         "causal": True, "interpret": interpret,
                         "max_err": float(err.max())})
+    # Latent attention's call at the Kimi cell's heads (64 of 128 + 64 on values of 128): q_nope, k_nope
+    # and v where their products wrote them, q_pe in float32 and turned in the kernel, one rotary key
+    # head for all, against the plain call on the concatenated operands.
+    from flink_tensorflow_tpu.ops.mla import rope_pairs
+
+    heads, nope, rope = 64, 128, 64
+    q_nope, k_nope, v = (jax.device_put(jnp.asarray(rng.randn(2, t, heads, nope), jnp.bfloat16), device)
+                         for _ in range(3))
+    q_pe = jax.device_put(jnp.asarray(rng.randn(2, t, heads, rope), jnp.float32), device)
+    angle = np.arange(t)[:, None] * 10.0 ** -np.linspace(0, 4, rope // 2)[None, :]
+    cos, sin = (jax.device_put(jnp.asarray(f(angle), jnp.float32), device) for f in (np.cos, np.sin))
+    k_pe = rope_pairs(jax.device_put(jnp.asarray(rng.randn(2, t, rope), jnp.float32), device),
+                      cos, sin).astype(jnp.bfloat16)
+    got = flash_attention(q_nope, k_nope, v, q_rope=q_pe, k_rope=k_pe, rotate=(cos, sin), causal=True,
+                          scale=0.1447, interpret=interpret)
+    want = flash_attention(
+        jnp.concatenate([q_nope, rope_pairs(q_pe, cos, sin).astype(jnp.bfloat16)], axis=-1),
+        jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[:, :, None], (2, t, heads, rope))], axis=-1),
+        v, causal=True, scale=0.1447, interpret=interpret)
+    _check(got.shape == v.shape and got.dtype == v.dtype, got.shape, got.dtype)
+    want = np.asarray(want, np.float32)
+    err = np.abs(np.asarray(got, np.float32) - want)
+    _check(np.isfinite(err).all(), "non-finite attention output")
+    # The same products in the same order; q_pe is turned by the kernel's own float32 arithmetic, so a
+    # rounding of it may fall the other way: a last place of a bfloat16 result, 2**-8 of its size.
+    err = err / np.maximum(1.0, np.abs(want))
+    _check(err.max() < 1.2e-2, "split entry against the plain call", float(err.max()))
+    checked.append({"shape": [2, t, heads, nope], "rope": rope, "dtype": "bfloat16", "causal": True,
+                    "interpret": interpret, "max_err": float(err.max()),
+                    "differ": int((err > 0).sum()), "of": int(err.size)})
     return {"checked": checked}
 
 

@@ -42,6 +42,9 @@ def flash_attention(
     block_k: typing.Optional[int] = None,
     interpret: typing.Optional[bool] = None,
     return_lse: bool = False,
+    q_rope=None,
+    k_rope=None,
+    rotate=None,
 ):
     """Attention over ``[B, T, H, D]`` tensors (same layout/semantics as
     parallel.full_attention).  The kernel picks its tile from the shape
@@ -60,15 +63,35 @@ def flash_attention(
     reads key/value head ``i // (H / Hkv)`` through the kernel's block
     index, so no repeated copy of K or V is ever made in HBM.
 
+    A rotary part handed over beside ``q`` and ``k`` (latent attention:
+    ``q_rope`` ``[B, T, H, R]``, ``k_rope`` ``[B, Tk, R]``, ONE head that
+    every query head reads): scores are ``(q . k + q_rope . k_rope) *
+    scale`` (default ``1 / sqrt(D + R)``), summed in float32, and the
+    call takes a path of its own (:func:`_flash_split`) on which every
+    operand is read where its projection wrote it: ``q``, ``k``, ``v``
+    out of ``[B, T, H x D]`` through the block index, ``k_rope``'s tile
+    by all heads, the output written ``[B, T, H x Dv]``; nothing is
+    transposed, concatenated or repeated over heads in HBM.  Compiled,
+    that path needs ``D`` and ``Dv`` in whole lane tiles of 128 (or one
+    head).  ``k_rope`` comes turned; ``q_rope`` too, unless ``rotate=(cos,
+    sin)`` (``[T, R / 2]`` each, as ``ops.mla.rope_pairs`` takes them) is
+    given: then ``q_rope`` is what its projection wrote (float32, say)
+    and the kernel turns adjacent pairs of a block in float32 before it
+    rounds them to ``q``'s dtype.  A call without ``q_rope`` traces to
+    the program it always did.
+
     Head sizes it has run at on the chip (TPU v5e, bfloat16, causal,
     4,096 positions: 512 query rows a program, K and V copied whole,
     scores 512 columns at a time): 128 (20 query heads on 4,
     Falcon-H1), 64 (32 on 8, LFM2: the block's last dimension is then
-    the whole head, half a lane tile wide), and 192 on ``q`` and ``k``
-    with 128 on ``v`` (64 on 64, a scale of 0.1447 given: latent
-    attention, Kimi-K2; 6.0 ms a call, 58% of its roofline: a head of
-    192 takes two passes of the MXU's 128 on ``q k^T``).  The tests also
-    run 16, 64 and 128, and 24 with 16, interpreted.
+    the whole head, half a lane tile wide), and latent attention's 128
+    with a rotary part of 64 on ``q`` and ``k`` and 128 on ``v`` (64 on
+    64, a scale of 0.1447 given, ``q_rope`` turned in the kernel:
+    Kimi-K2; 6.34 ms a call, 55% of its roofline: the rotary product
+    fills half the MXU's 128 and takes a pass of its own; the same
+    heads concatenated to 192 through the plain call took 6.00 ms and
+    4.5 ms of copies around it).  The tests also run 16, 64 and 128, 24
+    with 16, and 24 + 8 with 16, interpreted.
 
     ``return_lse=True`` also returns the per-row log-sum-exp
     ``[B, H, T]`` (f32; the call is built without that output otherwise)
@@ -84,9 +107,12 @@ def flash_attention(
         raise ValueError(f"{h} query heads cannot share {hkv} key / {v.shape[2]} value heads")
     if k.shape[3] != d:
         raise ValueError(f"queries of {d} cannot meet keys of {k.shape[3]}")
-    plan = tile_plan(t, tk, d, q.dtype, causal, block_q, block_k, dv=dv)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if q_rope is not None:
+        return _flash_split(q, q_rope, k, k_rope, v, causal=causal, scale=scale, block_q=block_q,
+                            block_k=block_k, interpret=interpret, return_lse=return_lse, rotate=rotate)
+    plan = tile_plan(t, tk, d, q.dtype, causal, block_q, block_k, dv=dv)
 
     # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head).
     def to_bh(x):
@@ -101,6 +127,57 @@ def flash_attention(
     if return_lse:
         return out, lse.reshape(b, h, t)  # drop the tiling-only unit dim
     return out
+
+
+def _flash_split(q, q_rope, k, k_rope, v, *, causal, scale, block_q, block_k, interpret, return_lse, rotate):
+    """The call with a rotary part handed over beside ``q`` and ``k``
+    (:func:`flash_attention` has the shapes): every operand is read where its
+    projection wrote it.  ``[B, T, H, D]`` is viewed ``[B, T, H x D]``, which
+    moves nothing, and a program's block is head ``h``'s ``D`` lanes of a row
+    block; the output is written the same way.  ``q_rope``'s block holds the
+    rotary parts of as many heads as fill whole lane tiles
+    (:func:`_rope_heads`: two at 64 a head), the program turns it if asked to
+    and zeroes the other heads' lanes, once a q block, and ``k_rope`` is
+    repeated across those lanes (a copy of ``[B, Tk, 128]``, one head), so
+    that the rotary product is one plain contraction over the block's lanes
+    and no lane is ever shifted between heads."""
+    import jax.numpy as jnp
+
+    b, t, h, d = q.shape
+    tk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
+    rope = q_rope.shape[3]
+    if q_rope.shape != (b, t, h, rope) or k_rope is None or k_rope.shape != (b, tk, rope):
+        raise ValueError(f"rotary parts of {q_rope.shape} on queries of {q.shape} and "
+                         f"{None if k_rope is None else k_rope.shape} on keys of {k.shape}")
+    if not interpret and any(n > 1 and w % _LANES for n, w in ((h, d), (hkv, d), (hkv, dv))):
+        raise ValueError(f"heads of {d} and {dv} are no whole lane tiles: Mosaic cannot block them "
+                         f"out of [B, T, H x D]; concatenate and make the plain call")
+    per = _rope_heads(h, rope)
+    plan = tile_plan(t, tk, d + rope, q.dtype, causal, block_q, block_k, dv=dv)
+    if rotate is not None:  # blocks of cos and sin, and q_rope's in float32, double-buffered
+        plan = plan._replace(vmem_bytes=plan.vmem_bytes + 3 * 2 * plan.block_q * per * rope * 4)
+    fn = _build_flash_call(
+        b * h, t, tk, d, jnp.dtype(q.dtype).name, causal, plan, interpret,
+        _vma(q, q_rope, k, k_rope, v), h // hkv, return_lse, dv,
+        1.0 / math.sqrt(d + rope) if scale is None else float(scale), h, rope, rotate is not None,
+    )
+    # cos and sin of a position's pairs, each beside itself (-sin, sin: the sign an even lane's
+    # partner takes), across the block's heads
+    turn = [jnp.tile((jnp.stack([x, x], axis=-1) * sign).reshape(t, rope), (1, per))
+            for x, sign in zip(rotate or (), (jnp.ones(2, jnp.float32), jnp.asarray([-1.0, 1.0], jnp.float32)))]
+    out, lse = fn(q.reshape(b, t, h * d), q_rope.reshape(b, t, h * rope), *turn, k.reshape(b, tk, hkv * d),
+                  jnp.tile(k_rope, (1, 1, per)), v.reshape(b, tk, hkv * dv))
+    out = out.reshape(b, t, h, dv)
+    if return_lse:
+        return out, lse.reshape(b, h, t)
+    return out
+
+
+def _rope_heads(heads: int, rope: int) -> int:
+    """How many heads' rotary parts one block of ``q_rope`` ``[B, T, H x rope]``
+    holds: the fewest that fill whole lane tiles (Mosaic blocks the last
+    dimension by whole tiles of 128 or not at all), else all of them."""
+    return next((n for n in range(1, heads) if heads % n == 0 and n * rope % _LANES == 0), heads)
 
 
 def flash_attention_decode(
@@ -299,10 +376,17 @@ def _flash_bh(q, k, v, *, scale, causal, plan, interpret, group=1, with_lse=True
 
 @functools.lru_cache(maxsize=256)
 def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
-                      group, with_lse, dv, scale):
+                      group, with_lse, dv, scale, heads=0, rope=0, rotate=False):
     """Jitted pallas_call per static configuration.  Building a fresh
     closure per invocation would defeat jax.jit's cache (keyed on the
-    function object) and recompile the Mosaic kernel on EVERY eager call."""
+    function object) and recompile the Mosaic kernel on EVERY eager call.
+
+    With ``rope`` (:func:`_flash_split`) the operands are ``q`` ``[B, T, heads x
+    d]``, ``q_rope`` ``[B, T, heads x rope]``, ``k`` ``[B, Tk, heads / group x
+    d]``, ``k_rope`` ``[B, Tk, lanes]`` (one head, repeated over a ``q_rope``
+    block's lanes) and ``v``; grid row ``b_`` is head ``b_ % heads`` of batch
+    ``b_ // heads``, and the body is the same but for a second product into the
+    scores."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -320,6 +404,9 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
     # The scale goes onto q once a q block where that rounds nothing (float32,
     # or a power of two: 1/8 at a head of 64), else onto the float32 scores.
     scale_q = wide or math.frexp(scale)[0] == 0.5
+    # A q_rope block holds `per` heads' rotary parts, `lanes` wide in all.
+    per = _rope_heads(heads, rope) if rope else 0
+    lanes = per * rope
 
     def kv_tile(b_, qi, j):
         # Row b_ of q reads row b_ // group of k and v (grouped queries).
@@ -329,22 +416,38 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
             j = jnp.minimum(j, (qi * block_q + block_q - 1) // block_k)
         return (b_ // group, j, 0)
 
+    def head_of(index, each=1):
+        """``index`` (a `[B*H, ..]` block index) on ``[B, .., H x D]``: the batch
+        first, the head last, ``each`` heads a block (all of them: one block for all)."""
+        def split(b_, qi, j):
+            _, row, _ = index(b_, qi, j)
+            return (b_ // heads, row, b_ % heads // each)
+        return split
+
     def across(x, n):
         """Lane-replicated ``(block_q, 128)`` statistics, ``n`` lanes wide."""
         if n % _LANES == 0:
             return jnp.tile(x, (1, n // _LANES))
         return x[:, :n] if n < _LANES else jnp.broadcast_to(x[:, :1], (block_q, n))
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, *rest):
+    def kernel(q_ref, *refs):
+        if rotate:
+            qr_ref, cos_ref, sin_ref, k_ref, kr_ref, v_ref, o_ref, *rest = refs
+        elif rope:
+            qr_ref, k_ref, kr_ref, v_ref, o_ref, *rest = refs
+        else:
+            k_ref, v_ref, o_ref, *rest = refs
         # Grid (bh, nq, nk): the innermost k dimension iterates sequentially
         # on TPU, so the VMEM scratch carries the online softmax across K/V
         # tiles and across the chunks of one.  The running max and denominator
         # stay two-dimensional and lane-replicated from the reduction to the
         # store: `s - m` and `acc * alpha` are then plain vector ops.
         lse_ref, (m_scr, l_scr, acc_scr, *q_scr) = (rest[0], rest[1:]) if with_lse else (None, rest)
+        qr_scr = q_scr.pop() if rope else None  # this head's rotary part, the block's other lanes zero
         q_scr = q_scr[0] if scale_q else None  # q times the scale, once a q block
         qi = pl.program_id(1)
         j = pl.program_id(2)
+        head = pl.program_id(0) % heads if rope else None
 
         @pl.when(j == 0)
         def _init():
@@ -353,6 +456,19 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
             acc_scr[...] = jnp.zeros((block_q, dv), jnp.float32)
             if scale_q:
                 q_scr[...] = q_ref[0] * scale
+            if rope:
+                lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, lanes), 1)
+                x = qr_ref[0]
+                if rotate:
+                    # (x[2j], x[2j+1]) turns to (x[2j] cos - x[2j+1] sin, x[2j+1] cos + x[2j] sin), in
+                    # float32; sin_ref holds -sin on the even lanes.  (The sum in this order: on the CPU
+                    # XLA contracts one of the products into the add, and tests/benchmark/test_kimi_k2.py
+                    # holds a float32 program to the reference's routing across a tie of three ulps.)
+                    x = x.astype(jnp.float32)
+                    partner = jnp.where(lane % 2 == 0, pltpu.roll(x, lanes - 1, 1), pltpu.roll(x, 1, 1))
+                    x = (partner * sin_ref[...] + x * cos_ref[...]).astype(dtype)
+                mine = jnp.where(lane // rope == head % per, x, 0)
+                qr_scr[...] = mine * scale if scale_q else mine
 
         def update(c, masked):
             """Fold chunk ``c`` of this K tile into the running softmax.  No row
@@ -369,6 +485,12 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
             s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
                                     precision=precision,
                                     preferred_element_type=jnp.float32)
+            if rope:
+                # The other heads' lanes of q are zero, so k_rope's copies there add nothing.
+                kr_blk = kr_ref[0] if chunk == block_k else kr_ref[0, cols, :]
+                s = s + jax.lax.dot_general(qr_scr[...], kr_blk, (((1,), (1,)), ((), ())),
+                                            precision=precision,
+                                            preferred_element_type=jnp.float32)
             if not scale_q:
                 s = s * scale
             if masked:
@@ -403,30 +525,46 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
                 lse = jnp.where(l == 0.0, -jnp.inf, m_scr[...] + jnp.log(denom))
                 lse_ref[0] = lse[:, :1]
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b_, qi, j: (b_, qi, 0),
-                          memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, block_k, d), kv_tile, memory_space=pltpu.VMEM)
-    v_spec = pl.BlockSpec((1, block_k, dv), kv_tile, memory_space=pltpu.VMEM)
-    out_specs = [pl.BlockSpec((1, block_q, dv), lambda b_, qi, j: (b_, qi, 0),
-                              memory_space=pltpu.VMEM)]
-    out_shape = [jax.ShapeDtypeStruct((bh, t, dv), dtype, vma=vma)]
+    def spec(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    def q_tile(b_, qi, j):
+        return (b_, qi, 0)
+
+    if rope:
+        in_specs = [
+            spec((1, block_q, d), head_of(q_tile)),
+            spec((1, block_q, lanes), head_of(q_tile, per)),
+            *[spec((block_q, lanes), lambda b_, qi, j: (qi, 0))] * (2 * rotate),  # cos, sin: by position alone
+            spec((1, block_k, d), head_of(kv_tile, group)),
+            # one head for all: the block index does not change with the head, so the
+            # tile is copied once a batch row and every head's programs read it
+            spec((1, block_k, lanes), head_of(kv_tile, heads)),
+            spec((1, block_k, dv), head_of(kv_tile, group)),
+        ]
+        out_specs = [spec((1, block_q, dv), head_of(q_tile))]
+        out_shape = [jax.ShapeDtypeStruct((bh // heads, t, heads * dv), dtype, vma=vma)]
+    else:
+        in_specs = [spec((1, block_q, d), q_tile), spec((1, block_k, d), kv_tile), spec((1, block_k, dv), kv_tile)]
+        out_specs = [spec((1, block_q, dv), q_tile)]
+        out_shape = [jax.ShapeDtypeStruct((bh, t, dv), dtype, vma=vma)]
     if with_lse:
         # Trailing unit dim keeps the block's last-two dims TPU-tileable
         # ((block_q, 1) instead of (1, block_q)).
-        out_specs.append(pl.BlockSpec((1, block_q, 1), lambda b_, qi, j: (b_, qi, 0),
-                                      memory_space=pltpu.VMEM))
+        out_specs.append(spec((1, block_q, 1), q_tile))
         out_shape.append(jax.ShapeDtypeStruct((bh, t, 1), jnp.float32, vma=vma))
     call = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
-        in_specs=[q_spec, k_spec, v_spec],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
-        ] + ([pltpu.VMEM((block_q, d), dtype)] if scale_q else []),
+        ] + ([pltpu.VMEM((block_q, d), dtype)] if scale_q else [])
+        + ([pltpu.VMEM((block_q, lanes), dtype)] if rope else []),
         # bh and q-blocks are independent programs (scratch re-inits at
         # j==0 per (bh, qi)): declaring them parallel lets Mosaic
         # megacore-partition the grid on v4/v5p; only the K sweep is
@@ -444,8 +582,8 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
         name="flash_attention",
     )
 
-    def fn(q, k, v):
-        out, *lse = call(q, k, v)
+    def fn(*operands):
+        out, *lse = call(*operands)
         return out, (lse[0] if with_lse else None)
 
     return jax.jit(fn)

@@ -8,24 +8,33 @@ same layer).
 ``u`` ``[B, T, d]``, the layer's normed input; ``norm`` an RMS norm with a
 weight:
 
-- ``latent_q``: ``c_q = norm(u W_qa)`` (``q_lora_rank``); ``q = c_q W_qb``,
-  ``heads`` heads of ``q_nope | q_pe``.
+- ``latent_q``: ``c_q = norm(u W_qa)`` (``q_lora_rank``); ``c_q W_qb``: ``heads``
+  heads of ``q_nope | q_pe``.  ``W_qb`` is split by columns before the product,
+  so that ``q_nope`` ``[B, T, heads x nope]`` and ``q_pe`` ``[B, T, heads x
+  rope]`` come out of two products and neither is sliced out of a third.
 - ``latent_kv``: ``u W_kva`` = ``c | k_pe``; ``c_kv = norm(c)``
-  (``kv_lora_rank``); ``c_kv W_kvb``: ``heads`` heads of ``k_nope | v``.  ``W_kvb``
-  is split by columns before the product, so that ``k_nope`` and ``v`` come
-  out as two tensors and neither is sliced out of a third.
+  (``kv_lora_rank``); ``c_kv W_kvb``: ``heads`` heads of ``k_nope | v``, ``W_kvb``
+  split likewise.
 - ``rope``: on ``q_pe`` and ``k_pe``, adjacent pairs ``(x[2j], x[2j+1])``,
   yarn frequencies (:func:`yarn_inv_freq`).  (The published code first
   regroups the pairs into halves on ``q`` and ``k`` alike; every dot product is
-  the same.)
+  the same.)  ``k_pe``, ONE head, is turned here (:func:`rope_pairs`); ``q_pe``
+  goes to the kernel as its product wrote it, float32 and not yet turned, with
+  the positions' ``cos`` and ``sin``, and the kernel turns a block of it in
+  VMEM by the same float32 arithmetic: no pass over ``q_pe`` is made in HBM.
 - ``attention``: scores ``(q_nope . k_nope + q_pe . k_pe) * scale``,
   :func:`softmax_scale`; causal softmax; ``P v``; ``W_o``.  The flash kernel
-  (ops/flash_attention.py) at heads of ``(nope + rope, v)``.  ``k_pe`` is
-  written beside every head's ``k_nope``, ``[B, T, heads, nope + rope]`` in
-  HBM: a third of ``k`` is the one rotary head ``heads`` times over (67 of
-  201 MB a layer at 2 x 4,096 positions and 64 heads of 128 + 64), which the
-  kernel could read through its block index instead, as it reads a grouped
-  key head; PERF.md section 7 has it as an open lever.
+  (ops/flash_attention.py, ``q_rope=``, ``k_rope=``, ``rotate=``) reads every
+  operand where and as its projection wrote it: ``q_nope``, ``k_nope`` and
+  ``v`` out of ``[B, T, heads x 128]``, a program's block head ``h``'s lanes of
+  a row block at block index ``(b, row block, h)``; ``q_pe`` two heads a block
+  of 128 lanes, the other head's lanes zeroed in VMEM; ``k_pe`` ``[B, T,
+  rope]``, whose block index names no head, so that all ``heads`` query heads
+  read the one tile; the output written ``[B, T, heads x v]``, which is what
+  ``W_o`` consumes.  ``q`` and ``k`` are never concatenated, ``k_pe`` is never
+  written beside a head's ``k_nope``, and no transposed copy of ``q``, ``k``,
+  ``v`` or the output exists in HBM (``tests/test_mla_layout.py`` searches the
+  traced layer for one).
 
 Precision: products take ``compute_dtype`` operands and accumulate in float32;
 the two latent norms, RoPE and the softmax statistics are float32.  The scale
@@ -112,15 +121,17 @@ def latent_attention(p, u, *, num_heads: int, qk_nope_head_dim: int, qk_rope_hea
         return jnp.dot(x.astype(cdt), w.astype(cdt), precision=exact, preferred_element_type=F32)
 
     with jax.named_scope("latent_q"):
-        q = dot(rms_norm(dot(u, p["q_a"]), p["q_a_norm"], eps), p["q_b"])
-        q = q.reshape(b, t, num_heads, nope + rope)
+        c_q = rms_norm(dot(u, p["q_a"]), p["q_a_norm"], eps)
+        w_qb = p["q_b"].reshape(c_q.shape[-1], num_heads, nope + rope)
+        q_nope = dot(c_q, w_qb[..., :nope].reshape(-1, num_heads * nope)).astype(cdt)
+        q_pe = dot(c_q, w_qb[..., nope:].reshape(-1, num_heads * rope)).reshape(b, t, num_heads, rope)
     with jax.named_scope("latent_kv"):
         kv = dot(u, p["kv_a"])
         rank = kv.shape[-1] - rope
         c_kv, k_pe = rms_norm(kv[..., :rank], p["kv_a_norm"], eps), kv[..., rank:]
         w_kvb = p["kv_b"].reshape(rank, num_heads, nope + dv)
-        k_nope = dot(c_kv, w_kvb[..., :nope].reshape(rank, num_heads * nope)).reshape(b, t, num_heads, nope)
-        v = dot(c_kv, w_kvb[..., nope:].reshape(rank, num_heads * dv)).reshape(b, t, num_heads, dv)
+        k_nope = dot(c_kv, w_kvb[..., :nope].reshape(rank, num_heads * nope)).astype(cdt)
+        v = dot(c_kv, w_kvb[..., nope:].reshape(rank, num_heads * dv)).astype(cdt)
     with jax.named_scope("rope"):
         if rope_scaling.get("type", rope_scaling.get("rope_type")) != "yarn":
             raise ValueError(f"rope_scaling of type yarn, not {rope_scaling!r}")
@@ -130,10 +141,10 @@ def latent_attention(p, u, *, num_heads: int, qk_nope_head_dim: int, qk_rope_hea
         factor, m, m_all = (rope_scaling.get(key) for key in ("factor", "mscale", "mscale_all_dim"))
         stretch = yarn_mscale(factor, m) / yarn_mscale(factor, m_all) if m and m_all else yarn_mscale(factor, 1.0)
         cos, sin = (jnp.asarray(fn(angle) * stretch, F32) for fn in (np.cos, np.sin))
-        q = jnp.concatenate([q[..., :nope], rope_pairs(q[..., nope:], cos, sin)], axis=-1)
-        k_pe = rope_pairs(k_pe, cos, sin)
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[:, :, None, :], (b, t, num_heads, rope))], axis=-1)
+        k_pe = rope_pairs(k_pe, cos, sin).astype(cdt)
     with jax.named_scope("attention"):
-        out = flash_attention(q.astype(cdt), k.astype(cdt), v.astype(cdt), causal=True,
+        # [B, T, H x D] viewed [B, T, H, D] and back inside the kernel's entry: nothing moves.
+        out = flash_attention(q_nope.reshape(b, t, num_heads, nope), k_nope.reshape(b, t, num_heads, nope),
+                              v.reshape(b, t, num_heads, dv), q_rope=q_pe, k_rope=k_pe, rotate=(cos, sin), causal=True,
                               scale=softmax_scale(nope + rope, rope_scaling))
         return dot(out.reshape(b, t, num_heads * dv), p["o"])

@@ -32,7 +32,9 @@ def test_phase2_serving_and_interpreted_kernel():
     flash = chip_smoke.phase2_flash(dev, interpret=True, cell_positions=256)
     assert [c["shape"] for c in flash["checked"]] == [
         [8, 128, 4, 16], [2, 256, 4, 64], [2, 256, 4, 64],
-        [2, 256, 20, 128], [2, 256, 32, 64]]  # the last two: the cells' heads
+        [2, 256, 20, 128], [2, 256, 32, 64],  # the cells' heads
+        [2, 256, 64, 128]]  # latent attention's split entry against the plain call
+    assert flash["checked"][-1]["rope"] == 64 and flash["checked"][-1]["max_err"] < 1.2e-2
 
 
 def test_result_line_has_exactly_the_contract_keys():

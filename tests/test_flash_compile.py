@@ -28,13 +28,18 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-#: (q shape [B, T, H, D], key positions, key/value heads, dtype, causal, return_lse[, the values' head size])
+#: (q shape [B, T, H, D], key positions, key/value heads, dtype, causal, return_lse[, the values' head size
+#: [, the rotary part handed over beside q and k, whether the kernel turns q's]])
 _SHAPES = {
     # the three language-model cells
     "falcon_h1-20on4-head128": ((2, 4096, 20, 128), 4096, 4, "bfloat16", True, False),
     "lfm2-32on8-head64": ((2, 4096, 32, 64), 4096, 8, "bfloat16", True, False),
     "kimi_k2-64on64-head192-values128": ((2, 4096, 64, 192), 4096, 64, "bfloat16", True, False, 128),
     "values-narrower-float32": ((1, 1024, 2, 192), 1024, 2, "float32", True, True, 128),
+    # latent attention's own call: q_nope, k_nope, v out of [B, T, H x 128], q_pe as its product wrote it
+    # (float32, turned in the kernel), one rotary key head for all 64
+    "kimi_k2-split-64x128-rope64-turned-in-kernel": ((2, 4096, 64, 128), 4096, 64, "bfloat16", True, False, 128, 64, True),
+    "split-rope-turned-before-float32-lse": ((1, 1024, 4, 128), 1024, 2, "float32", True, True, 128, 64, False),
     # chartransformer's prefill and chip_smoke's long-sequence shape
     "prefill-128": ((8, 128, 4, 16), 128, 4, "float32", True, True),
     "bf16-256-full": ((2, 256, 4, 64), 256, 4, "bfloat16", False, True),
@@ -55,21 +60,25 @@ _SHAPES = {
 
 @pytest.mark.parametrize("case", list(_SHAPES), ids=list(_SHAPES))
 def test_chosen_tile_compiles_for_v5e(one_chip, case):
-    shape, tk, kv_heads, dtype, causal, return_lse, *dv = _SHAPES[case]
-    b, t, _, d = shape
-    dv = dv[0] if dv else d
-    q = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
-    k = jax.ShapeDtypeStruct((b, tk, kv_heads, d), jnp.dtype(dtype), sharding=one_chip)
-    v = jax.ShapeDtypeStruct((b, tk, kv_heads, dv), jnp.dtype(dtype), sharding=one_chip)
+    case = _SHAPES[case]  # the last three are optional: as q's, none, not turned
+    shape, tk, kv_heads, dtype, causal, return_lse, dv, rope, turn = case + (None, 0, False)[len(case) - 6:]
+    b, t, h, d = shape
+    dv = dv or d
+    described = lambda shape, dtype=dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)  # noqa: E731
+    operands = {"q": described(shape), "k": described((b, tk, kv_heads, d)), "v": described((b, tk, kv_heads, dv))}
+    if rope:
+        operands.update(q_rope=described((b, t, h, rope), "float32" if turn else dtype), k_rope=described((b, tk, rope)))
+    if turn:
+        operands["rotate"] = (described((t, rope // 2), "float32"),) * 2
 
-    def call(q, k, v):
+    def call(operands):
         # A scale is given where the head sizes differ, as latent attention gives one.
-        return flash_attention(q, k, v, causal=causal, interpret=False, return_lse=return_lse,
-                               scale=0.1447 if dv != d else None)
+        return flash_attention(**operands, causal=causal, interpret=False, return_lse=return_lse,
+                               scale=0.1447 if rope or dv != d else None)
 
-    compiled = jax.jit(call).lower(q, k, v).compile()
+    compiled = jax.jit(call).lower(operands).compile()
     # one kernel a call, under the name the benchmark's roofline share reads
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
     assert "flash_attention" in compiled.as_text()
-    plan = tile_plan(t, tk, d, jnp.dtype(dtype), causal, dv=dv)
+    plan = tile_plan(t, tk, d + rope, jnp.dtype(dtype), causal, dv=dv)
     assert t % plan.block_q == 0 and tk % plan.block_k == 0 and plan.block_k % plan.chunk == 0
