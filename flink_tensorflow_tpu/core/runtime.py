@@ -31,6 +31,7 @@ global mesh (see flink_tensorflow_tpu.parallel.multihost).
 from __future__ import annotations
 
 import logging
+import os
 import threading
 import time
 import typing
@@ -226,6 +227,12 @@ class _Subtask:
         #: chain's operators; the two event loops report their parks to
         #: it.  None when the flight ring and the tracer are both off.
         self.spans = None
+        #: The subtask thread's account (tracing.flight.ThreadAccount),
+        #: made on that thread where there is a hook, and its reading
+        #: once the chain was open: what the chain head's gauges
+        #: ``cpu_s`` / ``runq_s`` read.
+        self.account = None
+        self._charge_at_open = None
         self.records_in = None      # Meter (workers only; head operator)
         self.latency = None         # Timer: per-record processing/emit time
         self.alignment = None       # Timer: barrier-alignment spans
@@ -233,6 +240,14 @@ class _Subtask:
     @property
     def scope(self) -> str:
         return f"{self.t.name}.{self.index}"
+
+    def charged(self, which: int) -> typing.Optional[float]:
+        """Seconds the subtask thread has been on a core (0) or runnable
+        and not run (1) since its chain was open, which is where
+        ``busy_s`` and ``idle_s`` start too; None before that."""
+        if self.account is None:
+            return None
+        return self.account.read()[which] - self._charge_at_open[which]
 
     @property
     def output(self):
@@ -289,6 +304,10 @@ class _Subtask:
         before its first record (Flink's chain open order)."""
         for unit in reversed(self.units):
             unit.operator.open()
+        if self.spans is not None:
+            account = self.spans.account()
+            self._charge_at_open = account.read()
+            self.account = account  # last: a report may be taken any time
 
     def _close_chain(self) -> None:
         for unit in self.units:
@@ -826,6 +845,14 @@ class LocalExecutor:
         flight_on = flight_recorder if env_flight is None else env_flight
         self.flight = flight_mod.FlightRecorder() if flight_on else None
         self.flight_path = flight_path or flight_mod.env_flight_path()
+        #: The process's heartbeat in the ring (flight.Pulse): started
+        #: with the subtask threads, stopped where they are joined.
+        self.pulse = None
+        if self.flight is not None:
+            self.pulse = flight_mod.Pulse(
+                self.flight,
+                timer=self.metrics.group("process").timer("pulse_late_s"),
+                on_stall=lambda: self.flight_dump("stall"))
         if self.sanitizer is not None and self.tracer is not None:
             # Satellite wiring: sanitizer findings (stall dumps with
             # thread stacks + lock ownership, protocol violations) land
@@ -1111,6 +1138,14 @@ class LocalExecutor:
                 grp.gauge("idle_s", lambda s=stats: s.idle_s)
                 grp.gauge("busy_s", lambda tm=latency: tm.total_s)
                 grp.gauge("backpressure_s", lambda s=stats: s.blocked_s)
+                if st.spans is not None:
+                    # What the OS charged the subtask thread since it
+                    # started; no run-queue gauge without the figure.
+                    from flink_tensorflow_tpu.tracing.flight import SCHEDSTAT
+
+                    grp.gauge("cpu_s", lambda st=st: st.charged(0))
+                    if os.access(SCHEDSTAT, os.R_OK):
+                        grp.gauge("runq_s", lambda st=st: st.charged(1))
                 if head_gate is not None:
                     grp.gauge("queue_depth",
                               lambda g=head_gate: g.depth)
@@ -1352,6 +1387,8 @@ class LocalExecutor:
             st.thread = threading.Thread(target=body, name=st.scope, daemon=True)
         for st in self.subtasks:
             st.thread.start()
+        if self.pulse is not None:
+            self.pulse.start()
         if self.checkpoint_interval_s is not None:
             self._periodic_thread = threading.Thread(
                 target=self._periodic_checkpoints, name="checkpoint-timer", daemon=True
@@ -1377,6 +1414,13 @@ class LocalExecutor:
                 logger.warning("periodic checkpoint failed", exc_info=True)
 
     def join(self, timeout: typing.Optional[float] = None) -> None:
+        try:
+            self._join(timeout)
+        finally:
+            if self.pulse is not None:
+                self.pulse.stop()
+
+    def _join(self, timeout: typing.Optional[float]) -> None:
         deadline = None if timeout is None else time.monotonic() + timeout
         for st in self.subtasks:
             remaining = None if deadline is None else max(0.0, deadline - time.monotonic())
@@ -1531,11 +1575,16 @@ class LocalExecutor:
     def subtask_finished(self, subtask: _Subtask) -> None:
         if self.flight is not None:
             self.flight.record(subtask.scope, "subtask.finished")
+        if subtask.account is not None:
+            subtask.account.read()  # the thread's last lines: its sums in all
         self.coordinator.subtask_finished(subtask)
         with self._error_lock:
             self._finished_count += 1
             if self._finished_count >= len(self.subtasks):
                 self._all_done.set()
+                if self.pulse is not None:
+                    # A job nobody joins must not leave its pulse behind.
+                    self.pulse.stop(join=False)
 
     @property
     def total_subtasks(self) -> int:

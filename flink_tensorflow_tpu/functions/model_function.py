@@ -37,6 +37,7 @@ from flink_tensorflow_tpu.models.loaders import GraphLoader, SavedModelLoader
 from flink_tensorflow_tpu.tensors.batching import BucketLadder, BucketPolicy
 from flink_tensorflow_tpu.tensors.coercion import coerce
 from flink_tensorflow_tpu.tensors.value import TensorValue
+from flink_tensorflow_tpu.tracing.flight import charged
 
 ModelSource = typing.Union[Model, str, SavedModelLoader, typing.Callable[[], Model]]
 
@@ -235,7 +236,10 @@ class _ModelFunctionBase(fn.RichFunction):
         """Hand collected batches (``runner.collect_batches``) downstream:
         one ``emit`` span and one ``emit_s`` update a fetched batch (the
         chained consumers' own work included, as the chain runs it)."""
+        account = self._spans.account() if self._spans is not None else None
         for seq, records in batches:
+            if account is not None:
+                charge = account.read()
             t0 = time.monotonic()
             for record in records:
                 out.collect(record)
@@ -243,9 +247,9 @@ class _ModelFunctionBase(fn.RichFunction):
             self._emit_total_s += t1 - t0
             if self._metrics is not None:
                 self._metrics.timer("emit_s").update(t1 - t0)
-            if self._spans is not None:
-                self._spans.span(self._track, "emit", t0, t1,
-                                 {"seq": seq, "records": len(records)})
+            if account is not None:
+                self._spans.span(self._track, "emit", t0, t1, charged(
+                    {"seq": seq, "records": len(records)}, charge, account.read()))
 
     def clone(self) -> "fn.Function":
         # Subtasks share the host-side source (read-only); each builds its
@@ -264,6 +268,8 @@ class _ModelFunctionBase(fn.RichFunction):
         self._spans = getattr(ctx, "spans", None)
         if self._spans is not None:
             self._track = f"{ctx.task_name}.{ctx.subtask_index}"
+            account = self._spans.account()
+            charge = account.read()
         model = _resolve(self._source)
         wire = (self._wire_dtype if self._wire_dtype is not None
                 else getattr(ctx, "wire_dtype", None))
@@ -305,7 +311,8 @@ class _ModelFunctionBase(fn.RichFunction):
         if self._metrics is not None:
             self._metrics.timer("open_s").update(now - t_open)
         if self._spans is not None:
-            self._spans.span(self._track, "open", t_open, now)
+            self._spans.span(self._track, "open", t_open, now,
+                             charged({}, charge, account.read()))
 
     def _open_buffers(self) -> None:
         """Subclass hook: the rest of ``open()``, inside its span."""
@@ -588,6 +595,8 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         #: before).
         self._fill_t0: typing.Optional[float] = None
         self._fill_marks = (0.0, 0.0, 0.0)
+        #: The subtask thread's account as read where the fill began.
+        self._fill_charge = None
         #: Seconds in the ring-full drain loop so far (emissions and
         #: blocked collections: ``emit`` and ``collect_wait`` count them).
         self._ring_wait_total_s = 0.0
@@ -751,10 +760,13 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         self._fill_marks = (self._emit_total_s + self.runner.collect_wait_total_s,
                             self._ring_wait_total_s, parked)
         self._early = _EarlyWindow() if self.runner.chunk_rows is not None else None
+        if self._spans is not None:
+            self._fill_charge = self._spans.account().read()
         self._fill_t0 = time.monotonic()
 
-    def _close_fill(self, now: float, records: int) -> None:
-        """``process_window`` is entered: close the ``fill`` span.  Its
+    def _close_fill(self, now: float, records: int, charge=None) -> None:
+        """``process_window`` is entered: close the ``fill`` span (``charge``:
+        the thread's account as read at ``now``).  Its
         self time — the fill less the emissions, blocked collections and
         parks inside it — is the ingest of the window's records (source poll, chain, ring write), got with no
         clock read per record."""
@@ -773,12 +785,12 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         if self._metrics is not None:
             self._metrics.timer("ingest_s").update(self_s)
         if spans is not None:
-            spans.span(self._track, "fill", t0, now, {
+            spans.span(self._track, "fill", t0, now, charged({
                 "seq": self.runner._batch_seq + 1, "records": records,
                 "self_s": self_s, "park_s": park_s,
                 "ring_wait_s": self._ring_wait_total_s - ring0,
                 "park_n": park_n, "park_over_max_s": park_over,
-                "park_before_s": park_before})
+                "park_before_s": park_before}, self._fill_charge, charge))
 
     def materialize_tokens(self, elements):
         """Replace ring tokens with concrete TensorValues (copy-out) —
@@ -827,9 +839,11 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
 
     # -- firing ------------------------------------------------------------
     def process_window(self, key, window, elements, out: fn.Collector):
+        account = self._spans.account() if self._spans is not None else None
+        charge = account.read() if account is not None else None
         t_fire = time.monotonic()
         elements = list(elements)
-        self._close_fill(t_fire, len(elements))
+        self._close_fill(t_fire, len(elements), charge)
         self._out = out
         self._fire_in_flight = 0
         blocked0 = self.runner.collect_wait_total_s
@@ -852,12 +866,12 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             policy = self.runner.policy
             cap = policy.fixed_batch or policy.batch.sizes[-1]
             tail = len(elements) % cap
-            self._spans.span(self._track, "fire", t_fire, now, {
+            self._spans.span(self._track, "fire", t_fire, now, charged({
                 "seq": self.runner._batch_seq, "records": len(elements),
                 "padded": policy.batch_bucket(tail) - tail if tail else 0,
                 "in_flight": self._fire_in_flight,
                 "blocked_s": self.runner.collect_wait_total_s - blocked0,
-                "early_chunks": early_chunks})
+                "early_chunks": early_chunks}, charge, account.read()))
 
     def _hold_depth(self, out: fn.Collector) -> None:
         """A batch has just been dispatched: note how many are in flight,

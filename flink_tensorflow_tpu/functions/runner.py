@@ -73,6 +73,7 @@ from flink_tensorflow_tpu.tensors.batching import Batch, BucketPolicy, assemble
 from flink_tensorflow_tpu.tensors.coercion import coerce
 from flink_tensorflow_tpu.tensors.transfer import DeviceTransfer
 from flink_tensorflow_tpu.tensors.value import TensorValue
+from flink_tensorflow_tpu.tracing.flight import charged
 from flink_tensorflow_tpu.utils.profiling import annotate_batch
 
 if typing.TYPE_CHECKING:
@@ -1492,7 +1493,11 @@ class CompiledMethodRunner:
             # puts and the same executable, none of them early.
             chunks = [{n: a[i:i + rows] for n, a in batch.arrays.items()}
                       for i in range(0, batch.padded_size, rows)]
+        spans = self._spans
+        account = spans.account() if spans is not None else None
         with annotate_batch(f"{self.model.name}.{self.method.name}", seq):
+            if account is not None:
+                charge = account.read()
             t_b = time.monotonic()
             if chunks is not None:
                 inputs, h2d_bytes, early_bytes = self._transfer.ship_chunks(shipped, chunks)
@@ -1537,6 +1542,10 @@ class CompiledMethodRunner:
             "t_lane_start": t_b,
             "t_dispatched": t_c,
         }
+        if account is not None:
+            # What this thread (a lane's, or the subtask's own) was charged
+            # between the two stamps: the ``enqueue`` span's cpu_s / runq_s.
+            timings["enqueue_charge"] = charged({}, charge, account.read())
         return batch, outputs, timings, on_done
 
     # -- device-resident input (HBM-resident chained handoff) -------------
@@ -1664,13 +1673,17 @@ class CompiledMethodRunner:
         # Stamped AFTER the lane future resolves: the fetch thread can
         # reach this batch while its lane is still enqueueing, so the
         # stamp never precedes t_dispatched.
+        spans = self._spans
+        account = spans.account() if spans is not None else None
+        reached = account.read() if account is not None else None
         t_fetch_start = time.monotonic()
         batch, outputs, timings, on_done = item
         if self.emit_device_batches:
             return self._complete_device(
-                batch, outputs, timings, on_done, t_fetch_start)
+                batch, outputs, timings, on_done, t_fetch_start, reached)
         host = DeviceTransfer.fetch(outputs)  # blocks on this batch only
         t_done = time.monotonic()
+        fetched = account.read() if account is not None else None
         timings["counts"] = self._take_counts(host, batch.valid)
         results = batch.unbatch(host)
         t_unbatched = time.monotonic()
@@ -1681,10 +1694,12 @@ class CompiledMethodRunner:
             dt if self.service_ewma_s is None
             else 0.75 * self.service_ewma_s + 0.25 * dt
         )
-        if self._spans is not None:
-            self._batch_spans(timings, t_fetch_start, t_done, len(results))
-            self._spans.span(self._trace_track, "unbatch", t_done, t_unbatched,
-                             {"seq": timings["seq"], "records": len(results)})
+        if spans is not None:
+            self._batch_spans(timings, t_fetch_start, t_done, len(results),
+                              charged({}, reached, fetched, "fetch_"))
+            spans.span(self._trace_track, "unbatch", t_done, t_unbatched, charged(
+                {"seq": timings["seq"], "records": len(results)},
+                fetched, account.read()))
         if self._metrics is not None:
             self._metrics.meter("records").mark(len(results))
             self._metrics.histogram("batch_latency_s").record(dt)
@@ -1716,14 +1731,17 @@ class CompiledMethodRunner:
         return results, on_done, timings["seq"], time.monotonic()
 
     def _batch_spans(self, timings, t_fetch_start: float, t_done: float,
-                     n: int) -> None:
+                     n: int, fetch_charge: dict) -> None:
         """One batch's spans off the subtask thread, written by the fetch
         thread from the stamps the lane left: the boundaries t0 ->
         t_lane_start -> t_dispatched -> t_done tile the batch's service
         time.  A batch fed by an upstream DeviceBatch records NO enqueue
         span — the elision shows as an ``h2d.elided`` instant (the CI
         guard greps for exactly this shape: zero transfers between fused
-        model ops)."""
+        model ops).  ``fetch_charge``: what the fetch thread was charged
+        from ``t_fetch_start`` to ``t_done`` (``fetch_cpu_s`` /
+        ``fetch_runq_s``: ``in_flight`` starts on another thread, so they
+        are not the whole span's)."""
         spans, track, seq = self._spans, self._trace_track, timings["seq"]
         # ``tokens``: the batch's real positions, where the method takes tokens.
         tokens = {} if timings.get("tokens") is None else {"tokens": timings["tokens"]}
@@ -1741,14 +1759,15 @@ class CompiledMethodRunner:
             spans.span(track, "enqueue", timings["t_lane_start"],
                        timings["t_dispatched"],
                        {"seq": seq, "bytes": timings["h2d_bytes"],
-                        "early_bytes": timings["h2d_early_bytes"], "batch": n, **tokens})
+                        "early_bytes": timings["h2d_early_bytes"], "batch": n, **tokens,
+                        **timings.get("enqueue_charge", {})})
         # Launched .. results on the host.  Where the fetch thread stood
         # when it reached the batch is an accident of its schedule, so it
         # is a number here and no longer a cut between two spans.
         spans.span(track, "in_flight", timings["t_dispatched"], t_done,
                    {"seq": seq, "batch": n, "fetch_reached_s":
                     round(t_fetch_start - timings["t_dispatched"], 6), **tokens,
-                    **timings["counts"]})
+                    **timings["counts"], **fetch_charge})
 
     def _take_counts(self, outputs: dict, valid) -> typing.Dict[str, int]:
         """Takes the method's count outputs out of ``outputs`` (which then
@@ -1770,7 +1789,7 @@ class CompiledMethodRunner:
             self._metrics.counter(name).inc(value)
 
     def _complete_device(self, batch, outputs, timings, on_done,
-                         t_fetch_start: float):
+                         t_fetch_start: float, reached=None):
         """Device-resident completion: wait for COMPUTE (not transfer) —
         ``block_until_ready`` is the pipeline-depth barrier the fetch
         used to provide — then hand out one HBM-resident DeviceBatch.
@@ -1782,6 +1801,8 @@ class CompiledMethodRunner:
 
         jax.block_until_ready(outputs)
         t_done = time.monotonic()
+        spans = self._spans
+        fetched = spans.account().read() if spans is not None else None
         outputs = dict(outputs)
         timings["counts"] = self._take_counts(outputs, batch.valid)
         n = batch.num_records
@@ -1790,12 +1811,12 @@ class CompiledMethodRunner:
             dt if self.service_ewma_s is None
             else 0.75 * self.service_ewma_s + 0.25 * dt
         )
-        spans = self._spans
         if spans is not None:
             # In flight to t_done (block_until_ready IS the barrier); the
             # d2h.elided instant is what the attribution table and the
             # CI guard read as "no fetch happened here".
-            self._batch_spans(timings, t_fetch_start, t_done, n)
+            self._batch_spans(timings, t_fetch_start, t_done, n,
+                              charged({}, reached, fetched, "fetch_"))
             spans.instant(self._trace_track, "d2h.elided", t_done,
                           {"seq": timings["seq"], "batch": n})
         if self._metrics is not None:
@@ -1858,6 +1879,8 @@ class CompiledMethodRunner:
         max_in_flight = max(0, max_in_flight)
         out: typing.List[tuple] = []
         t_blocked = None  # start of the blocking stretch under way
+        account = self._spans.account() if self._spans is not None else None
+        charge = None  # the thread's account as read at t_blocked
         while True:
             entries: typing.List[typing.Any] = []
             with self._lock:
@@ -1866,6 +1889,8 @@ class CompiledMethodRunner:
                 done = len(self._pending) <= max_in_flight
                 if not entries and not done:
                     if t_blocked is None:
+                        if account is not None:
+                            charge = account.read()
                         t_blocked = time.monotonic()
                     self._done_cv.wait(timeout=0.2)
                     if (self._fetcher is None or not self._fetcher.is_alive()) \
@@ -1875,23 +1900,26 @@ class CompiledMethodRunner:
                     continue
                 in_flight = len(self._pending)
             now = time.monotonic() if t_blocked is not None else 0.0
+            spent = charged({}, charge, account.read()) if charge is not None else {}
             out.extend(self._consume(e) for e in entries)
             if t_blocked is not None:
-                self._note_collect_wait(t_blocked, now, in_flight)
-                t_blocked = None
+                self._note_collect_wait(t_blocked, now, in_flight, spent)
+                t_blocked = charge = None
             if done:
                 return out
 
     def _note_collect_wait(self, t_blocked: float, now: float,
-                           in_flight: int) -> None:
+                           in_flight: int, spent: dict) -> None:
         """One blocking stretch of :meth:`collect_ready` has ended with
-        the results of batch ``collected_seq``."""
+        the results of batch ``collected_seq``; ``spent`` is what the
+        thread was charged in it."""
         self.collect_wait_total_s += now - t_blocked
         if self._metrics is not None:
             self._metrics.timer("collect_wait_s").update(now - t_blocked)
         if self._spans is not None:
             self._spans.span(self._trace_track, "collect_wait", t_blocked, now,
-                             {"seq": self.collected_seq, "in_flight": in_flight})
+                             {"seq": self.collected_seq, "in_flight": in_flight,
+                              **spent})
 
     def collect_available(self) -> typing.List[TensorValue]:
         """Drain every batch the fetch thread has already completed —

@@ -34,6 +34,7 @@ from flink_tensorflow_tpu.tensors.batching import BucketPolicy, assemble
 from flink_tensorflow_tpu.tensors.coercion import coerce
 from flink_tensorflow_tpu.tensors.schema import RecordSchema, check_compatible
 from flink_tensorflow_tpu.tensors.value import TensorValue
+from flink_tensorflow_tpu.tracing.flight import charged
 
 
 def _to_host(pytree):
@@ -471,6 +472,8 @@ class DPTrainWindowFunction(fn.WindowFunction):
         self._spans = getattr(ctx, "spans", None)
         if self._spans is not None:
             self._track = f"{ctx.task_name}.{ctx.subtask_index}"
+            account = self._spans.account()
+            charge = account.read()
         if ctx.mesh is None:
             raise RuntimeError(
                 "DPTrainWindowFunction needs env.set_mesh(...) — the gang owns the mesh"
@@ -528,7 +531,8 @@ class DPTrainWindowFunction(fn.WindowFunction):
             spans, track = self._spans, self._track
             spans.span(track, "init_state", t_init, t_replicate)
             spans.span(track, "replicate", t_replicate, now)
-            spans.span(track, "open", t_open, now)
+            spans.span(track, "open", t_open, now,
+                       charged({}, charge, account.read()))
 
     def process_window(self, key, window, elements, out: fn.Collector) -> None:
         import collections
@@ -536,25 +540,28 @@ class DPTrainWindowFunction(fn.WindowFunction):
         from flink_tensorflow_tpu.parallel.mesh import shard_batch
 
         self._out = out
-        t0 = time.monotonic()
+        # The thread's account (tracing/flight.py), read at every stamp
+        # where there is a hook.
+        read = self._spans.account().read if self._spans is not None else lambda: None
+        c0, t0 = read(), time.monotonic()
         _, arrays = _train_batch_arrays(list(elements), self.train_schema, self._policy)
-        t1 = time.monotonic()
+        c1, t1 = read(), time.monotonic()
         batch = shard_batch(self.mesh, arrays)
-        t2 = time.monotonic()
+        c2, t2 = read(), time.monotonic()
         # Dispatch-and-go: the state chains asynchronously; metrics fetch
         # lags by pipeline_depth so the NEXT window's h2d transfer
         # overlaps this step's device compute.
         self._state, metrics = self._step_fn(self._state, batch)
-        t3 = time.monotonic()
+        c3, t3 = read(), time.monotonic()
         self._step_no += 1
         # The host's work a step, against the step's device time.
         self.ctx.metrics.timer("feed_s").update(t3 - t0)
         if self._spans is not None:
             spans, track = self._spans, self._track
             args = {"step": self._step_no, "examples": len(elements)}
-            spans.span(track, "assemble", t0, t1, args)
-            spans.span(track, "h2d_enqueue", t1, t2, args)
-            spans.span(track, "dispatch", t2, t3, args)
+            spans.span(track, "assemble", t0, t1, charged(dict(args), c0, c1))
+            spans.span(track, "h2d_enqueue", t1, t2, charged(dict(args), c1, c2))
+            spans.span(track, "dispatch", t2, t3, charged(dict(args), c2, c3))
         if self._pending is None:
             self._pending = collections.deque()
         self._pending.append((metrics, self._step_no, len(elements)))
@@ -565,13 +572,16 @@ class DPTrainWindowFunction(fn.WindowFunction):
 
         while self._pending and len(self._pending) > keep:
             metrics, step_no, n = self._pending.popleft()
+            account = self._spans.account() if self._spans is not None else None
+            if account is not None:
+                charge = account.read()
             t0 = time.monotonic()
             host = {k: np.asarray(v) for k, v in metrics.items()}
             t1 = time.monotonic()
             self.ctx.metrics.timer("drain_wait_s").update(t1 - t0)
-            if self._spans is not None:
-                self._spans.span(self._track, "drain_wait", t0, t1,
-                                 {"step": step_no, "examples": n})
+            if account is not None:
+                self._spans.span(self._track, "drain_wait", t0, t1, charged(
+                    {"step": step_no, "examples": n}, charge, account.read()))
             host["step"] = np.asarray(step_no, np.int64)
             out.collect(TensorValue(host))
             self.ctx.metrics.meter("train_records").mark(n)

@@ -15,9 +15,19 @@ WINDOW-LEVEL spans of the model and train operators' hot paths — ``fill``,
 ``assemble``, ``h2d_enqueue``, ``dispatch``, ``drain_wait`` — are always
 on: one :class:`SpanHook` per subtask (``ctx.spans``) writes them, once
 a window, into the flight ring and, when tracing is on, into the tracer
-(``flight.py`` lists them with their cuts).  :func:`recorder_of` hands
-back the ring of the most recent job of a given name after the job has
-been released: ``recorder_of("job").events()``.
+(``flight.py`` lists them with their cuts).  A span whose two stamps are
+read on one thread carries what the OS charged that thread between them:
+``cpu_s`` (seconds on a core) and, where the machine has
+``/proc/thread-self/schedstat``, ``runq_s`` (seconds runnable and not
+run).  Beside the operators' tracks the ring holds the track ``process``,
+written by one pulse thread an executor (:class:`~flink_tensorflow_tpu.
+tracing.flight.Pulse`, none when the ring is off): the instant
+``pulse.late`` for a wake that came more than 50 ms late, with what the
+gap was charged and the ``cause`` it is booked to (``gc``, ``off_core``,
+``lock_held``, ``nothing_ran``), and the span ``gc`` for a full
+collection.  :func:`recorder_of` hands back the ring of the most recent
+job of a given name after the job has been released:
+``recorder_of("job").events()``.
 """
 
 from flink_tensorflow_tpu.tracing.attribution import (

@@ -78,6 +78,47 @@ The gang train operator records ``assemble``, ``h2d_enqueue``,
 ``dispatch`` and ``drain_wait`` a step (``args["step"]``), and ``open``
 with children ``init_state`` and ``replicate``.
 
+**What the OS charged the thread.**  A span whose two stamps are read on
+one thread carries two more args, the deltas of that thread's
+:class:`ThreadAccount` between them: ``cpu_s`` (seconds on a core) and
+``runq_s`` (seconds runnable and not run; left out where
+``/proc/thread-self/schedstat`` cannot be read, never 0 in its place).
+A wall-clock span of 2.3 s then says whether its thread ran, stood in a
+run queue, or waited.  On the subtask thread ``fill``, ``fire``,
+``collect_wait``, ``emit`` and ``open``; on the lane thread ``enqueue``;
+on the fetch thread ``unbatch``, and on ``in_flight`` (which starts on
+the lane thread) ``fetch_cpu_s`` / ``fetch_runq_s``: the stretch the
+fetch thread itself spent getting the results, from ``fetch_reached_s``
+in to the span's end; in the gang train operator ``assemble``,
+``h2d_enqueue``, ``dispatch``, ``drain_wait`` and ``open``.  The kernel
+books a running thread's seconds at its scheduler's tick, so a span's
+``cpu_s`` may run a few ms past what it ran.  The chain head's metric
+group reports the subtask thread's two sums since it started as the
+gauges ``cpu_s`` and ``runq_s``, beside ``busy_s`` / ``idle_s`` /
+``backpressure_s``, read when a report is taken.
+
+**Track ``process``: the pulse.**  One daemon thread an executor
+(:class:`Pulse`, ``flight-pulse``; none when the ring is off) sleeps
+``PULSE_S`` and writes nothing while it wakes on time.  A wake more than
+``OVERSLEPT_S`` late writes the instant ``pulse.late`` at the time it
+woke, with ``late_s`` and what the gap was charged: ``cpu_s`` (the
+process, since the wake before), ``runq_s`` (the pulse thread's own:
+near ``late_s`` when it stood in a run queue, near 0 when it waited for
+the interpreter's lock or the process was frozen), ``gc_s``, and the
+deltas of the OS's slower accounts since their last sample, at most a
+second back, each only where its source can be read: ``majflt``,
+``steal_s``, ``throttled_s``, ``psi_cpu_s``, ``psi_mem_s``,
+``psi_io_s``; and ``cause`` (:func:`book_stall`): ``gc``, ``off_core``,
+``lock_held`` or ``nothing_ran``.  A generation-2 collection writes the
+span ``gc`` (``args``: ``collected``).  The lateness feeds the timer
+``process.pulse_late_s``; the first ``pulse.late`` of a second or more
+dumps the ring with reason ``stall`` where a dump path is configured,
+two seconds later (or when the job's threads are joined), so that the
+spans that cover the gap, which are written when they end, are in it.
+A stall of the device side is no ``pulse.late``: it is an ``in_flight``
+far over the run's median whose ``fetch_cpu_s`` and ``fetch_runq_s``
+read near 0.
+
 **Post-mortem accessor.**  :func:`recorder_of` returns the ring of the
 most recent job of a given name in this process, after the job has been
 released — for a notebook, a test, a crash handler, or a benchmark
@@ -87,8 +128,10 @@ reader that only ever gets ``job.metrics``.
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import os
+import resource
 import signal
 import threading
 import time
@@ -217,6 +260,62 @@ class FlightRecorder:
 OVERSLEPT_S = 0.05
 
 
+#: A thread's seconds on a core and seconds runnable and not run, in ns.
+SCHEDSTAT = "/proc/thread-self/schedstat"
+
+
+class ThreadAccount:
+    """What the OS has charged one hot-path thread: seconds on a core and
+    seconds runnable and not run.  Made ON its thread (it opens that
+    thread's ``schedstat`` once); :meth:`read` is one ``pread`` and may be
+    called from any thread, which is how a gauge reads the subtask
+    thread's.  Where the file cannot be read the seconds come from the
+    thread's CPU clock and there is no run-queue figure: None, never 0."""
+
+    __slots__ = ("tid", "_fd", "_clock", "_last")
+
+    def __init__(self):
+        #: Native id: the same file is ``/proc/self/task/<tid>/schedstat``.
+        self.tid = threading.get_native_id()
+        self._clock = time.pthread_getcpuclockid(threading.get_ident())
+        try:
+            self._fd: typing.Optional[int] = os.open(SCHEDSTAT, os.O_RDONLY)
+        except OSError:
+            self._fd = None
+        self._last: typing.Tuple[float, typing.Optional[float]] = (0.0, None)
+        self.read()
+
+    def read(self) -> typing.Tuple[float, typing.Optional[float]]:
+        """``(cpu_s, runq_s)`` since the thread started; once it has
+        ended, what it was charged in all."""
+        try:
+            if self._fd is not None:
+                cpu, runq = os.pread(self._fd, 64, 0).split()[:2]
+                self._last = (int(cpu) * 1e-9, int(runq) * 1e-9)
+            elif self._clock is not None:
+                self._last = (time.clock_gettime_ns(self._clock) * 1e-9, None)
+        except (OSError, ValueError):
+            pass  # the thread has ended
+        return self._last
+
+    def close(self) -> None:
+        fd, self._fd, self._clock = self._fd, None, None
+        if fd is not None:
+            os.close(fd)
+
+    __del__ = close
+
+
+def charged(args: dict, then, now, prefix: str = "") -> dict:
+    """``args`` with what a thread was charged between two readings of
+    its account: ``cpu_s`` and, where there is a run-queue figure,
+    ``runq_s``."""
+    args[prefix + "cpu_s"] = now[0] - then[0]
+    if now[1] is not None and then[1] is not None:
+        args[prefix + "runq_s"] = now[1] - then[1]
+    return args
+
+
 class SpanHook:
     """The one hook the hot path's window-level spans go through: one
     per subtask, handed to every chained operator as ``ctx.spans``
@@ -228,12 +327,14 @@ class SpanHook:
     park of the subtask thread through :meth:`park`; the sums wait here
     for the ``fill`` span that closes next (:meth:`take_parks`)."""
 
-    __slots__ = ("_flight", "_tracer", "park_s", "park_n", "park_over_max_s")
+    __slots__ = ("_flight", "_tracer", "_threads", "park_s", "park_n",
+                 "park_over_max_s")
 
     def __init__(self, flight: typing.Optional[FlightRecorder],
                  tracer: typing.Optional[typing.Any] = None):
         self._flight = flight._ring if flight is not None else None
         self._tracer = tracer
+        self._threads = threading.local()
         self.park_s = 0.0
         self.park_n = 0
         self.park_over_max_s = 0.0
@@ -251,6 +352,16 @@ class SpanHook:
     def instant(self, track: str, name: str, ts: float,
                 args: typing.Optional[dict] = None) -> None:
         self._write((track, name, "i", ts, 0.0, args))
+
+    def account(self) -> ThreadAccount:
+        """The calling thread's account, made at its first span and closed
+        when the thread ends: a span site reads it at its two stamps and
+        hands the readings to :func:`charged`."""
+        try:
+            return self._threads.account
+        except AttributeError:
+            account = self._threads.account = ThreadAccount()
+            return account
 
     def park(self, track: str, asked: typing.Optional[float], slept: float,
              woken: bool, now: float) -> None:
@@ -271,6 +382,248 @@ class SpanHook:
         out = (self.park_s, self.park_n, self.park_over_max_s)
         self.park_s, self.park_n, self.park_over_max_s = 0.0, 0, 0.0
         return out
+
+
+#: Seconds the pulse sleeps between two wakes.
+PULSE_S = 0.1
+
+
+def book_stall(late_s: float, *, gc_s: float = 0.0, cpu_s: float = 0.0,
+               runq_s: typing.Optional[float] = None,
+               **slow: float) -> str:
+    """What a late wake of the pulse is booked to, from what its gap was
+    charged, in this order: ``gc`` where full collections cover over half
+    of it; ``off_core`` where the pulse thread stood that long in a run
+    queue (without a run-queue figure: where the cgroup was throttled,
+    cores were stolen or tasks waited for a core that long, by whichever
+    of ``throttled_s`` / ``steal_s`` / ``psi_cpu_s`` there is);
+    ``lock_held`` where neither does and the process was on its cores for
+    over half of it (a thread ran on with the interpreter's lock: the
+    spans that cover the gap say which, by their own ``cpu_s``); else
+    ``nothing_ran`` (a frozen process, or the lock held by a call that
+    itself blocked)."""
+    half = late_s / 2
+    if gc_s > half:
+        return "gc"
+    if runq_s is None:
+        runq_s = max((slow[k] for k in ("throttled_s", "steal_s", "psi_cpu_s")
+                      if k in slow), default=0.0)
+    if runq_s > half:
+        return "off_core"
+    return "lock_held" if cpu_s > half else "nothing_ran"
+
+
+def _cgroup_cpu_stat() -> typing.Optional[str]:
+    """Path of the ``cpu.stat`` of this process's cgroup (v2, or v1's
+    ``cpu`` controller), or None."""
+    try:
+        with open("/proc/self/cgroup") as f:
+            rows = [line.rstrip("\n").split(":", 2) for line in f]
+    except OSError:
+        return None
+    for _, controllers, path in (r for r in rows if len(r) == 3):
+        if controllers == "" or "cpu" in controllers.split(","):
+            stat = os.path.join("/sys/fs/cgroup", controllers, path.lstrip("/"), "cpu.stat")
+            if os.access(stat, os.R_OK):
+                return stat
+    return None
+
+
+class Pulse:
+    """The process's heartbeat in the flight ring (track ``process``): one
+    daemon thread an executor, none when the ring is off.  It sleeps
+    ``period`` and records nothing while it wakes on time; a wake more
+    than ``OVERSLEPT_S`` late leaves ``pulse.late`` with what the gap was
+    charged and the ``cause`` :func:`book_stall` books it to.  It owns
+    the ``gc.callbacks`` hook that writes a ``gc`` span a full collection.
+
+    ``timer`` (the registry's ``process.pulse_late_s``) takes every
+    lateness; ``on_stall`` is called once, for the first ``pulse.late`` of
+    ``STALL_S`` or more (the executor dumps the ring with reason
+    ``stall``): ``DUMP_AFTER`` wakes later or when the pulse is stopped,
+    whichever comes first, because a span is written when it ends and the
+    spans that cover the gap are still open when the pulse wakes."""
+
+    #: The first lateness of this many seconds dumps the ring.
+    STALL_S = 1.0
+    #: ... this many wakes after it: two seconds, a dozen windows.
+    DUMP_AFTER = 20
+    #: The OS's slower accounts are sampled every this many wakes.
+    SAMPLE_EVERY = 10
+
+    def __init__(self, flight: FlightRecorder, *, timer=None,
+                 on_stall: typing.Optional[typing.Callable[[], typing.Any]] = None,
+                 period: float = PULSE_S):
+        self._ring = flight._ring
+        self._timer = timer
+        self._on_stall = on_stall
+        self.period = period
+        self._stop = threading.Event()
+        self._thread: typing.Optional[threading.Thread] = None
+        #: Wakes left until the stall met is dumped; None while none was.
+        self._dump_in: typing.Optional[int] = None
+        #: (start, end) of the newest full collections, for ``gc_s``.
+        self._collections: typing.Deque[tuple] = collections.deque(maxlen=32)
+        self._collecting_since: typing.Optional[float] = None
+        #: name -> (fd, reads the file's bytes to seconds or a count).
+        self._sources: typing.Dict[str, typing.Tuple[int, typing.Callable]] = {}
+
+    # -- lifecycle -------------------------------------------------------
+    def start(self) -> None:
+        if self._thread is not None:
+            return
+        gc.callbacks.append(self._on_collection)
+        self._thread = threading.Thread(target=self._run, name="flight-pulse",
+                                        daemon=True)
+        self._thread.start()
+
+    def stop(self, join: bool = True) -> None:
+        """Ends the thread and takes the collector's hook away; idempotent.
+        ``join=False`` only signals (from a subtask's own last lines)."""
+        self._stop.set()
+        thread = self._thread
+        if thread is None:
+            return
+        if join and thread is not threading.current_thread():
+            thread.join(timeout=5.0)
+            self._thread = None
+        try:
+            gc.callbacks.remove(self._on_collection)
+        except ValueError:
+            pass
+
+    def _on_collection(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._collecting_since = time.monotonic()
+            return
+        t0, now = self._collecting_since, time.monotonic()
+        if t0 is None:
+            return
+        # Kept first and cleared last: the pulse may take the interpreter's
+        # lock between any two of these lines (it has waited for it), and
+        # then still finds the collection, under way or done.
+        self._collections.append((t0, now))
+        self._collecting_since = None
+        self._ring.append(("process", "gc", "X", t0, now - t0,
+                           {"collected": info["collected"]}))
+
+    # -- the OS's slower accounts ----------------------------------------
+    def _open_sources(self) -> None:
+        tick = 1.0 / os.sysconf("SC_CLK_TCK")
+
+        def steal(text: bytes) -> float:  # first line: cpu user nice ... steal
+            return int(text.split(b"\n", 1)[0].split()[8]) * tick
+
+        def psi(text: bytes) -> float:  # some avg10=.. total=<us>
+            return int(text.split(b"total=", 1)[1].split()[0]) * 1e-6
+
+        def throttled(text: bytes) -> float:
+            fields = dict(line.split()[:2] for line in text.splitlines() if line)
+            if b"throttled_usec" in fields:
+                return int(fields[b"throttled_usec"]) * 1e-6
+            return int(fields[b"throttled_time"]) * 1e-9  # cgroup v1, ns
+
+        wanted = [("steal_s", "/proc/stat", steal),
+                  ("psi_cpu_s", "/proc/pressure/cpu", psi),
+                  ("psi_mem_s", "/proc/pressure/memory", psi),
+                  ("psi_io_s", "/proc/pressure/io", psi),
+                  ("throttled_s", _cgroup_cpu_stat(), throttled)]
+        for name, path, parse in wanted:
+            if path is None:
+                continue
+            try:
+                fd = os.open(path, os.O_RDONLY)
+                parse(os.pread(fd, 4096, 0))
+            except (OSError, ValueError, IndexError, KeyError):
+                continue  # a source that is missing is left out
+            self._sources[name] = (fd, parse)
+
+    def _sample(self) -> typing.Dict[str, float]:
+        sample = {"majflt": resource.getrusage(resource.RUSAGE_SELF).ru_majflt}
+        for name, (fd, parse) in self._sources.items():
+            try:
+                sample[name] = parse(os.pread(fd, 4096, 0))
+            except (OSError, ValueError, IndexError, KeyError):
+                pass
+        return sample
+
+    # -- the thread ------------------------------------------------------
+    def _run(self) -> None:
+        account = ThreadAccount()
+        self._open_sources()
+        try:
+            self._beat(account)
+        finally:
+            account.close()
+            for fd, _ in self._sources.values():
+                os.close(fd)
+            self._sources.clear()
+
+    def _beat(self, account: ThreadAccount) -> None:
+        period, wait = self.period, self._stop.wait
+        slow = self._sample()
+        wakes = 0
+        cpu, charge = time.process_time(), account.read()
+        due = time.monotonic() + period
+        while not wait(max(due - time.monotonic(), 0.0)):
+            now = time.monotonic()
+            wakes += 1
+            if now - due > OVERSLEPT_S:
+                slow = self._late(due, now, cpu, charge, account, slow)
+            elif wakes % self.SAMPLE_EVERY == 0:
+                slow = self._sample()
+            if self._dump_in is not None:
+                self._dump_in -= 1
+                if self._dump_in <= 0:
+                    self._dump()
+            # What the next gap is measured from: read last, so that a late
+            # wake's deltas are those of the gap and of nothing before it.
+            cpu, charge = time.process_time(), account.read()
+            due += period
+            if due <= (now := time.monotonic()):
+                due = now + period
+        if self._dump_in is not None:
+            self._dump()
+
+    def _late(self, due: float, now: float, cpu: float, charge, account,
+              slow: dict) -> dict:
+        """The wake due at ``due`` came at ``now``: ``pulse.late``, with what
+        the gap was charged since the wake before.  Returns the sample of
+        the slower accounts it took."""
+        late_s = now - due
+        args = {"late_s": late_s, "cpu_s": time.process_time() - cpu}
+        runq0, runq1 = charge[1], account.read()[1]
+        if runq0 is not None and runq1 is not None:
+            args["runq_s"] = runq1 - runq0
+        collected = dict(self._collections)  # start -> end
+        if (since := self._collecting_since) is not None:
+            collected.setdefault(since, now)  # its hook has not closed it yet
+        args["gc_s"] = sum(max(min(end, now) - max(start, due), 0.0)
+                           for start, end in collected.items())
+        sample = self._sample()
+        for name, value in sample.items():
+            if name in slow:
+                args[name] = value - slow[name]
+        args["cause"] = book_stall(**args)
+        self._ring.append(("process", "pulse.late", "i", now, 0.0, args))
+        if self._timer is not None:
+            self._timer.update(late_s)
+        if (late_s >= self.STALL_S and self._on_stall is not None
+                and self._dump_in is None):
+            self._dump_in = self.DUMP_AFTER
+        return sample
+
+    def _dump(self) -> None:
+        on_stall, self._on_stall, self._dump_in = self._on_stall, None, None
+        try:
+            on_stall()
+        except Exception:  # noqa: BLE001 - observability only
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "stall dump failed", exc_info=True)
 
 
 #: (job name, recorder) of the most recent job started in this process.

@@ -656,7 +656,10 @@ class TestBackgroundFetch:
 
         mdef = get_model_def("lenet", num_classes=10)
         model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
-        f = ModelMapFunction(model, micro_batch=8, idle_flush_s=0.5,
+        # An idle deadline that cannot pass by itself: under loaded
+        # workers the poll below may outlast any short one, and the
+        # completion wake would then find the flush due as well.
+        f = ModelMapFunction(model, micro_batch=8, idle_flush_s=600.0,
                              transfer_lanes=1)
         emitted = []
         out = fn.Collector(lambda v, ts=None: emitted.append(v))

@@ -3,8 +3,10 @@
 event a window and span kind, none a record; the subtask thread's spans tile
 its time; a batch's spans share its seq; the ring outlives the job's release;
 with the ring and the tracer off the hooks allocate nothing; a park that
-returns late leaves ``park.overslept``; the registry reports every name the
-benchmark's ``counters`` metrics read (``benchmark/layer_metrics/*.json``).
+returns late leaves ``park.overslept``; a span whose two stamps are read on one
+thread says what the OS charged that thread between them; the registry reports
+every name the benchmark's ``counters`` metrics read
+(``benchmark/layer_metrics/*.json``).
 
 All tier-1 fast — no TPU, LeNet on tiny windows.
 """
@@ -191,6 +193,9 @@ def test_off_path_allocates_nothing_in_the_hooks(lenet):
     assert len(out) == WINDOW * WINDOWS
     assert handle.executor.flight is None and handle.executor.tracer is None
     assert all(st.spans is None for st in handle.executor.subtasks)
+    # No pulse and no thread's account either: both hang on the ring.
+    assert handle.executor.pulse is None
+    assert all(st.account is None for st in handle.executor.subtasks)
     assert recorder_of("spans-off") is None
     pkg = str(REPO / "flink_tensorflow_tpu" / "tracing")
     stats = snap.filter_traces([tracemalloc.Filter(True, pkg + "/*")]).statistics("filename")
@@ -312,6 +317,58 @@ def test_train_step_spans_and_timers():
     assert m["train.0.drain_wait_s"]["count"] == 4 and m["train.0.open_s"]["count"] == 1
     (opened,) = [e for e in events if e[1] == "open"]
     assert m["train.0.open_s"]["total_s"] == pytest.approx(opened[4], rel=1e-6)
+
+
+#: The spans that carry what the OS charged their thread, by job and thread;
+#: ``in_flight`` starts on another thread than it ends on, so its args are the
+#: fetch thread's stretch and are named for it.
+CHARGED = {
+    "model": ("fill", "fire", "collect_wait", "emit", "open",   # subtask thread
+              "enqueue",                                          # lane thread
+              "unbatch", "in_flight"),                            # fetch thread
+    "train": ("assemble", "h2d_enqueue", "dispatch", "drain_wait", "open"),
+}
+
+
+@pytest.fixture(scope="module")
+def charged_spans(lenet):
+    """``{(job, span name): [(seconds, args)]}`` of one job of each kind."""
+    handle, _ = _job(lenet, "spans-charged")
+    _train_job("spans-charged-train")
+    out = collections.defaultdict(list)
+    for job, events in (("model", _model_events(handle.executor.flight.events())),
+                        ("train", [e for e in recorder_of("spans-charged-train").events()
+                                   if e[0] == "train.0"])):
+        for _, name, ph, _, dur, args in events:
+            if ph == "X":
+                out[job, name].append((dur, args or {}))
+    return out
+
+
+@pytest.mark.parametrize("job,name", [(job, name) for job, names in CHARGED.items()
+                                      for name in names])
+def test_span_says_what_the_os_charged_its_thread(charged_spans, job, name):
+    import os
+
+    spans = charged_spans[job, name]
+    assert spans, f"no {name} span"
+    prefix = "fetch_" if name == "in_flight" else ""
+    has_runq = os.access(flight.SCHEDSTAT, os.R_OK)
+    for dur, args in spans:
+        cpu = args[prefix + "cpu_s"]
+        # The kernel books a running thread's seconds at its tick.
+        assert -1e-9 <= cpu <= dur + 0.02, (name, dur, args)
+        assert ((prefix + "runq_s") in args) == has_runq
+        if has_runq:
+            assert args[prefix + "runq_s"] >= 0.0
+    if name == "in_flight":
+        assert all("cpu_s" not in args for _, args in spans)  # not the whole span's
+
+
+def test_spans_whose_stamps_lie_on_two_threads_carry_no_charge(charged_spans):
+    for name in ("lane_wait", "handoff_wait"):
+        assert charged_spans["model", name]
+        assert all("cpu_s" not in args for _, args in charged_spans["model", name])
 
 
 #: The benchmark's per-layer metrics that read the job's registry, from its
