@@ -30,17 +30,35 @@ products' floor is 1.36 ms).  Where every expert is held the layer is the
 one-pass program it was before shares had a path of their own (at the same
 sizes with every pair here it takes 62 ms, and sixteen passes 117).
 
-The grouped product is the Pallas kernel ``pallas.ops.tpu.megablox.gmm`` at
-tiles of 512 rows x 2,048 x 512: on a v5e, at 32,768 rows of 2,048 against 32
-experts of 3,584 and uneven groups, it took 6.2 ms a layer's two products
+A layer's two grouped products are two Pallas kernels.  The first
+(:func:`gated_grouped_matmul`: rows against ``W1|W3``) is ``megablox.gmm``'s
+algorithm with two accumulators: it reads the gate and the up half of ``w13``
+where they are stored, and writes ``silu(gate) * up`` in ``compute_dtype``
+``[rows, f]``, computed in float32 on its accumulators and rounded once.  The
+second (:func:`grouped_matmul`: that against ``W2``) is ``megablox.gmm`` itself
+at tiles of 512 rows x 2,048 x 512, float32 out.  So what crosses HBM between
+the two is the bfloat16 ``[rows, f]`` the second one reads, and the float32
+``[rows, 2f]`` is no tensor of the program (until PR 40 the first product was
+``gmm`` too, wrote it, and an XLA pass read it back for ``silu * up``: 470 MB
+written and read for 117 MB a layer at 32,768 rows of 3,584).  On a v5e, alone,
+at 32,768 rows of 2,048 against 32 experts of 2 x 1,792 and uneven groups (the
+fullest 6.6% of the rows), before -> since: the first product 3.84 -> 3.85
+ms, ``silu * up`` 0.83 -> none, the ``W2`` product 2.21, a layer's products
+6.70 -> 6.09 (PERF.md section 6, PR 40; bit for bit the same ``[rows, f]``,
+on the chip as interpreted).  ``gmm`` had taken 6.2 ms a layer's two products
 where ``jax.lax.ragged_dot`` (which this XLA compiles to the same kernel at
-tiles of its own choosing: the results are equal bit for bit) took 8.6, and
-5.5 against 6.3 on even groups (PERF.md section 6, PR 35).  A share's groups
-are small, and the kernel computes a whole row tile for every group with a row
-in it: its row tile is then 256 or 128 (2,040 rows over 12 groups of 7,168 x
-4,096: 2.57 ms at 256, 2.83 at 128, 2.97 at 512; PR 37).  float32 callers
-get ``ragged_dot`` at ``HIGHEST``: the kernel's MXU pass would round their
-operands to bfloat16, as ops/flash_attention.py says of its own.
+tiles of its own choosing: the results are equal bit for bit) took 8.6 (PERF.md
+section 6, PR 35).  A share's groups are small, and either kernel computes a
+whole row tile for every group with a row in it: its row tile is then 256 or
+128 (2,040 rows over 12 groups of 7,168 x 4,096: 2.57 ms at 256, 2.83 at 128,
+2.97 at 512; PR 37).  In a share's loop of passes the narrower output has a
+second effect, which is XLA's and not the kernel's: with 17 MB out where 67
+were, the compiler keeps the pass's gathered rows (59 MB) in VMEM beside the
+kernel, which reads them once a column tile: 2.24 -> 1.87 ms a pass in
+``kimi_k2_7_code``'s step, where the two kernels alone take the same 2.46-2.48
+(PERF.md section 6, PR 40).  float32 callers get ``ragged_dot`` at ``HIGHEST`` and
+XLA's ``silu * up``: a kernel's MXU pass would round their operands to
+bfloat16, as ops/flash_attention.py says of its own.
 
 Precision: the router's product is float32 at ``Precision.HIGHEST`` (the
 choice is discontinuous: it must not be made on rounded scores), as are the
@@ -50,16 +68,21 @@ products take ``compute_dtype`` operands and accumulate in float32.
 
 from __future__ import annotations
 
+import functools
 import typing
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.megablox import gmm
+from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
 
 F32 = jnp.float32
 #: The grouped kernel's tiles: rows (shrunk for fewer rows), contraction, columns.
 TILE_ROWS, TILE_K, TILE_N = 512, 2048, 512
+_LANES = 128
 #: A share's buffers hold this many times the rows an even routing sends it.
 CAPACITY_SLACK = 2
 
@@ -96,6 +119,13 @@ def route(x, w_router, bias, *, k: int, scaling: float = 1.0, eps: float = 1e-6)
         return experts.astype(jnp.int32), weights
 
 
+def _row_tile(m: int, tile_rows: int = TILE_ROWS) -> int:
+    """The grouped kernels' row tile over ``m`` rows: ``tile_rows``, or all of
+    fewer rows in whole sublanes.  One rule for a layer's two products: they
+    then visit the same row tiles by the same group metadata."""
+    return min(tile_rows, -(-m // 8) * 8)
+
+
 def grouped_matmul(rows, stacked, group_sizes, *, compute_dtype=jnp.bfloat16, tile_rows: int = TILE_ROWS):
     """``rows[group g] @ stacked[g]`` for contiguous groups of ``rows`` ``[M,
     K]``; ``stacked`` ``[G, K, N]``, ``group_sizes`` int32 ``[G]``; float32
@@ -105,11 +135,139 @@ def grouped_matmul(rows, stacked, group_sizes, *, compute_dtype=jnp.bfloat16, ti
         return lax.ragged_dot(rows.astype(F32), stacked.astype(F32), group_sizes,
                               precision=lax.Precision.HIGHEST, preferred_element_type=F32)
     m = rows.shape[0]
-    tile = min(tile_rows, -(-m // 8) * 8)
+    tile = _row_tile(m, tile_rows)
     padded = jnp.pad(rows.astype(compute_dtype), ((0, -m % tile), (0, 0)))
     out = gmm(padded, stacked.astype(compute_dtype), group_sizes, preferred_element_type=F32,
               tiling=(tile, TILE_K, TILE_N), interpret=jax.default_backend() != "tpu")
     return out[:m]
+
+
+def gated_grouped_matmul(rows, w13, group_sizes, *, compute_dtype=jnp.bfloat16, tile_rows: int = TILE_ROWS,
+                         interpret: typing.Optional[bool] = None):
+    """``silu(rows[group g] @ gate[g]) * (rows[group g] @ up[g])`` for contiguous
+    groups of ``rows`` ``[M, d]``, where ``w13`` ``[G, d, 2f]`` holds expert
+    ``g``'s gate in its first ``f`` columns and its up projection in the last
+    ``f``; ``compute_dtype`` ``[M, f]`` out.  Rows past the last group are not
+    computed and hold anything.
+
+    One Pallas kernel, ``megablox.gmm`` with two accumulators: it reads ``w13``
+    as it is stored through two block specs (column block ``j`` of either half),
+    accumulates both products in float32 in VMEM and, on the last contraction
+    step, writes ``silu(gate) * up`` computed in float32 on the accumulators and
+    rounded ONCE, so the float32 ``[M, 2f]`` between a layer's two products is
+    no tensor of the program.  Group metadata, the grid's order, the contraction
+    remainder's mask and the rows' mask of a tile that two groups share are
+    ``gmm``'s; the tile is read off the shapes (:func:`gated_tiles`; ``tile_rows``
+    is :func:`grouped_matmul`'s).  float32 callers get :func:`grouped_matmul`'s
+    ``ragged_dot`` and the XLA ``silu * up``."""
+    f = w13.shape[2] // 2
+    if jnp.dtype(compute_dtype) == F32:
+        both = grouped_matmul(rows, w13, group_sizes, compute_dtype=compute_dtype)
+        return jax.nn.silu(both[:, :f]) * both[:, f:]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    tile = gated_tiles(rows.shape[0], rows.shape[1], f, tile_rows)
+    return _gated_call(rows, w13, group_sizes, jnp.dtype(compute_dtype), tile, interpret)
+
+
+# Jitted as ``megablox.gmm`` is: a model's layers of one shape are traced and lowered once.  The tile is
+# an argument, so that the trace reads nothing the cache's key does not hold.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _gated_call(rows, w13, group_sizes, compute_dtype, tile, interpret):
+    f = w13.shape[2] // 2
+    m, d = rows.shape
+    tm, tk, tn = tile
+    if not interpret and tn % _LANES:
+        raise ValueError(f"gate and up of {f} columns cannot be blocked out of [d, 2f] in tiles of {tn}: "
+                         f"compiled, a tile is whole lane tiles of {_LANES}")
+    padded = jnp.pad(rows.astype(compute_dtype), ((0, -m % tm), (0, 0)))
+    (offsets, group_ids, m_tile_ids), active_tiles = make_group_metadata(
+        group_sizes=group_sizes, m=padded.shape[0], tm=tm, start_group=jnp.int32(0),
+        num_nonzero_groups=w13.shape[0], visit_empty_groups=False)
+    tiles_k, k_rem = -(-d // tk), d % tk
+
+    def kernel(offsets, group_ids, m_tile_ids, lhs, gate, up, out, *accs):
+        visit, k_i = pl.program_id(1), pl.program_id(2)
+
+        def whole(x, axis, last):  # the contraction's remainder reads past the operand: zeros there
+            if not (last and k_rem):
+                return x
+            return jnp.where(lax.broadcasted_iota(jnp.int32, x.shape, axis) < k_rem, x.astype(F32), 0).astype(x.dtype)
+
+        def step(last):
+            x = whole(lhs[...], 1, last)
+            parts = [lax.dot_general(x, whole(w[...], 0, last), (((1,), (0,)), ((), ())), preferred_element_type=F32)
+                     for w in (gate, up)]
+            if accs:
+                for acc, part in zip(accs, parts):
+                    acc[...] += part
+                parts = [acc[...] for acc in accs]
+            if last:  # only the rows of this visit's group: a tile may be shared by two
+                group = group_ids[visit]
+                row = lax.broadcasted_iota(jnp.int32, (tm, tn), 0) + m_tile_ids[visit] * tm
+                mine = (row >= offsets[group]) & (row < offsets[group + 1])
+                gate_acc, up_acc = parts
+                out[...] = jnp.where(mine, jax.nn.silu(gate_acc) * up_acc, out[...].astype(F32)).astype(out.dtype)
+
+        if tiles_k == 1:  # nothing to accumulate over: no scratch
+            step(True)
+            return
+
+        @pl.when(k_i == 0)
+        def _():
+            for acc in accs:
+                acc[...] = jnp.zeros_like(acc)
+
+        lax.cond(k_i == tiles_k - 1, functools.partial(step, True), functools.partial(step, False))
+
+    half = f // tn  # the up half starts this many column blocks in
+    call = pl.pallas_call(
+        kernel,
+        name="gmm",  # what the benchmark's roofline share finds the grouped products by
+        out_shape=jax.ShapeDtypeStruct((padded.shape[0], f), compute_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[pl.BlockSpec((tm, tk), lambda n, v, k, offsets, group_ids, m_tile_ids: (m_tile_ids[v], k)),
+                      pl.BlockSpec((None, tk, tn), lambda n, v, k, offsets, group_ids, m_tile_ids: (group_ids[v], k, n)),
+                      pl.BlockSpec((None, tk, tn),
+                                   lambda n, v, k, offsets, group_ids, m_tile_ids: (group_ids[v], k, n + half))],
+            out_specs=pl.BlockSpec((tm, tn), lambda n, v, k, offsets, group_ids, m_tile_ids: (m_tile_ids[v], n)),
+            grid=(half, active_tiles, tiles_k),
+            scratch_shapes=[pltpu.VMEM((tm, tn), F32)] * (2 if tiles_k > 1 else 0)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        cost_estimate=pl.CostEstimate(flops=4 * m * d * f, transcendentals=m * f,
+                                      bytes_accessed=(half * m * d + w13.size + m * f) * padded.dtype.itemsize),
+        interpret=interpret)
+    w13 = w13.astype(compute_dtype)
+    return call(offsets, group_ids, m_tile_ids, padded, w13, w13)[:m]
+
+
+def gated_tiles(m: int, d: int, f: int, tile_rows: int = TILE_ROWS):
+    """(rows, contraction, columns) of the gated product's tile, read off its shapes.
+
+    Rows and contraction are the ``W2`` product's kernel's (:func:`_row_tile`,
+    at a share's own ``tile_rows`` where it passes one; ``TILE_K``), so a
+    layer's two kernels visit the same row tiles by the same group metadata.
+    Columns: gate and up in the widest whole lane tiles of at most half
+    ``TILE_N`` each that divide ``f``: the work of that kernel's 512 columns a
+    grid step, in no more VMEM (under 12 MB, within Mosaic's default).
+
+    What the chip showed (PERF.md section 6, PR 40, has the table): at lfm2's
+    layer half the rows and all of ``f`` (256 x 2,048 x 1,792, which has to ask
+    Mosaic for its VMEM) took 3.22 ms alone against 3.85 at the tile chosen,
+    because a group's edge inside a row tile is a second visit of the tile (32
+    groups over 64 tiles of 512 rows are 95 visits' MXU passes) and one
+    contraction tile keeps a group's weights in VMEM over its row tiles; in the
+    cell that was 6 ms a step more, but ``open()`` took 1.8-2.4 s longer (7-9%
+    of ``setup_s``, whose bound is 10%) while the ``W2`` kernel kept 512 rows:
+    each kernel then computes group metadata of its own.  With BOTH products at
+    256 rows ``open()`` stands where it stood and the step reads 145.74 ms for
+    152.65 (PR 40's last chip call, through a wrapper, too late to hand in):
+    ROADMAP S12's next item.  Wider columns at a share's sizes (256 x 2,048 x
+    2,048: 1.87 ms a pass against 2.51) were 1.3 ms of a 252 ms step and 0.7 s
+    of ``open()``: not taken."""
+    tn = max((n for n in range(_LANES, TILE_N // 2 + 1, _LANES) if f % n == 0), default=f)
+    return _row_tile(m, tile_rows), min(TILE_K, d), tn
 
 
 def share_capacity(pairs: int, held: int, num_experts: int) -> int:
@@ -117,7 +275,7 @@ def share_capacity(pairs: int, held: int, num_experts: int) -> int:
     what falls on ``held`` of ``num_experts`` when routing is even, rounded up
     to whole row tiles of the grouped kernel."""
     rows = -(-CAPACITY_SLACK * pairs * held // num_experts)
-    tile = min(TILE_ROWS, -(-rows // 8) * 8)
+    tile = _row_tile(rows)
     return -(-rows // tile) * tile
 
 
@@ -161,13 +319,11 @@ def routed_experts(x, w_router, bias, w13, w2, *, k: int, first: int = 0, scalin
 def _whole_layer(tokens, order, group_sizes, weights, w13, w2, k, compute_dtype):
     """Every pair falls on a held expert: a row for every pair, in one pass,
     gathered back to its token slot by slot."""
-    f = w2.shape[1]
     with jax.named_scope("dispatch"):
         rows = tokens.astype(compute_dtype)[order // k]
 
     with jax.named_scope("experts"):
-        both = grouped_matmul(rows, w13, group_sizes, compute_dtype=compute_dtype)
-        hidden = jax.nn.silu(both[:, :f]) * both[:, f:]
+        hidden = gated_grouped_matmul(rows, w13, group_sizes, compute_dtype=compute_dtype)
         y = grouped_matmul(hidden, w2, group_sizes, compute_dtype=compute_dtype)
 
     with jax.named_scope("combine"):
@@ -184,7 +340,7 @@ def _share_of_layer(tokens, order, group_sizes, weights, w13, w2, k, capacity, c
     their tokens' rows, runs both grouped products with the pass's own group
     sizes, and adds the weighted outputs into ``[B T, d]`` at their tokens.
     As many passes as the pairs here need, so none is dropped; (out, passes)."""
-    n, f = tokens.shape[0], w2.shape[1]
+    n = tokens.shape[0]
     # The kernel computes a whole row tile for every group that has a row in it, and a
     # share's groups are small: a tile near a group's rows under an even routing (a
     # power of two, 128 to 512) wastes less of the MXU on other groups' rows.
@@ -207,8 +363,7 @@ def _share_of_layer(tokens, order, group_sizes, weights, w13, w2, k, capacity, c
             # Of each group, what lies in [lo, lo + capacity).
             sizes = (jnp.clip(ends - lo, 0, capacity) - jnp.clip(ends - group_sizes - lo, 0, capacity))
         with jax.named_scope("experts"):
-            both = grouped_matmul(rows, w13, sizes, compute_dtype=compute_dtype, tile_rows=tile_rows)
-            hidden = jax.nn.silu(both[:, :f]) * both[:, f:]
+            hidden = gated_grouped_matmul(rows, w13, sizes, compute_dtype=compute_dtype, tile_rows=tile_rows)
             y = grouped_matmul(hidden, w2, sizes, compute_dtype=compute_dtype, tile_rows=tile_rows)
         with jax.named_scope("combine"):
             # Rows past the last group are another chip's pairs: nothing was computed there.

@@ -1,5 +1,5 @@
-"""The flash kernel compiled for a described TPU v5e (no chip attached): what
-Mosaic refuses (a block it cannot tile, a slice off the tiling, more VMEM than
+"""The flash kernel, and the routed experts' gated grouped product, compiled
+for a described TPU v5e (no chip attached): what Mosaic refuses (a block it cannot tile, a slice off the tiling, more VMEM than
 the call asked for) fails here and not on the chip.  Nothing runs, so this says
 nothing of results or speed; ``chip_smoke.py`` phase 2 holds the results.
 
@@ -7,12 +7,14 @@ The topology is described inside a fixture, never at import: one process at a
 time may load the TPU's library, and every xdist worker imports this file."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from flink_tensorflow_tpu.ops import moe
 from flink_tensorflow_tpu.ops.flash_attention import flash_attention, tile_plan
 
 
@@ -82,3 +84,32 @@ def test_chosen_tile_compiles_for_v5e(one_chip, case):
     assert "flash_attention" in compiled.as_text()
     plan = tile_plan(t, tk, d + rope, jnp.dtype(dtype), causal, dv=dv)
     assert t % plan.block_q == 0 and tk % plan.block_k == 0 and plan.block_k % plan.chunk == 0
+
+
+#: (rows, d, f, experts held, tile_rows): the two routed cells' first products at the row tiles their layers take,
+#: and at the others a share can take or that were tried on the chip (PERF.md 6, PR 40)
+_GATED = {
+    "lfm2-32768-rows-32-experts": (32768, 2048, 1792, 32, 512),
+    "kimi-a-pass-of-4096-rows-12-experts": (4096, 7168, 2048, 12, 256),  # 3.5 contraction tiles
+    "kimi-row-tile-128": (4096, 7168, 2048, 12, 128),
+    "kimi-row-tile-512": (4096, 7168, 2048, 12, 512),
+    "lfm2-half-the-rows": (32768, 2048, 1792, 32, 256),
+    "rows-no-multiple-of-the-tile": (1000, 512, 384, 3, 256),
+}
+
+
+@pytest.mark.parametrize("case", list(_GATED), ids=list(_GATED))
+def test_the_gated_grouped_product_compiles_for_v5e_under_the_name_gmm(one_chip, case):
+    m, d, f, held, tile_rows = _GATED[case]
+    described = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+
+    def call(rows, w13, group_sizes):
+        return moe.gated_grouped_matmul(rows, w13, group_sizes, tile_rows=tile_rows, interpret=False)
+
+    text = jax.jit(call).lower(described((m, d)), described((held, d, 2 * f)),
+                               described((held,), jnp.int32)).compile().as_text()
+    # one kernel, found by the benchmark's pattern for the grouped products, which writes bfloat16 [rows, f]
+    padded = -(-m // tile_rows) * tile_rows
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(rf"^\s*(ROOT )?%gmm(\.\d+)? = bf16\[{padded},{f}\]", text, re.M)
+    assert f"[{m},{2 * f}]" not in text and f"[{padded},{2 * f}]" not in text
