@@ -16,8 +16,10 @@ behavioral, not mechanism parity.  One module per BASELINE.json workload:
 Beyond the reference's workloads: :mod:`chartransformer` (the serving
 plane's char-level decoder), :mod:`falcon_h1` (a hybrid Mamba-2 +
 grouped-query attention language model), :mod:`lfm2_moe` (gated short
-convolutions, grouped-query attention and routed experts) and :mod:`kimi_k2`
-(latent attention, a chip's share of the routed experts beside a shared one),
+convolutions, grouped-query attention and routed experts), :mod:`kimi_k2`
+(latent attention, a chip's share of the routed experts beside a shared one)
+and :mod:`afmoe` (sliding-window and full attention mixed, a gated attention
+output, sandwich norms, a share of the routed experts beside a shared one),
 each scored a record at a time on the stream path.
 """
 

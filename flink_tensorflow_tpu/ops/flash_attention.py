@@ -19,6 +19,10 @@ native TPU kernel (pallas) rather than a composed jnp graph:
   0.01 output error the interpreter never shows)
 - causal grids skip fully-masked K/V tiles and chunks entirely
   (upper-triangle blocks are never read or computed)
+- a sliding window (``window=W``: key ``j`` seen by query ``i`` iff ``0 <=
+  i - j < W``) skips the tiles and chunks below the band as well: the grid's
+  K extent is the band's, not the sequence's, and only the chunks on the
+  band's two edges are masked
 
 Composes with the ``seq``-axis ring (parallel/ring_attention.py): ring
 moves K/V shards BETWEEN chips over ICI, this kernel computes each local
@@ -45,6 +49,7 @@ def flash_attention(
     q_rope=None,
     k_rope=None,
     rotate=None,
+    window: typing.Optional[int] = None,
 ):
     """Attention over ``[B, T, H, D]`` tensors (same layout/semantics as
     parallel.full_attention).  The kernel picks its tile from the shape
@@ -80,6 +85,14 @@ def flash_attention(
     rounds them to ``q``'s dtype.  A call without ``q_rope`` traces to
     the program it always did.
 
+    A sliding window (``window=W``, causal calls only): query ``i`` sees key
+    ``j`` iff ``0 <= i - j < W``.  The grid visits only the K tiles and the
+    chunks that meet a q block's band ``[first_row - W + 1, last_row]``, the
+    copied tile is at most half the band (three tiles a q block at ``W =
+    4096``, so that a q block copies three K/V tiles of 2,048 rows and not two of
+    8,192), and the call is named ``flash_attention_window`` so that a trace
+    tells its calls from the causal ones.  Without ``window`` nothing changes.
+
     Head sizes it has run at on the chip (TPU v5e, bfloat16, causal,
     4,096 positions: 512 query rows a program, K and V copied whole,
     scores 512 columns at a time): 128 (20 query heads on 4,
@@ -90,8 +103,12 @@ def flash_attention(
     Kimi-K2; 6.34 ms a call, 55% of its roofline: the rotary product
     fills half the MXU's 128 and takes a pass of its own; the same
     heads concatenated to 192 through the plain call took 6.00 ms and
-    4.5 ms of copies around it).  The tests also run 16, 64 and 128, 24
-    with 16, and 24 + 8 with 16, interpreted.
+    4.5 ms of copies around it).  At 32,768 positions, heads of 128, 48
+    query heads on 8 (Trinity-Large, PERF.md 6, PR 41): causal, K and V in
+    four tiles of 8,192 rows; and the band of ``W = 4096``, tiles of 2,048
+    rows, 540 compute tiles a head where the causal call visits 2,080.  The
+    tests also run 16, 64 and 128, 24 with 16, and 24 + 8 with 16, and
+    windows of 1 to 200 positions, interpreted.
 
     ``return_lse=True`` also returns the per-row log-sum-exp
     ``[B, H, T]`` (f32; the call is built without that output otherwise)
@@ -109,10 +126,14 @@ def flash_attention(
         raise ValueError(f"queries of {d} cannot meet keys of {k.shape[3]}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if window is not None and (not causal or q_rope is not None or tk != t or int(window) < 1):
+        raise ValueError(f"a window of {window} is a causal call's over its own {t} positions "
+                         f"(keys {tk}, causal {causal}, rotary part {q_rope is not None})")
     if q_rope is not None:
         return _flash_split(q, q_rope, k, k_rope, v, causal=causal, scale=scale, block_q=block_q,
                             block_k=block_k, interpret=interpret, return_lse=return_lse, rotate=rotate)
-    plan = tile_plan(t, tk, d, q.dtype, causal, block_q, block_k, dv=dv)
+    window = None if window is None else int(window)
+    plan = tile_plan(t, tk, d, q.dtype, causal, block_q, block_k, dv=dv, window=window)
 
     # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head).
     def to_bh(x):
@@ -121,7 +142,7 @@ def flash_attention(
     out, lse = _flash_bh(
         to_bh(q), to_bh(k), to_bh(v), group=h // hkv,
         scale=1.0 / math.sqrt(d) if scale is None else float(scale),
-        causal=causal, plan=plan, interpret=interpret, with_lse=return_lse,
+        causal=causal, plan=plan, interpret=interpret, with_lse=return_lse, window=window,
     )
     out = out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
     if return_lse:
@@ -293,13 +314,24 @@ class TilePlan(typing.NamedTuple):
 def tile_plan(t: int, tk: int, d: int, dtype, causal: bool,
               block_q: typing.Optional[int] = None,
               block_k: typing.Optional[int] = None, *,
-              dv: typing.Optional[int] = None) -> TilePlan:
+              dv: typing.Optional[int] = None,
+              window: typing.Optional[int] = None) -> TilePlan:
     """The tile of a call, read off its shape.  An edge the caller names is kept
     (as far as `_tileable_block` allows) and is then the compute tile's edge too;
     an edge left out is chosen: up to 512 query rows a program, the whole key
     sequence copied once if K and V fit 2 MiB each, scores 512 columns at a time.
     ``d`` is the head size of ``q`` and ``k``, ``dv`` that of ``v`` and the output
-    (``d`` if left out): the larger of the two sets the copied tile's rows."""
+    (``d`` if left out): the larger of the two sets the copied tile's rows.
+
+    With a ``window`` the copied tile chosen is at most half the band (and at
+    least a chunk), so that a q block copies a few tiles near its band and not
+    whole sequences of keys it never reads.  ``tiles_visited`` counts the
+    compute tiles (``block_q x chunk``) of one ``(batch x head)`` row that the
+    kernel computes: those that meet the causal triangle, or the band, by
+    :func:`_chunk_counts`, which is also the kernel's loop bounds; tiles above
+    the diagonal or below the band are neither copied nor computed and are not
+    counted.  ``tiles_masked`` counts those of them that the diagonal or the
+    band's lower edge crosses: they alone are masked."""
     import numpy as np
 
     itemsize = np.dtype(dtype).itemsize
@@ -310,15 +342,19 @@ def tile_plan(t: int, tk: int, d: int, dtype, causal: bool,
     if block_k:
         bk = chunk = _tileable_block(tk, block_k)
     else:
-        bk = _tileable_block(tk, max(_PREF_CHUNK, _KV_TILE_BYTES // (wide * itemsize)))
+        pref = max(_PREF_CHUNK, _KV_TILE_BYTES // (wide * itemsize))
+        if window:
+            pref = min(pref, max(_PREF_CHUNK, 1 << max(window // 2, 1).bit_length() - 1))
+        bk = _tileable_block(tk, pref)
         # A chunk inside the copied tile starts at a multiple of itself: whole lane
         # tiles of scores and whole sublane tiles of K, or the tile is one chunk.
         chunk = next((c for c in (_PREF_CHUNK, 256, 128) if bk % c == 0), bk)
     visited = masked = 0
     for qi in range(t // bq):
-        for j in range(tk // bk):
-            whole, some = _chunk_counts(qi, j, bq, bk, chunk, causal)
-            visited, masked = visited + some, masked + some - whole
+        for j in range(_kv_steps(t, tk, bq, bk, window)):
+            tile = _first_tile(qi, bq, bk, window) + j if window else j
+            low, whole_lo, whole_hi, high = _chunk_counts(qi, tile, bq, bk, chunk, causal, window)
+            visited, masked = visited + high - low, masked + (high - low) - (whole_hi - whole_lo)
     vmem = (
         2 * bq * (lanes(d) + lanes(dv)) * itemsize    # q and out blocks, double-buffered
         + 2 * bk * (lanes(d) + lanes(dv)) * itemsize  # K and V tiles, double-buffered
@@ -330,13 +366,18 @@ def tile_plan(t: int, tk: int, d: int, dtype, causal: bool,
     return TilePlan(bq, bk, chunk, visited, masked, vmem)
 
 
-def _chunk_counts(qi, j, block_q: int, block_k: int, chunk: int, causal: bool):
-    """Of K tile ``j``'s chunks, how many every row of q block ``qi`` sees whole
-    and how many any of its rows sees at all.  Python ints and traced scalars
-    alike: the kernel's loop bounds and `tile_plan`'s counts are this one rule."""
+def _chunk_counts(qi, j, block_q: int, block_k: int, chunk: int, causal: bool,
+                  window: typing.Optional[int] = None):
+    """Which of K tile ``j``'s chunks the rows of q block ``qi`` see: four chunk
+    indices ``low <= whole_lo <= whole_hi <= high``, where rows of the block see
+    chunks ``[low, high)`` at all and every row sees ``[whole_lo, whole_hi)``
+    whole; the chunks outside the whole range are the masked ones (``low`` and
+    ``whole_lo`` are 0 but for a ``window``'s lower edge).  Python ints and
+    traced scalars alike: the kernel's loop bounds and `tile_plan`'s counts are
+    this one rule."""
     n = block_k // chunk
     if not causal:
-        return n, n
+        return 0, 0, n, n
     if isinstance(qi, int):
         most, least = max, min
     else:
@@ -345,8 +386,37 @@ def _chunk_counts(qi, j, block_q: int, block_k: int, chunk: int, causal: bool):
         most, least = jnp.maximum, jnp.minimum
     # Chunk c holds keys lo + c*chunk .. lo + (c+1)*chunk - 1; row r sees keys 0 .. r.
     first_row, lo = qi * block_q, j * block_k
-    return (least(most(first_row + 1 - lo, 0) // chunk, n),
-            least((most(first_row + block_q - lo, 0) + chunk - 1) // chunk, n))
+    if window is None:
+        return (0, 0, least(most(first_row + 1 - lo, 0) // chunk, n),
+                least((most(first_row + block_q - lo, 0) + chunk - 1) // chunk, n))
+    # Row r sees keys r - window + 1 .. r: a chunk is seen at all where its last key
+    # reaches the first row's band and its first key the last row; whole where it
+    # lies above the last row's lower edge and below the first row.
+    clip = lambda c, a, b: least(most(c, a), b)  # noqa: E731
+    high = clip((first_row + block_q - lo + chunk - 1) // chunk, 0, n)
+    low = clip((first_row - window + 1 - lo) // chunk, 0, high)
+    whole_lo = clip((first_row + block_q - window - lo + chunk - 1) // chunk, low, high)
+    whole_hi = clip((first_row + 1 - lo) // chunk, whole_lo, high)
+    return low, whole_lo, whole_hi, high
+
+
+def _first_tile(qi, block_q: int, block_k: int, window: int):
+    """The first K tile that q block ``qi``'s band meets (Python ints or traced)."""
+    if isinstance(qi, int):
+        return max(qi * block_q - window + 1, 0) // block_k
+    import jax.numpy as jnp
+
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+
+
+def _kv_steps(t: int, tk: int, block_q: int, block_k: int, window: typing.Optional[int]) -> int:
+    """The grid's K extent: every K tile without a window; with one, the most
+    tiles any q block's band meets (a step past a block's diagonal asks again for
+    its last tile, which is then not copied, and computes nothing)."""
+    if window is None:
+        return tk // block_k
+    return max((qi * block_q + block_q - 1) // block_k - _first_tile(qi, block_q, block_k, window) + 1
+               for qi in range(t // block_q))
 
 
 def _vma(*xs):
@@ -357,7 +427,7 @@ def _vma(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
-def _flash_bh(q, k, v, *, scale, causal, plan, interpret, group=1, with_lse=True):
+def _flash_bh(q, k, v, *, scale, causal, plan, interpret, group=1, with_lse=True, window=None):
     """``q`` ``[B*H, T, D]``; ``k`` ``[B*H/group, Tk, D]``, ``v`` ``[B*H/group,
     Tk, Dv]``: row ``i`` of ``q`` reads row ``i // group`` of ``k`` and ``v``.
     Returns ``(out, lse)``, ``out`` ``[B*H, T, Dv]``, ``lse`` ``None`` unless
@@ -369,14 +439,14 @@ def _flash_bh(q, k, v, *, scale, causal, plan, interpret, group=1, with_lse=True
     fn = _build_flash_call(
         bh, t, k.shape[1], d, jax.numpy.dtype(q.dtype).name, causal,
         plan, interpret, _vma(q, k, v), group, with_lse,
-        v.shape[2], scale,
+        v.shape[2], scale, window=window,
     )
     return fn(q, k, v)
 
 
 @functools.lru_cache(maxsize=256)
 def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
-                      group, with_lse, dv, scale, heads=0, rope=0, rotate=False):
+                      group, with_lse, dv, scale, heads=0, rope=0, rotate=False, window=None):
     """Jitted pallas_call per static configuration.  Building a fresh
     closure per invocation would defeat jax.jit's cache (keyed on the
     function object) and recompile the Mosaic kernel on EVERY eager call.
@@ -395,6 +465,11 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
     dtype = jnp.dtype(dtype_str)
     block_q, block_k, chunk = plan.block_q, plan.block_k, plan.chunk
     nq, nk = t // block_q, tk // block_k
+    if window:
+        # The band's K extent; a masked score is finite, since a row can meet a tile
+        # (the band's first) in which it sees no key before it has seen one.
+        nk = _kv_steps(t, tk, block_q, block_k, window)
+        hidden = -0.7 * float(jnp.finfo(jnp.float32).max)
     # Mosaic's default contraction feeds the MXU one bf16 pass whatever
     # the operand dtype: a silent downcast of q/k/v/p for float32 callers,
     # who get float32 operands at HIGHEST; narrower callers' tiles go to the
@@ -412,7 +487,9 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
         # Row b_ of q reads row b_ // group of k and v (grouped queries).
         # Causal: a tile wholly above the diagonal is not computed, and
         # asking again for the last visible one keeps it from being copied.
-        if causal:
+        if window:  # the band's tiles from its first; past the diagonal, the last again
+            j = jnp.minimum(_first_tile(qi, block_q, block_k, window) + j, (qi * block_q + block_q - 1) // block_k)
+        elif causal:
             j = jnp.minimum(j, (qi * block_q + block_q - 1) // block_k)
         return (b_ // group, j, 0)
 
@@ -447,6 +524,8 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
         q_scr = q_scr[0] if scale_q else None  # q times the scale, once a q block
         qi = pl.program_id(1)
         j = pl.program_id(2)
+        # The K tile this step reads: the j-th of the band's, or the j-th.
+        tile = _first_tile(qi, block_q, block_k, window) + j if window else j
         head = pl.program_id(0) % heads if rope else None
 
         @pl.when(j == 0)
@@ -475,7 +554,11 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
             is ever empty here: key 0 is in the first chunk a row visits (causal
             rows see keys 0..r, others see all), so the max is finite from then
             on, ``exp(-inf - m)`` is 0 for a masked score and for the first
-            ``alpha``, and nothing needs a guard."""
+            ``alpha``, and nothing needs a guard.  In a band a row can see no
+            key of the first chunks it visits: its masked scores are then
+            ``hidden``, finite, and what they add is wiped by the first
+            ``alpha`` after its first real key (``exp(hidden - m)`` is 0), which
+            every row meets on its diagonal."""
             if chunk == block_k:
                 k_blk, v_blk = k_ref[0], v_ref[0]
             else:
@@ -497,8 +580,11 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
                 # key j*block_k + c*chunk + col <= query qi*block_q + row
                 rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, chunk), 0)
                 cols_ = jax.lax.broadcasted_iota(jnp.int32, (block_q, chunk), 1)
-                ahead = qi * block_q - j * block_k - c * chunk
-                s = jnp.where(cols_ - rows <= ahead, s, -jnp.inf)
+                ahead = qi * block_q - tile * block_k - c * chunk
+                if window:  # and key > query - window
+                    s = jnp.where((cols_ - rows <= ahead) & (cols_ - rows > ahead - window), s, hidden)
+                else:
+                    s = jnp.where(cols_ - rows <= ahead, s, -jnp.inf)
             m_prev = m_scr[...]
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - across(m_next, chunk))
@@ -510,10 +596,16 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
                 preferred_element_type=jnp.float32)
 
         # Chunks below the diagonal first, with no mask; then those it crosses.
-        whole, some = _chunk_counts(qi, j, block_q, block_k, chunk, causal)
-        jax.lax.fori_loop(0, whole, lambda c, _: update(c, False), None)
-        if causal:
-            jax.lax.fori_loop(whole, some, lambda c, _: update(c, True), None)
+        if window:  # the band's lower edge, the chunks every row sees whole, the diagonal
+            low, whole_lo, whole_hi, high = _chunk_counts(qi, tile, block_q, block_k, chunk, causal, window)
+            jax.lax.fori_loop(low, whole_lo, lambda c, _: update(c, True), None)
+            jax.lax.fori_loop(whole_lo, whole_hi, lambda c, _: update(c, False), None)
+            jax.lax.fori_loop(whole_hi, high, lambda c, _: update(c, True), None)
+        else:
+            _, _, whole, some = _chunk_counts(qi, j, block_q, block_k, chunk, causal)
+            jax.lax.fori_loop(0, whole, lambda c, _: update(c, False), None)
+            if causal:
+                jax.lax.fori_loop(whole, some, lambda c, _: update(c, True), None)
 
         @pl.when(j == nk - 1)
         def _finalize():
@@ -579,7 +671,7 @@ def _build_flash_call(bh, t, tk, d, dtype_str, causal, plan, interpret, vma,
                               if plan.vmem_bytes > _VMEM_DEFAULT else None),
         ),
         interpret=interpret,
-        name="flash_attention",
+        name="flash_attention_window" if window else "flash_attention",
     )
 
     def fn(*operands):

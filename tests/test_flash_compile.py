@@ -31,7 +31,7 @@ def one_chip():
 
 
 #: (q shape [B, T, H, D], key positions, key/value heads, dtype, causal, return_lse[, the values' head size
-#: [, the rotary part handed over beside q and k, whether the kernel turns q's]])
+#: [, the rotary part handed over beside q and k, whether the kernel turns q's[, a sliding window]]])
 _SHAPES = {
     # the three language-model cells
     "falcon_h1-20on4-head128": ((2, 4096, 20, 128), 4096, 4, "bfloat16", True, False),
@@ -50,6 +50,11 @@ _SHAPES = {
     "ring-off-diagonal": ((1, 1024, 4, 128), 4096, 4, "bfloat16", False, True),
     # K and V too long to copy whole: a grid over key tiles, chunks inside each
     "keys-in-tiles": ((1, 16384, 8, 128), 16384, 8, "bfloat16", True, False),
+    # Trinity-Large's two calls at 32,768 positions, 48 query heads on 8: the band of 4,096 (three
+    # tiles of 2,048 keys a q block) and the full layer's triangle (four tiles of 8,192)
+    "trinity-window-4096-48on8-32768": ((1, 32768, 48, 128), 32768, 8, "bfloat16", True, False, None, 0, False, 4096),
+    "trinity-full-48on8-32768": ((1, 32768, 48, 128), 32768, 8, "bfloat16", True, False),
+    "window-not-a-multiple-of-the-chunk-float32-lse": ((1, 4096, 4, 128), 4096, 2, "float32", True, True, None, 0, False, 1000),
     "float32-4096": ((1, 4096, 2, 128), 4096, 2, "float32", True, True),
     # TestTileableBlocks' lengths: no multiple of 128, no multiple of 8, mixed
     "length-100": ((1, 100, 2, 16), 100, 2, "float32", True, True),
@@ -62,8 +67,8 @@ _SHAPES = {
 
 @pytest.mark.parametrize("case", list(_SHAPES), ids=list(_SHAPES))
 def test_chosen_tile_compiles_for_v5e(one_chip, case):
-    case = _SHAPES[case]  # the last three are optional: as q's, none, not turned
-    shape, tk, kv_heads, dtype, causal, return_lse, dv, rope, turn = case + (None, 0, False)[len(case) - 6:]
+    case = _SHAPES[case]  # the last four are optional: as q's, none, not turned, no window
+    shape, tk, kv_heads, dtype, causal, return_lse, dv, rope, turn, window = case + (None, 0, False, None)[len(case) - 6:]
     b, t, h, d = shape
     dv = dv or d
     described = lambda shape, dtype=dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)  # noqa: E731
@@ -76,13 +81,14 @@ def test_chosen_tile_compiles_for_v5e(one_chip, case):
     def call(operands):
         # A scale is given where the head sizes differ, as latent attention gives one.
         return flash_attention(**operands, causal=causal, interpret=False, return_lse=return_lse,
-                               scale=0.1447 if rope or dv != d else None)
+                               scale=0.1447 if rope or dv != d else None, window=window)
 
     compiled = jax.jit(call).lower(operands).compile()
     # one kernel a call, under the name the benchmark's roofline share reads
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
     assert "flash_attention" in compiled.as_text()
-    plan = tile_plan(t, tk, d + rope, jnp.dtype(dtype), causal, dv=dv)
+    assert ("flash_attention_window" in compiled.as_text()) == bool(window)
+    plan = tile_plan(t, tk, d + rope, jnp.dtype(dtype), causal, dv=dv, window=window)
     assert t % plan.block_q == 0 and tk % plan.block_k == 0 and plan.block_k % plan.chunk == 0
 
 

@@ -389,6 +389,10 @@ _ROUTED = {
                     num_key_value_heads=2, q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
                     qk_rope_head_dim=8, v_head_dim=8, n_routed_experts=2, router_experts=8,
                     num_experts_per_tok=2),
+    "afmoe": dict(seq_len=8, vocab_size=64, hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+                  num_hidden_layers=2, layer_types=("sliding_attention", "full_attention"), num_dense_layers=1,
+                  num_attention_heads=2, num_key_value_heads=1, head_dim=16, sliding_window=4, num_experts=2,
+                  router_experts=8, num_experts_per_tok=2),
 }
 
 
@@ -419,7 +423,8 @@ def registry(lenet):
     runs, with the operators named as its jobs name them."""
     handle, _ = _job(lenet, "registry-stream", source=_paced_source(), source_name="offered")
     return {**_routed_job("registry-routed", "lfm2_moe").metrics,
-            **_routed_job("registry-share", "kimi_k2").metrics, **handle.executor.metrics.report(),
+            **_routed_job("registry-share", "kimi_k2").metrics, **_routed_job("registry-band", "afmoe").metrics,
+            **handle.executor.metrics.report(),
             **_train_job("registry-train").metrics}
 
 
