@@ -60,6 +60,22 @@ def _avg_pool_same(x):
     return nn.avg_pool(x, (3, 3), strides=(1, 1), padding="SAME")
 
 
+def _stored(x):
+    """A branch's value that a windowed unit (a kernel other than 1x1) reads,
+    written out once.
+
+    Left alone, XLA fuses the convolution that makes the value (or its
+    norm and relu) into the operand of the windowed one and computes it
+    again for every tap of the window: a 1x7 would run the 7x1 before it
+    seven times.  The barrier is an identity that keeps the two apart, so
+    the reader takes the bfloat16 tensor its producer stored.  Never on a
+    block's input, whose concatenation would then be written out, and not
+    in the stem, whose producers are cheap to recompute and their outputs
+    (0.9-1.5 GB at a batch of 1024) dear to store and read back.
+    """
+    return jax.lax.optimization_barrier(x)
+
+
 class InceptionA(nn.Module):
     pool_features: int
     dtype: jnp.dtype = jnp.bfloat16
@@ -68,10 +84,10 @@ class InceptionA(nn.Module):
     def __call__(self, x, train: bool = False):
         c = functools.partial(ConvBN, compute_dtype=self.dtype)
         b1 = c(64, (1, 1))(x, train)
-        b5 = c(48, (1, 1))(x, train)
+        b5 = _stored(c(48, (1, 1))(x, train))
         b5 = c(64, (5, 5), padding="SAME")(b5, train)
-        b3 = c(64, (1, 1))(x, train)
-        b3 = c(96, (3, 3), padding="SAME")(b3, train)
+        b3 = _stored(c(64, (1, 1))(x, train))
+        b3 = _stored(c(96, (3, 3), padding="SAME")(b3, train))
         b3 = c(96, (3, 3), padding="SAME")(b3, train)
         bp = c(self.pool_features, (1, 1), avg_pool=True)(x, train)
         return jnp.concatenate([b1, b5, b3, bp], axis=-1)
@@ -84,8 +100,8 @@ class ReductionA(nn.Module):
     def __call__(self, x, train: bool = False):
         c = functools.partial(ConvBN, compute_dtype=self.dtype)
         b3 = c(384, (3, 3), strides=(2, 2))(x, train)
-        bd = c(64, (1, 1))(x, train)
-        bd = c(96, (3, 3), padding="SAME")(bd, train)
+        bd = _stored(c(64, (1, 1))(x, train))
+        bd = _stored(c(96, (3, 3), padding="SAME")(bd, train))
         bd = c(96, (3, 3), strides=(2, 2))(bd, train)
         bp = nn.max_pool(x, (3, 3), strides=(2, 2))
         return jnp.concatenate([b3, bd, bp], axis=-1)
@@ -102,13 +118,13 @@ class InceptionB(nn.Module):
         c = functools.partial(ConvBN, compute_dtype=self.dtype)
         c7 = self.channels_7x7
         b1 = c(192, (1, 1))(x, train)
-        b7 = c(c7, (1, 1))(x, train)
-        b7 = c(c7, (1, 7), padding="SAME")(b7, train)
+        b7 = _stored(c(c7, (1, 1))(x, train))
+        b7 = _stored(c(c7, (1, 7), padding="SAME")(b7, train))
         b7 = c(192, (7, 1), padding="SAME")(b7, train)
-        bd = c(c7, (1, 1))(x, train)
-        bd = c(c7, (7, 1), padding="SAME")(bd, train)
-        bd = c(c7, (1, 7), padding="SAME")(bd, train)
-        bd = c(c7, (7, 1), padding="SAME")(bd, train)
+        bd = _stored(c(c7, (1, 1))(x, train))
+        bd = _stored(c(c7, (7, 1), padding="SAME")(bd, train))
+        bd = _stored(c(c7, (1, 7), padding="SAME")(bd, train))
+        bd = _stored(c(c7, (7, 1), padding="SAME")(bd, train))
         bd = c(192, (1, 7), padding="SAME")(bd, train)
         bp = c(192, (1, 1), avg_pool=True)(x, train)
         return jnp.concatenate([b1, b7, bd, bp], axis=-1)
@@ -120,11 +136,11 @@ class ReductionB(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         c = functools.partial(ConvBN, compute_dtype=self.dtype)
-        b3 = c(192, (1, 1))(x, train)
+        b3 = _stored(c(192, (1, 1))(x, train))
         b3 = c(320, (3, 3), strides=(2, 2))(b3, train)
-        b7 = c(192, (1, 1))(x, train)
-        b7 = c(192, (1, 7), padding="SAME")(b7, train)
-        b7 = c(192, (7, 1), padding="SAME")(b7, train)
+        b7 = _stored(c(192, (1, 1))(x, train))
+        b7 = _stored(c(192, (1, 7), padding="SAME")(b7, train))
+        b7 = _stored(c(192, (7, 1), padding="SAME")(b7, train))
         b7 = c(192, (3, 3), strides=(2, 2))(b7, train)
         bp = nn.max_pool(x, (3, 3), strides=(2, 2))
         return jnp.concatenate([b3, b7, bp], axis=-1)
@@ -139,11 +155,11 @@ class InceptionC(nn.Module):
     def __call__(self, x, train: bool = False):
         c = functools.partial(ConvBN, compute_dtype=self.dtype)
         b1 = c(320, (1, 1))(x, train)
-        b3 = c(384, (1, 1))(x, train)
+        b3 = _stored(c(384, (1, 1))(x, train))
         b3a = c(384, (1, 3), padding="SAME")(b3, train)
         b3b = c(384, (3, 1), padding="SAME")(b3, train)
-        bd = c(448, (1, 1))(x, train)
-        bd = c(384, (3, 3), padding="SAME")(bd, train)
+        bd = _stored(c(448, (1, 1))(x, train))
+        bd = _stored(c(384, (3, 3), padding="SAME")(bd, train))
         bda = c(384, (1, 3), padding="SAME")(bd, train)
         bdb = c(384, (3, 1), padding="SAME")(bd, train)
         bp = c(192, (1, 1), avg_pool=True)(x, train)

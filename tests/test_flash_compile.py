@@ -1,7 +1,8 @@
-"""The flash kernel, and the routed experts' gated grouped product, compiled
-for a described TPU v5e (no chip attached): what Mosaic refuses (a block it cannot tile, a slice off the tiling, more VMEM than
-the call asked for) fails here and not on the chip.  Nothing runs, so this says
-nothing of results or speed; ``chip_smoke.py`` phase 2 holds the results.
+"""The flash kernel, the routed experts' gated grouped product and Inception-v3's
+step, compiled for a described TPU v5e (no chip attached): what Mosaic refuses (a block it cannot tile, a slice off the tiling, more VMEM than
+the call asked for) fails here and not on the chip, and so does a fusion XLA would
+pick that the model is written to avoid.  Nothing runs, so this says nothing of
+results or speed; ``chip_smoke.py`` phase 2 holds the results.
 
 The topology is described inside a fixture, never at import: one process at a
 time may load the TPU's library, and every xdist worker imports this file."""
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from flink_tensorflow_tpu.models import get_model_def
 from flink_tensorflow_tpu.ops import moe
 from flink_tensorflow_tpu.ops.flash_attention import flash_attention, tile_plan
 
@@ -119,3 +121,44 @@ def test_the_gated_grouped_product_compiles_for_v5e_under_the_name_gmm(one_chip,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert re.search(rf"^\s*(ROOT )?%gmm(\.\d+)? = bf16\[{padded},{f}\]", text, re.M)
     assert f"[{m},{2 * f}]" not in text and f"[{padded},{2 * f}]" not in text
+
+
+def _nested_convolutions(text):
+    """The op_name of every fusion of the entry computation that holds, in
+    itself or in a fusion nested in it, more than one convolution: a
+    producer computed inside its consumer's operand."""
+    bodies, entry, body = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            body = bodies.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
+        elif line.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(line)
+
+    def convolutions(name):
+        return sum((" convolution(" in line) + sum(map(convolutions, re.findall(r"calls=%([\w.\-]+)", line)))
+                   for line in bodies[name])
+    return [re.search(r'op_name="([^"]+)"', line).group(1) for line in bodies[entry]
+            if re.search(r" fusion\(", line) and convolutions(re.search(r"calls=%([\w.\-]+)", line).group(1)) > 1]
+
+
+def test_inception_blocks_compute_no_convolution_inside_another_for_v5e(one_chip):
+    """Left alone XLA fuses a branch's producer convolution into the operand
+    of the windowed one that reads it and computes it once per tap (17 such
+    fusions in the blocks at a batch of 1,024: PERF.md section 7, row 6).
+    The blocks store those values, so only the stem's (left there on
+    purpose) remain.  A batch of 128 lays the blocks' tensors out as the
+    cell's 1,024 does, batch in the lanes, in a fifth of the compile."""
+    mdef = get_model_def("inception_v3", uint8_input=True)
+    params = jax.tree_util.tree_map(lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=one_chip),
+                                    jax.eval_shape(mdef.init_fn, jax.random.key(0)))
+    image = jax.ShapeDtypeStruct((128, 299, 299, 3), jnp.uint8, sharding=one_chip)
+    text = jax.jit(mdef.methods["serve"].fn).lower(params, {"image": image}).compile().as_text()
+
+    assert re.search(r"= bf16\[128,17,17,192\]\{0,3,2,1:", text)
+    nested = [name.split("InceptionV3/")[1] for name in _nested_convolutions(text)]
+    assert nested, "the census finds not even the stem's nested convolutions"
+    assert not [name for name in nested if name.startswith(("Inception", "Reduction"))], nested

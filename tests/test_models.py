@@ -12,6 +12,7 @@ import pathlib
 import numpy as np
 import pytest
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
@@ -33,6 +34,27 @@ def rng():
 
 def init_jit(mdef, rng):
     return jax.jit(mdef.init_fn)(rng)
+
+
+def _perturbed(variables):
+    """A fresh norm is the identity: give every leaf a value of its own
+    (variances stay positive)."""
+    leaves, treedef = jax.tree_util.tree_flatten(variables)
+    keys = jax.random.split(jax.random.key(3), len(leaves))
+    return jax.tree_util.tree_unflatten(treedef, [
+        leaf + 0.5 * jax.random.uniform(k, leaf.shape) for leaf, k in zip(leaves, keys)])
+
+
+#: Each block's branches as chains of its ``ConvBN_<i>`` units from the
+#: block's input, in the order they are concatenated; "pool" is the 3x3/2
+#: max-pool of the input.
+_BRANCHES = {
+    "A": (inception.InceptionA(32, jnp.float32), [[0], [1, 2], [3, 4, 5], [6]]),
+    "ReductionA": (inception.ReductionA(jnp.float32), [[0], [1, 2, 3], "pool"]),
+    "B": (inception.InceptionB(128, jnp.float32), [[0], [1, 2, 3], [4, 5, 6, 7, 8], [9]]),
+    "ReductionB": (inception.ReductionB(jnp.float32), [[0, 1], [2, 3, 4, 5], "pool"]),
+    "C": (inception.InceptionC(jnp.float32), [[0], [1, 2], [1, 3], [4, 5, 6], [4, 5, 7], [8]]),
+}
 
 
 class TestZoo:
@@ -92,13 +114,7 @@ class TestZoo:
         (average the block's input, then conv, norm, relu) does from the
         same variables, and in training leaves the same batch_stats."""
         x = jax.random.normal(jax.random.key(1), (2, 9, 9, 24))
-        variables = jax.jit(lambda k: block.init(k, x))(jax.random.key(2))
-        # A fresh norm is the identity: give every leaf a value of its own
-        # (variances stay positive).
-        leaves, treedef = jax.tree_util.tree_flatten(variables)
-        keys = jax.random.split(jax.random.key(3), len(leaves))
-        variables = jax.tree_util.tree_unflatten(treedef, [
-            leaf + 0.5 * jax.random.uniform(k, leaf.shape) for leaf, k in zip(leaves, keys)])
+        variables = _perturbed(jax.jit(lambda k: block.init(k, x))(jax.random.key(2)))
         plain = inception.ConvBN(features, (1, 1), compute_dtype=jnp.float32)
         of_unit = {col: tree[unit] for col, tree in variables.items()}
 
@@ -144,6 +160,91 @@ class TestZoo:
                   for level in _iter_levels(jaxpr.jaxpr) for eqn in level.eqns
                   if eqn.primitive.name == "reduce_window_sum"]
         assert pooled == [32, 64, 64] + [192] * 6
+
+    def test_inception_v3_stores_what_its_windowed_units_read(self):
+        """Every branch value that a unit with a kernel other than 1x1 reads
+        goes through one barrier (InceptionC's two shared ones feed both
+        readers): 45 a step at the recorded sizes and widths, none in the
+        stem (149, 147, 73, 71 wide) and none on a block's input (a
+        concatenation or the stem's last max-pool)."""
+        mdef = get_model_def("inception_v3", uint8_input=True)
+        params = jax.eval_shape(mdef.init_fn, jax.random.key(0))
+        image = jax.ShapeDtypeStruct((1, 299, 299, 3), jnp.uint8)
+        jaxpr = jax.make_jaxpr(mdef.methods["serve"].fn)(params, {"image": image})
+
+        stored, producers = [], []
+        for level in _iter_levels(jaxpr.jaxpr):
+            made_by = {v: eqn.primitive.name for eqn in level.eqns for v in eqn.outvars}
+            for eqn in level.eqns:
+                if eqn.primitive.name == "optimization_barrier":
+                    stored += [v.aval.shape[1:] for v in eqn.invars]
+                    producers += [made_by.get(v) for v in eqn.invars]
+        block_a = [(35, 35, 48), (35, 35, 64), (35, 35, 96)]
+        block_b = lambda c7: [(17, 17, c7)] * 6  # noqa: E731
+        assert stored == (block_a * 3 + [(35, 35, 64), (35, 35, 96)]
+                          + block_b(128) + block_b(160) * 2 + block_b(192)
+                          + [(17, 17, 192)] * 4 + [(8, 8, 384), (8, 8, 448), (8, 8, 384)] * 2)
+        assert not {"concatenate", "reduce_window_max"} & set(producers)
+
+    @pytest.mark.parametrize("train", [False, True], ids=["serve", "train"])
+    @pytest.mark.parametrize("name", list(_BRANCHES))
+    def test_inception_block_answers_as_its_units_applied_one_by_one(self, name, train):
+        """The barriers are identities: a block gives the output, and in
+        training the batch_stats, of its units applied one after another by
+        hand from the same variables."""
+        block, branches = _BRANCHES[name]
+        x = jax.random.normal(jax.random.key(1), (2, 9, 9, 24))
+        units = {}
+
+        def record(f, args, kwargs, context):
+            m = context.module
+            if isinstance(m, inception.ConvBN) and context.method_name == "__call__":
+                units[m.name] = inception.ConvBN(m.features, m.kernel, m.strides, m.padding,
+                                                 m.compute_dtype, m.avg_pool)
+            return f(*args, **kwargs)
+
+        with nn.intercept_methods(record):
+            variables = _perturbed(jax.jit(lambda k: block.init(k, x))(jax.random.key(2)))
+
+        @jax.jit
+        def both(variables, x):
+            out, new = block.apply(variables, x, train, mutable=["batch_stats"])
+            parts, stats = [], {}
+            for chain in branches:
+                if chain == "pool":
+                    parts.append(nn.max_pool(x, (3, 3), strides=(2, 2)))
+                    continue
+                y = x
+                for i in chain:
+                    unit = f"ConvBN_{i}"
+                    y, stats[unit] = units[unit].apply(
+                        {col: tree[unit] for col, tree in variables.items()}, y, train,
+                        mutable=["batch_stats"])
+                parts.append(y)
+            return out, new["batch_stats"], jnp.concatenate(parts, axis=-1), stats
+
+        got, got_stats, ref, ref_stats = both(variables, x)
+        assert len(units) == len(got_stats) == len(ref_stats)
+        assert float(jnp.max(ref)) > 0.1  # the relus have not zeroed the case
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+        for unit, stats in ref_stats.items():
+            for a, b in zip(jax.tree_util.tree_leaves(got_stats[unit]),
+                            jax.tree_util.tree_leaves(stats["batch_stats"])):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+    def test_inception_v3_gradient_crosses_the_stored_values(self, rng):
+        """The train path runs the same blocks: on two 75x75 images (the
+        least the stem and reductions take) every gradient is finite, and a
+        unit whose output is stored (InceptionB_0's first 1x1, read by a
+        1x7) gets one."""
+        mdef = get_model_def("inception_v3", num_classes=10, image_size=75)
+        params = init_jit(mdef, rng)
+        batch = {"image": jax.random.normal(jax.random.key(4), (2, 75, 75, 3)),
+                 "label": jnp.array([1, 7], jnp.int32)}
+        grads = jax.jit(jax.grad(lambda p: mdef.loss_fn(p, batch, rng)[0]))(params)
+        assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree_util.tree_leaves(grads))
+        kernel = grads["params"]["InceptionB_0"]["ConvBN_1"]["Conv_0"]["kernel"]
+        assert float(jnp.max(jnp.abs(kernel))) > 0
 
     def test_bilstm_padding_invariance(self, rng):
         """Same sequence padded to different buckets -> same logits: the
