@@ -18,9 +18,11 @@ plane's char-level decoder), :mod:`falcon_h1` (a hybrid Mamba-2 +
 grouped-query attention language model), :mod:`lfm2_moe` (gated short
 convolutions, grouped-query attention and routed experts), :mod:`kimi_k2`
 (latent attention, a chip's share of the routed experts beside a shared one)
-and :mod:`afmoe` (sliding-window and full attention mixed, a gated attention
-output, sandwich norms, a share of the routed experts beside a shared one),
-each scored a record at a time on the stream path.
+:mod:`afmoe` (sliding-window and full attention mixed, a gated attention
+output, sandwich norms, a share of the routed experts beside a shared one)
+and :mod:`mellum` (sliding-window and yarn-scaled full attention mixed, a
+softmax router over narrow experts, every one held), each scored a record at
+a time on the stream path.
 """
 
 from flink_tensorflow_tpu.models.zoo.registry import ModelDef, get_model_def, register_model_def

@@ -45,13 +45,21 @@ def _rms_norm(x, weight, eps):
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * weight.astype(F32)
 
 
-def _rope(x, theta: float):
-    """Rotate-half over the whole head: ``x`` is ``[B, T, heads, head_dim]`` float32."""
+def _rope(x, theta: float, inv_freq=None, scale: float = 1.0):
+    """Rotate-half over the whole head: ``x`` is ``[B, T, heads, head_dim]`` float32.
+    Pair ``j`` turns by ``theta^(-2j/head_dim)`` a position, or by ``inv_freq[j]``
+    where given (yarn's, ops/mla.py ``yarn_inv_freq``); ``cos`` and ``sin`` are
+    times ``scale`` where it is not 1 (yarn's ``attention_factor``)."""
     t, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
+    if inv_freq is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
+    else:
+        inv = jnp.asarray(np.asarray(inv_freq, np.float32))
     angle = jnp.arange(t, dtype=F32)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     half = jnp.concatenate([-x[..., hd // 2:], x[..., :hd // 2]], axis=-1)
     return x * cos + half * sin
 

@@ -55,7 +55,7 @@ def register_model_def(name: str):
 
 
 _ZOO_MODULES = ("lenet", "inception", "resnet", "bilstm", "widedeep",
-                "chartransformer", "falcon_h1", "lfm2_moe", "kimi_k2", "afmoe")
+                "chartransformer", "falcon_h1", "lfm2_moe", "kimi_k2", "afmoe", "mellum")
 
 
 def get_model_def(architecture: str, **config) -> ModelDef:

@@ -62,7 +62,7 @@ bfloat16, as ops/flash_attention.py says of its own.
 
 Precision: the router's product is float32 at ``Precision.HIGHEST`` (the
 choice is discontinuous: it must not be made on rounded scores), as are the
-sigmoid, the selection bias, the top-k and the combine weights; the experts'
+sigmoid or the softmax, the selection bias, the top-k and the combine weights; the experts'
 products take ``compute_dtype`` operands and accumulate in float32.
 """
 
@@ -85,6 +85,13 @@ TILE_ROWS, TILE_K, TILE_N = 512, 2048, 512
 _LANES = 128
 #: A share's buffers hold this many times the rows an even routing sends it.
 CAPACITY_SLACK = 2
+#: Where every expert is held and a layer's ``k`` slots, float32 ``[tokens, d]`` each, would hold more
+#: than this at once, the combine adds them one slot at a time in a loop.  Unrolled, XLA gathers every
+#: slot before the sum: at Mellum 2's layer (32,768 tokens, top-8, d = 2,304: 8 x 302 MB) 8 layers
+#: took 15.4 GiB of scratch compiled for a v5e, over the chip with the weights; the loop leaves 8.0 GB.
+#: No timing sets the number itself: any cutoff between LFM2's 268 MB (unrolled, the program its cell
+#: has always run) and Mellum's 2.4 GB (unrolled, no room) picks the same path for every model here.
+COMBINE_UNROLLED_BYTES = 1 << 30
 
 
 class Routed(typing.NamedTuple):
@@ -103,16 +110,32 @@ class Routed(typing.NamedTuple):
     passes: typing.Any
 
 
-def route(x, w_router, bias, *, k: int, scaling: float = 1.0, eps: float = 1e-6):
-    """Sigmoid router with a selection bias: ``x`` ``[N, d]`` float32 ->
-    (``experts`` int32 ``[N, k]``, ``weights`` float32 ``[N, k]``).
+def route(x, w_router, bias, *, k: int, scaling: float = 1.0, eps: float = 1e-6, score_func: str = "sigmoid"):
+    """The router: ``x`` ``[N, d]`` float32 -> (``experts`` int32 ``[N, k]``,
+    ``weights`` float32 ``[N, k]``).
 
-    ``bias`` chooses and never weighs: the top-k is over ``sigmoid(x W) +
-    bias`` (ties to the lower index), the weights are the chosen experts' own
-    scores over their sum plus ``eps``, times ``scaling``."""
+    ``score_func="sigmoid"``: a sigmoid router with a selection bias.  ``bias``
+    chooses and never weighs: the top-k is over ``sigmoid(x W) + bias`` (ties
+    to the lower index), the weights are the chosen experts' own scores over
+    their sum plus ``eps``, times ``scaling``.
+
+    ``score_func="softmax"``: ``s = softmax(x W)`` over all experts, the top-k
+    of ``s`` (ties to the lower index), the weights ``s`` of the chosen over
+    their sum, times ``scaling``.  The softmax is monotone in the logit, so the
+    top-k is taken of the logits, where it cannot tie on a rounding of ``exp``,
+    and ``s[sel] / sum(s[sel])`` is the softmax of the ``k`` chosen logits; no
+    ``eps`` (the sum is at least ``k / num_experts``) and no ``bias``."""
     with jax.named_scope("router"):
-        scores = jax.nn.sigmoid(jnp.dot(x.astype(F32), w_router.astype(F32),
-                                        precision=lax.Precision.HIGHEST))
+        logits = jnp.dot(x.astype(F32), w_router.astype(F32), precision=lax.Precision.HIGHEST)
+        if score_func == "softmax":
+            if bias is not None:
+                raise ValueError("a softmax router takes no selection bias")
+            _, experts = lax.top_k(logits, k)
+            weights = jax.nn.softmax(jnp.take_along_axis(logits, experts, axis=-1), axis=-1) * scaling
+            return experts.astype(jnp.int32), weights
+        if score_func != "sigmoid":
+            raise ValueError(f"a router of score_func sigmoid or softmax, not {score_func!r}")
+        scores = jax.nn.sigmoid(logits)
         _, experts = lax.top_k(scores + bias.astype(F32), k)
         picked = jnp.take_along_axis(scores, experts, axis=-1)
         weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps) * scaling
@@ -280,21 +303,21 @@ def share_capacity(pairs: int, held: int, num_experts: int) -> int:
 
 
 def routed_experts(x, w_router, bias, w13, w2, *, k: int, first: int = 0, scaling: float = 1.0,
-                   eps: float = 1e-6, compute_dtype=jnp.bfloat16) -> Routed:
+                   eps: float = 1e-6, score_func: str = "sigmoid", compute_dtype=jnp.bfloat16) -> Routed:
     """The routed layer on ``x`` ``[B, T, d]`` float32 (already normed).
 
-    ``w_router`` ``[d, num_experts]``, ``bias`` ``[num_experts]``; ``w13``
+    ``w_router`` ``[d, num_experts]``, ``bias`` ``[num_experts]`` (None with a softmax router); ``w13``
     ``[held, d, 2f]`` holds each held expert's gate (first ``f`` columns) and
     up projection side by side, ``w2`` ``[held, f, d]`` its down projection:
-    an expert is ``w2(silu(gate x) * up x)``.  ``eps`` is the router's
-    (:func:`route`)."""
+    an expert is ``w2(silu(gate x) * up x)``.  ``eps`` and ``score_func`` are
+    the router's (:func:`route`)."""
     b, t, d = x.shape
     held, f = w2.shape[0], w2.shape[1]
     num_experts = w_router.shape[1]
     if not 0 <= first <= num_experts - held:
         raise ValueError(f"experts [{first}, {first + held}) are not among the router's {num_experts}")
     tokens = x.reshape(b * t, d)
-    experts, weights = route(tokens, w_router, bias, k=k, scaling=scaling, eps=eps)
+    experts, weights = route(tokens, w_router, bias, k=k, scaling=scaling, eps=eps, score_func=score_func)
 
     with jax.named_scope("dispatch"):
         # Pair p is slot p % k of token p // k.  Pairs on an expert held
@@ -330,7 +353,10 @@ def _whole_layer(tokens, order, group_sizes, weights, w13, w2, k, compute_dtype)
         back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
         back = back.reshape(tokens.shape[0], k)
         # Slot by slot: one ``[B T, k, d]`` gather would be re-tiled for its sublane of k.
-        return sum(y[back[:, j]] * weights[:, j, None] for j in range(k))
+        if k * tokens.size * 4 <= COMBINE_UNROLLED_BYTES:
+            return sum(y[back[:, j]] * weights[:, j, None] for j in range(k))
+        return lax.fori_loop(0, k, lambda j, out: out + y[back[:, j]] * weights[:, j, None],
+                             jnp.zeros(tokens.shape, F32))
 
 
 def _share_of_layer(tokens, order, group_sizes, weights, w13, w2, k, capacity, compute_dtype):

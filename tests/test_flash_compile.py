@@ -56,6 +56,10 @@ _SHAPES = {
     # tiles of 2,048 keys a q block) and the full layer's triangle (four tiles of 8,192)
     "trinity-window-4096-48on8-32768": ((1, 32768, 48, 128), 32768, 8, "bfloat16", True, False, None, 0, False, 4096),
     "trinity-full-48on8-32768": ((1, 32768, 48, 128), 32768, 8, "bfloat16", True, False),
+    # Mellum 2's two calls at 32,768 positions, 32 query heads on 4: the band of 1,024 (three tiles of 512
+    # keys a q block) and the full layer's triangle
+    "mellum-window-1024-32on4-32768": ((1, 32768, 32, 128), 32768, 4, "bfloat16", True, False, None, 0, False, 1024),
+    "mellum-full-32on4-32768": ((1, 32768, 32, 128), 32768, 4, "bfloat16", True, False),
     "window-not-a-multiple-of-the-chunk-float32-lse": ((1, 4096, 4, 128), 4096, 2, "float32", True, True, None, 0, False, 1000),
     "float32-4096": ((1, 4096, 2, 128), 4096, 2, "float32", True, True),
     # TestTileableBlocks' lengths: no multiple of 128, no multiple of 8, mixed
@@ -103,6 +107,7 @@ _GATED = {
     "kimi-row-tile-512": (4096, 7168, 2048, 12, 512),
     "lfm2-half-the-rows": (32768, 2048, 1792, 32, 256),
     "rows-no-multiple-of-the-tile": (1000, 512, 384, 3, 256),
+    "mellum-262144-rows-64-experts-f896": (262144, 2304, 896, 64, 512),  # gate and up in tiles of 128
 }
 
 
@@ -121,6 +126,17 @@ def test_the_gated_grouped_product_compiles_for_v5e_under_the_name_gmm(one_chip,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert re.search(rf"^\s*(ROOT )?%gmm(\.\d+)? = bf16\[{padded},{f}\]", text, re.M)
     assert f"[{m},{2 * f}]" not in text and f"[{padded},{2 * f}]" not in text
+
+
+def test_the_w2_product_at_2304_output_columns_compiles_for_v5e_under_the_name_gmm(one_chip, monkeypatch):
+    """Mellum 2's second product: 2,304 columns out are 4.5 of its 512-column tiles, the last hanging over
+    the edge; f = 896 under a contraction tile of 2,048."""
+    described = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel chooses to be interpreted off the TPU
+    text = jax.jit(lambda rows, w2, sizes: moe.grouped_matmul(rows, w2, sizes)).lower(
+        described((262144, 896)), described((64, 896, 2304)), described((64,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"^\s*(ROOT )?%gmm(\.\d+)? = f32\[262144,2304\]", text, re.M)
 
 
 def _nested_convolutions(text):
