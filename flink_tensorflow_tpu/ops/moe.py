@@ -35,8 +35,10 @@ A layer's two grouped products are two Pallas kernels.  The first
 algorithm with two accumulators: it reads the gate and the up half of ``w13``
 where they are stored, and writes ``silu(gate) * up`` in ``compute_dtype``
 ``[rows, f]``, computed in float32 on its accumulators and rounded once.  The
-second (:func:`grouped_matmul`: that against ``W2``) is ``megablox.gmm`` itself
-at tiles of 512 rows x 2,048 x 512, float32 out.  So what crosses HBM between
+second (:func:`grouped_matmul`: that against ``W2``) is ``megablox.gmm`` itself,
+float32 out.  Both take their tiles from :func:`grouped_tiles`, whose
+contraction and column tiles divide the operands: no grid step multiplies the
+zeros of a remainder.  So what crosses HBM between
 the two is the bfloat16 ``[rows, f]`` the second one reads, and the float32
 ``[rows, 2f]`` is no tensor of the program (until PR 40 the first product was
 ``gmm`` too, wrote it, and an XLA pass read it back for ``silu * up``: 470 MB
@@ -79,9 +81,15 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
 
+# Mosaic's scoped VMEM default: the grouped kernels' tiles fit under it, so that neither names ``vmem_limit_bytes``
+# (a call that names one, even the default, makes XLA set that much aside for the whole program: PERF.md 6).
+from flink_tensorflow_tpu.ops.flash_attention import _VMEM_DEFAULT
+
 F32 = jnp.float32
-#: The grouped kernel's tiles: rows (shrunk for fewer rows), contraction, columns.
-TILE_ROWS, TILE_K, TILE_N = 512, 2048, 512
+#: The grouped kernels' row tile (shrunk for fewer rows), and the work of a grid step in columns: the ``W2``
+#: product's least column tile, twice the gated product's most (gate and up each).  The contraction tile has
+#: no constant: :func:`grouped_tiles` reads it off the shapes.
+TILE_ROWS, TILE_N = 512, 512
 _LANES = 128
 #: A share's buffers hold this many times the rows an even routing sends it.
 CAPACITY_SLACK = 2
@@ -153,15 +161,22 @@ def grouped_matmul(rows, stacked, group_sizes, *, compute_dtype=jnp.bfloat16, ti
     """``rows[group g] @ stacked[g]`` for contiguous groups of ``rows`` ``[M,
     K]``; ``stacked`` ``[G, K, N]``, ``group_sizes`` int32 ``[G]``; float32
     out.  Rows past the last group are not computed and hold anything.  Off
-    the TPU the kernel runs interpreted: the same code, as the flash kernel's."""
+    the TPU the kernel runs interpreted: the same code, as the flash kernel's.
+
+    ``megablox.gmm`` at the ``W2`` tile of :func:`grouped_tiles` (a layer's
+    ``W2`` product is ``[M, f] @ [f, d]``, so ``K`` is ``f`` and ``N`` is
+    ``d``): contraction and column tiles that divide ``K`` and ``N``.  At a
+    fixed 2,048 x 512, Mellum 2's ``f`` of 896 was one contraction step of
+    2,048 with 56% of both blocks masked to zero, and its ``d`` of 2,304 was
+    4.5 column tiles: 2.75 TFLOP of MXU work a layer for 1.08 (PERF.md 5)."""
     if jnp.dtype(compute_dtype) == F32:
         return lax.ragged_dot(rows.astype(F32), stacked.astype(F32), group_sizes,
                               precision=lax.Precision.HIGHEST, preferred_element_type=F32)
-    m = rows.shape[0]
-    tile = _row_tile(m, tile_rows)
-    padded = jnp.pad(rows.astype(compute_dtype), ((0, -m % tile), (0, 0)))
+    m, k = rows.shape
+    _, tile = grouped_tiles(m, stacked.shape[2], k, tile_rows)
+    padded = jnp.pad(rows.astype(compute_dtype), ((0, -m % tile[0]), (0, 0)))
     out = gmm(padded, stacked.astype(compute_dtype), group_sizes, preferred_element_type=F32,
-              tiling=(tile, TILE_K, TILE_N), interpret=jax.default_backend() != "tpu")
+              tiling=tile, interpret=jax.default_backend() != "tpu")
     return out[:m]
 
 
@@ -178,18 +193,18 @@ def gated_grouped_matmul(rows, w13, group_sizes, *, compute_dtype=jnp.bfloat16, 
     accumulates both products in float32 in VMEM and, on the last contraction
     step, writes ``silu(gate) * up`` computed in float32 on the accumulators and
     rounded ONCE, so the float32 ``[M, 2f]`` between a layer's two products is
-    no tensor of the program.  Group metadata, the grid's order, the contraction
-    remainder's mask and the rows' mask of a tile that two groups share are
-    ``gmm``'s; the tile is read off the shapes (:func:`gated_tiles`; ``tile_rows``
-    is :func:`grouped_matmul`'s).  float32 callers get :func:`grouped_matmul`'s
-    ``ragged_dot`` and the XLA ``silu * up``."""
+    no tensor of the program.  Group metadata, the grid's order and the rows'
+    mask of a tile that two groups share are ``gmm``'s; the tile is read off the
+    shapes (:func:`grouped_tiles`; ``tile_rows`` is :func:`grouped_matmul`'s) and
+    divides the contraction, so no step masks a remainder.  float32 callers get
+    :func:`grouped_matmul`'s ``ragged_dot`` and the XLA ``silu * up``."""
     f = w13.shape[2] // 2
     if jnp.dtype(compute_dtype) == F32:
         both = grouped_matmul(rows, w13, group_sizes, compute_dtype=compute_dtype)
         return jax.nn.silu(both[:, :f]) * both[:, f:]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    tile = gated_tiles(rows.shape[0], rows.shape[1], f, tile_rows)
+    tile, _ = grouped_tiles(rows.shape[0], rows.shape[1], f, tile_rows)
     return _gated_call(rows, w13, group_sizes, jnp.dtype(compute_dtype), tile, interpret)
 
 
@@ -203,23 +218,20 @@ def _gated_call(rows, w13, group_sizes, compute_dtype, tile, interpret):
     if not interpret and tn % _LANES:
         raise ValueError(f"gate and up of {f} columns cannot be blocked out of [d, 2f] in tiles of {tn}: "
                          f"compiled, a tile is whole lane tiles of {_LANES}")
+    if d % tk or f % tn:
+        raise ValueError(f"tiles of {tk} x {tn} leave a remainder of [{d}, {f}]: the kernel computes none")
     padded = jnp.pad(rows.astype(compute_dtype), ((0, -m % tm), (0, 0)))
     (offsets, group_ids, m_tile_ids), active_tiles = make_group_metadata(
         group_sizes=group_sizes, m=padded.shape[0], tm=tm, start_group=jnp.int32(0),
         num_nonzero_groups=w13.shape[0], visit_empty_groups=False)
-    tiles_k, k_rem = -(-d // tk), d % tk
+    tiles_k = d // tk
 
     def kernel(offsets, group_ids, m_tile_ids, lhs, gate, up, out, *accs):
         visit, k_i = pl.program_id(1), pl.program_id(2)
 
-        def whole(x, axis, last):  # the contraction's remainder reads past the operand: zeros there
-            if not (last and k_rem):
-                return x
-            return jnp.where(lax.broadcasted_iota(jnp.int32, x.shape, axis) < k_rem, x.astype(F32), 0).astype(x.dtype)
-
         def step(last):
-            x = whole(lhs[...], 1, last)
-            parts = [lax.dot_general(x, whole(w[...], 0, last), (((1,), (0,)), ((), ())), preferred_element_type=F32)
+            x = lhs[...]
+            parts = [lax.dot_general(x, w[...], (((1,), (0,)), ((), ())), preferred_element_type=F32)
                      for w in (gate, up)]
             if accs:
                 for acc, part in zip(accs, parts):
@@ -265,32 +277,64 @@ def _gated_call(rows, w13, group_sizes, compute_dtype, tile, interpret):
     return call(offsets, group_ids, m_tile_ids, padded, w13, w13)[:m]
 
 
-def gated_tiles(m: int, d: int, f: int, tile_rows: int = TILE_ROWS):
-    """(rows, contraction, columns) of the gated product's tile, read off its shapes.
+def grouped_tiles(m: int, d: int, f: int, tile_rows: int = TILE_ROWS):
+    """The tiles, (rows, contraction, columns) each, of a layer's two grouped
+    kernels, read off its shapes: (the gated product's, ``[m, d]`` against
+    ``[d, 2f]``; the ``W2`` product's, ``[m, f]`` against ``[f, d]``).
 
-    Rows and contraction are the ``W2`` product's kernel's (:func:`_row_tile`,
-    at a share's own ``tile_rows`` where it passes one; ``TILE_K``), so a
-    layer's two kernels visit the same row tiles by the same group metadata.
-    Columns: gate and up in the widest whole lane tiles of at most half
-    ``TILE_N`` each that divide ``f``: the work of that kernel's 512 columns a
-    grid step, in no more VMEM (under 12 MB, within Mosaic's default).
+    No tile leaves a remainder.  A contraction tile that does not divide the
+    contraction makes the last grid step a whole tile's MXU product, with both
+    blocks masked to zero past the edge; a column tile that does not divide the
+    columns computes the last tile's columns past the edge.  At Mellum 2's
+    layer (d 2,304, f 896), at the fixed 2,048 x 512 these kernels had, the
+    gated product did 4,096 / 2,304 of its work and a mask pass besides, the
+    ``W2`` product 2.5 times its work: 387 ms of a 982 ms step at 34% of their
+    floor.  Alone on a v5e at that layer (262,144 rows over 64 experts), the
+    gated product took 33.1 ms at (512, 2,048, 128) and 15.2 at (512, 2,304,
+    128), the ``W2`` product 17.2 at (512, 2,048, 512) and 7.7 at (512, 896,
+    768) with its output the same to the bit (PERF.md 6).
 
-    What the chip showed (PERF.md section 6, PR 40, has the table): at lfm2's
-    layer half the rows and all of ``f`` (256 x 2,048 x 1,792, which has to ask
-    Mosaic for its VMEM) took 3.22 ms alone against 3.85 at the tile chosen,
-    because a group's edge inside a row tile is a second visit of the tile (32
-    groups over 64 tiles of 512 rows are 95 visits' MXU passes) and one
-    contraction tile keeps a group's weights in VMEM over its row tiles; in the
-    cell that was 6 ms a step more, but ``open()`` took 1.8-2.4 s longer (7-9%
-    of ``setup_s``, whose bound is 10%) while the ``W2`` kernel kept 512 rows:
-    each kernel then computes group metadata of its own.  With BOTH products at
-    256 rows ``open()`` stands where it stood and the step reads 145.74 ms for
-    152.65 (PR 40's last chip call, through a wrapper, too late to hand in):
-    ROADMAP S12's next item.  Wider columns at a share's sizes (256 x 2,048 x
-    2,048: 1.87 ms a pass against 2.51) were 1.3 ms of a 252 ms step and 0.7 s
-    of ``open()``: not taken."""
-    tn = max((n for n in range(_LANES, TILE_N // 2 + 1, _LANES) if f % n == 0), default=f)
-    return _row_tile(m, tile_rows), min(TILE_K, d), tn
+    - Rows: :func:`_row_tile`, at a share's own ``tile_rows`` where it passes
+      one, the same for both kernels, so that they visit the same row tiles by
+      the same group metadata (a row tile for one alone cost ``open()`` 1.8-2.4
+      s in LFM2's cell: PERF.md 6, the gated product).
+    - Columns: gate and up in the widest whole lane tiles of at most half
+      ``TILE_N`` each that divide ``f`` (the work of ``TILE_N`` columns a grid
+      step; wider ones at a share's sizes were 1.3 ms of a 252 ms step and 0.7
+      s of ``open()``); ``W2``'s in the narrowest whole lane tiles of at
+      least ``TILE_N`` that divide ``d``.
+    - Contraction: the fewest tiles that divide it, in whole lane tiles, whose
+      VMEM (:func:`_vmem_bytes`) fits under Mosaic's default.  One tile where
+      it fits: a group's weights' block then keeps its index over the group's
+      row tiles and Pallas fetches it once, where a split contraction fetches it
+      again every step (two tiles of 1,152 took Mellum's gated product 20.9
+      ms where one of 2,304 took 15.2)."""
+    tm = _row_tile(m, tile_rows)
+    gate_n = max((n for n in range(_LANES, TILE_N // 2 + 1, _LANES) if f % n == 0), default=f)
+    w2_n = min((n for n in range(TILE_N, d, _LANES) if d % n == 0), default=d)
+    gate_k = _contraction_tile(d, lambda tk: _vmem_bytes(tm, tk, gate_n, weights=2, out_bytes=2,
+                                                          accumulators=2 if tk < d else 0))
+    w2_k = _contraction_tile(f, lambda tk: _vmem_bytes(tm, tk, w2_n, weights=1, out_bytes=4, accumulators=1))
+    return (tm, gate_k, gate_n), (tm, w2_k, w2_n)
+
+
+def _contraction_tile(k: int, vmem) -> int:
+    """The widest tile that divides ``k``, all of it or whole lane tiles, whose
+    ``vmem(tile)`` fits under Mosaic's default; the narrowest where none does."""
+    tiles = [k] + [t for t in range(k - k % _LANES, 0, -_LANES) if t < k and k % t == 0]
+    return next((t for t in tiles if vmem(t) <= _VMEM_DEFAULT), tiles[-1])
+
+
+def _vmem_bytes(tm: int, tk: int, tn: int, *, weights: int, out_bytes: int, accumulators: int) -> int:
+    """What a grouped kernel holds in VMEM at a tile, bfloat16 operands: the
+    rows' block and ``weights`` weight blocks, each twice (the pipeline fetches
+    the next step's while the MXU reads this one's), the output block twice,
+    ``accumulators`` float32 ``[tm, tn]``, and what the body makes: the rows'
+    block loaded once more and a float32 product a weight block.  Compiled for a
+    described v5e at the cells' tiles, Mosaic's own count lies 3-8% under it for
+    the gated kernel and 6-26% under it for ``gmm``."""
+    return (2 * (tm * tk + weights * tk * tn) * 2 + tm * tk * 2 + 2 * tm * tn * out_bytes
+            + (accumulators + weights) * tm * tn * 4)
 
 
 def share_capacity(pairs: int, held: int, num_experts: int) -> int:

@@ -102,7 +102,7 @@ def test_chosen_tile_compiles_for_v5e(one_chip, case):
 #: and at the others a share can take or that were tried on the chip (PERF.md 6, PR 40)
 _GATED = {
     "lfm2-32768-rows-32-experts": (32768, 2048, 1792, 32, 512),
-    "kimi-a-pass-of-4096-rows-12-experts": (4096, 7168, 2048, 12, 256),  # 3.5 contraction tiles
+    "kimi-a-pass-of-4096-rows-12-experts": (4096, 7168, 2048, 12, 256),  # 2 contraction tiles of 3,584
     "kimi-row-tile-128": (4096, 7168, 2048, 12, 128),
     "kimi-row-tile-512": (4096, 7168, 2048, 12, 512),
     "lfm2-half-the-rows": (32768, 2048, 1792, 32, 256),
@@ -129,14 +129,56 @@ def test_the_gated_grouped_product_compiles_for_v5e_under_the_name_gmm(one_chip,
 
 
 def test_the_w2_product_at_2304_output_columns_compiles_for_v5e_under_the_name_gmm(one_chip, monkeypatch):
-    """Mellum 2's second product: 2,304 columns out are 4.5 of its 512-column tiles, the last hanging over
-    the edge; f = 896 under a contraction tile of 2,048."""
+    """Mellum 2's second product: 2,304 columns out in three tiles of 768, f = 896 in one contraction tile:
+    no tile hangs over an edge."""
     described = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel chooses to be interpreted off the TPU
     text = jax.jit(lambda rows, w2, sizes: moe.grouped_matmul(rows, w2, sizes)).lower(
         described((262144, 896)), described((64, 896, 2304)), described((64,), jnp.int32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert re.search(r"^\s*(ROOT )?%gmm(\.\d+)? = f32\[262144,2304\]", text, re.M)
+
+
+#: (rows, d, f, experts held, tile_rows): the four routed cells' layers, at the row tiles they take
+_MOE_CELLS = {
+    "mellum-whole-layer": (262144, 2304, 896, 64, 512),
+    "lfm2-whole-layer": (32768, 2048, 1792, 32, 512),
+    "trinity-a-pass-of-32768-rows-32-experts": (32768, 3072, 3072, 32, 512),
+    "kimi-a-pass-of-4096-rows-12-experts": (4096, 7168, 2048, 12, 256),
+}
+
+
+def _mosaic_params(jaxpr):
+    """The Mosaic compiler parameters of every ``pallas_call`` of a jaxpr, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["compiler_params"]["mosaic_tpu"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _mosaic_params(sub)
+    return found
+
+
+@pytest.mark.parametrize("cell", list(_MOE_CELLS), ids=list(_MOE_CELLS))
+def test_both_grouped_kernels_compile_for_v5e_at_the_cells_tiles_in_mosaics_default_vmem(one_chip, cell, monkeypatch):
+    """The tiles ``moe.grouped_tiles`` reads off each cell's shapes: Mosaic takes both kernels, and neither names
+    ``vmem_limit_bytes`` (a call that names one makes XLA set that much VMEM aside for the whole program)."""
+    m, d, f, held, tile_rows = _MOE_CELLS[cell]
+    described = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels choose to be interpreted off the TPU
+
+    def layer(rows, w13, w2, sizes):
+        hidden = moe.gated_grouped_matmul(rows, w13, sizes, tile_rows=tile_rows)
+        return moe.grouped_matmul(hidden, w2, sizes, tile_rows=tile_rows)
+
+    args = (described((m, d)), described((held, d, 2 * f)), described((held, f, d)), described((held,), jnp.int32))
+    params = _mosaic_params(jax.make_jaxpr(layer)(*args).jaxpr)
+    assert len(params) == 2 and all(p.vmem_limit_bytes is None for p in params)
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    padded = -(-m // tile_rows) * tile_rows
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert re.search(rf"^\s*(ROOT )?%gmm(\.\d+)? = bf16\[{padded},{f}\]", text, re.M)
+    assert re.search(rf"^\s*(ROOT )?%gmm(\.\d+)? = f32\[{padded},{d}\]", text, re.M)
 
 
 def _nested_convolutions(text):

@@ -513,6 +513,12 @@ class TestAutoscaleSupervisor:
                 # A decision demanding more than the parent allows.
                 return [sys.executable, "-S", "-c",
                         _decision_writer_code(path, 99)]
+            if attempt == 0:
+                # The deciding worker's peer, killed by the supervisor: a
+                # peer that exited first with its own code would race the
+                # decision file, and the attempt would read as a failure.
+                return [sys.executable, "-S", "-c",
+                        "import time; time.sleep(60)"]
             return [sys.executable, "-S", "-c",
                     f"import sys; sys.exit(0 if {num_workers} == 3 else 9)"]
 
