@@ -62,10 +62,19 @@ kernel, which reads them once a column tile: 2.24 -> 1.87 ms a pass in
 XLA's ``silu * up``: a kernel's MXU pass would round their operands to
 bfloat16, as ops/flash_attention.py says of its own.
 
+Where every expert is held, each token's ``k`` weighted rows of the ``W2``
+product are summed back at the token.  Up to ``COMBINE_UNROLLED_BYTES`` of
+slots XLA gathers a slot at a time and sums them (LFM2's layer); above it one
+more kernel, :func:`combine_rows`, copies each row by DMA into VMEM and adds it
+there, so every row is read once and the sum written once.  At Mellum 2's layer
+(32,768 tokens, top-8, d = 2,304) on a v5e, alone: 5.6 ms, where the loop over
+the slots it replaced took 22.9, the same to the bit (PERF.md section 6).
+
 Precision: the router's product is float32 at ``Precision.HIGHEST`` (the
 choice is discontinuous: it must not be made on rounded scores), as are the
 sigmoid or the softmax, the selection bias, the top-k and the combine weights; the experts'
-products take ``compute_dtype`` operands and accumulate in float32.
+products take ``compute_dtype`` operands and accumulate in float32; the combine is float32
+throughout, each token's rows weighed and added from slot 0 on.
 """
 
 from __future__ import annotations
@@ -94,9 +103,11 @@ _LANES = 128
 #: A share's buffers hold this many times the rows an even routing sends it.
 CAPACITY_SLACK = 2
 #: Where every expert is held and a layer's ``k`` slots, float32 ``[tokens, d]`` each, would hold more
-#: than this at once, the combine adds them one slot at a time in a loop.  Unrolled, XLA gathers every
-#: slot before the sum: at Mellum 2's layer (32,768 tokens, top-8, d = 2,304: 8 x 302 MB) 8 layers
-#: took 15.4 GiB of scratch compiled for a v5e, over the chip with the weights; the loop leaves 8.0 GB.
+#: than this at once, the combine is one kernel (:func:`combine_rows`) that copies each row it sums
+#: straight into VMEM.  Unrolled, XLA gathers every slot before the sum: at Mellum 2's layer (32,768
+#: tokens, top-8, d = 2,304: 8 x 302 MB) 8 layers took 15.4 GiB of scratch compiled for a v5e, over the
+#: chip with the weights.  A loop over the slots fitted (8.0 GB) and took 22.9 ms a layer on a v5e,
+#: writing each slot's gather out and reading it back beside the sum; the kernel takes 5.6 (PERF.md 6).
 #: No timing sets the number itself: any cutoff between LFM2's 268 MB (unrolled, the program its cell
 #: has always run) and Mellum's 2.4 GB (unrolled, no room) picks the same path for every model here.
 COMBINE_UNROLLED_BYTES = 1 << 30
@@ -385,7 +396,8 @@ def routed_experts(x, w_router, bias, w13, w2, *, k: int, first: int = 0, scalin
 
 def _whole_layer(tokens, order, group_sizes, weights, w13, w2, k, compute_dtype):
     """Every pair falls on a held expert: a row for every pair, in one pass,
-    gathered back to its token slot by slot."""
+    summed back at its token (slot by slot, or by :func:`combine_rows` above
+    ``COMBINE_UNROLLED_BYTES``)."""
     with jax.named_scope("dispatch"):
         rows = tokens.astype(compute_dtype)[order // k]
 
@@ -399,8 +411,103 @@ def _whole_layer(tokens, order, group_sizes, weights, w13, w2, k, compute_dtype)
         # Slot by slot: one ``[B T, k, d]`` gather would be re-tiled for its sublane of k.
         if k * tokens.size * 4 <= COMBINE_UNROLLED_BYTES:
             return sum(y[back[:, j]] * weights[:, j, None] for j in range(k))
-        return lax.fori_loop(0, k, lambda j, out: out + y[back[:, j]] * weights[:, j, None],
-                             jnp.zeros(tokens.shape, F32))
+        return combine_rows(y, back, weights)
+
+
+def combine_rows(y, back, weights, *, interpret: typing.Optional[bool] = None):
+    """A whole layer's combine: float32 ``[tokens, d]`` whose row ``t`` is
+    ``y[back[t, 0]] * weights[t, 0] + ... + y[back[t, k-1]] * weights[t, k-1]``,
+    summed from slot 0 on, for ``y`` float32 ``[rows, d]``, ``back`` int32 and
+    ``weights`` float32 ``[tokens, k]``: the operations, in their order, of a
+    loop that adds one slot's gathered rows at a time.
+
+    One Pallas kernel over blocks of tokens (:func:`_combine_tokens`).  ``y``
+    stays in HBM.  For slot ``j`` the kernel copies each token's row
+    ``y[back[t, j]]`` into VMEM, one DMA a row, and starts slot ``j + 1``'s
+    copies before it waits on slot ``j``'s; it adds the slot into the output
+    block, which is written once.  So each row of ``y`` is read once and the sum
+    written once, where the loop wrote every slot's gather out and read it back
+    beside the sum it read and wrote again.  A row copy may not take one sublane
+    of a tile, so ``y`` is handed over as ``[rows / 8, d / 128, 8, 1, 128]``,
+    the same bytes (a bitcast) in tiles of one row, and the rows land in VMEM
+    the same way; the block is summed as ``[tm / 8, d / 128, 8, 128]`` and
+    written as ``[tm, d]``.  (Written out as the former, XLA's bitcast back to
+    ``[tokens, d]`` changed how it fused the residual adds after the layer:
+    Mellum 2's step held 0.6 GB more scratch on a v5e, PERF.md 6.)  Off the
+    TPU it runs interpreted."""
+    rows, d = y.shape
+    if d % _LANES or rows % 8:
+        raise ValueError(f"rows of [{rows}, {d}] are copied in tiles of one row of {_LANES} lanes, "
+                         f"eight to a tile: {rows} rows of {d} are not")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _combine_call(y, back, weights, _combine_tokens(back.shape[0], d), interpret)
+
+
+def _combine_tokens(tokens: int, d: int) -> int:
+    """Tokens a grid step of :func:`combine_rows`: the most, a power of two,
+    whose VMEM fits under Mosaic's default (two slots' rows, the output block
+    twice, the sum, a slot's weighted rows as the sum makes them); all of fewer
+    tokens in whole sublanes.  256 at Mellum 2's d = 2,304, where 128 took the same time
+    on a v5e (PERF.md 6) and 512 does not fit."""
+    tm = 8
+    while 6 * (2 * tm) * d * 4 <= _VMEM_DEFAULT:
+        tm *= 2
+    return min(tm, -(-tokens // 8) * 8)
+
+
+# Jitted as the grouped kernels are; the block is an argument, so that the trace reads nothing the key does not hold.
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _combine_call(y, back, weights, tm, interpret):
+    n, k = back.shape
+    d = y.shape[1]
+    lanes = d // _LANES
+    blocks = -(-n // tm)
+    # A block's indices in one SMEM row, token-major, padded to whole tiles of XLA's s32 vector layout (1,024).
+    stride = -(-tm * k // 1024) * 1024
+    back = jnp.pad(back, ((0, blocks * tm - n), (0, 0))).reshape(blocks, tm * k)
+    back = jnp.pad(back, ((0, 0), (0, stride - tm * k))).reshape(-1)
+    # Padded tokens copy row 0 and weigh it 0; they are cut off below.
+    weights = jnp.pad(weights, ((0, blocks * tm - n), (0, 0)))
+
+    def kernel(back, w, y, out, buf, acc, sem):
+        def copies(j):  # slot j's rows, eight tokens a loop step: no division, no sublane index computed
+            def eight(g, carry):
+                for u in range(8):
+                    r = back[(g * 8 + u) * k + j]
+                    pltpu.make_async_copy(y.at[lax.shift_right_logical(r, 3), :, lax.bitwise_and(r, 7)],
+                                          buf.at[j % 2, g, :, u], sem.at[j % 2]).start()
+                return carry
+            lax.fori_loop(0, tm // 8, eight, 0)
+
+        copies(0)
+        for j in range(k):
+            if j + 1 < k:
+                copies(j + 1)
+            # One wait for the slot's tm rows: a DMA semaphore counts the bytes that arrived.
+            pltpu.make_async_copy(buf.at[j % 2], buf.at[j % 2], sem.at[j % 2]).wait()
+            term = buf.at[j % 2].reshape(tm // 8, lanes, 8, _LANES)[...] * w[:, j:j + 1].reshape(tm // 8, 1, 8, 1)
+            acc[...] = term if j == 0 else acc[...] + term
+        # [tm / 8, d / 128, 8, 128] holds the block's rows as [tm, d] holds them in tiles of (8, 128)
+        out[...] = acc[...].transpose(0, 2, 1, 3).reshape(tm, d)
+
+    call = pl.pallas_call(
+        kernel,
+        name="combine_rows",  # not "gmm": the grouped products' roofline share finds its kernels by that name
+        out_shape=jax.ShapeDtypeStruct((blocks * tm, d), F32),
+        grid=(blocks,),
+        in_specs=[pl.BlockSpec((stride,), lambda i: (i,), memory_space=pltpu.SMEM),
+                  pl.BlockSpec((tm, k), lambda i: (i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tm, d), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((2, tm // 8, lanes, 8, 1, _LANES), F32),
+                        pltpu.VMEM((tm // 8, lanes, 8, _LANES), F32), pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        cost_estimate=pl.CostEstimate(flops=2 * n * k * d, transcendentals=0,
+                                      bytes_accessed=(n * k * d + n * d) * 4 + n * k * 8),
+        interpret=interpret)
+    rows = y.reshape(-1, 8, lanes, _LANES).transpose(0, 2, 1, 3).reshape(-1, lanes, 8, 1, _LANES)
+    return call(back, weights, rows)[:n]
 
 
 def _share_of_layer(tokens, order, group_sizes, weights, w13, w2, k, capacity, compute_dtype):

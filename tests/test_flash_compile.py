@@ -1,5 +1,5 @@
-"""The flash kernel, the routed experts' gated grouped product and Inception-v3's
-step, compiled for a described TPU v5e (no chip attached): what Mosaic refuses (a block it cannot tile, a slice off the tiling, more VMEM than
+"""The flash kernel, the routed experts' gated grouped product, the whole layer's
+combine and Inception-v3's step, compiled for a described TPU v5e (no chip attached): what Mosaic refuses (a block it cannot tile, a slice off the tiling, more VMEM than
 the call asked for) fails here and not on the chip, and so does a fusion XLA would
 pick that the model is written to avoid.  Nothing runs, so this says nothing of
 results or speed; ``chip_smoke.py`` phase 2 holds the results.
@@ -137,6 +137,24 @@ def test_the_w2_product_at_2304_output_columns_compiles_for_v5e_under_the_name_g
         described((262144, 896)), described((64, 896, 2304)), described((64,), jnp.int32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert re.search(r"^\s*(ROOT )?%gmm(\.\d+)? = f32\[262144,2304\]", text, re.M)
+
+
+def test_the_combine_at_mellums_layer_compiles_for_v5e_and_reads_the_w2_product_as_it_lies(one_chip):
+    """Mellum 2's whole-layer combine: one kernel, named so that the grouped products' pattern (``^%gmm``) does
+    not count it, in Mosaic's default VMEM; the ``W2`` product's ``[262144, 2304]`` reaches it in tiles of one
+    row by a bitcast, with no copy, and it writes ``[32768, 2304]`` itself."""
+    described = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    args = (described((262144, 2304)), described((32768, 8), jnp.int32), described((32768, 8)))
+    combine = lambda y, back, weights: moe.combine_rows(y, back, weights, interpret=False)  # noqa: E731
+    params = _mosaic_params(jax.make_jaxpr(combine)(*args).jaxpr)
+    assert len(params) == 1 and params[0].vmem_limit_bytes is None
+    compiled = jax.jit(combine).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"^\s*ROOT %combine_rows(\.\d+)? = f32\[32768,2304\]\{1,0:T\(8,128\)\}", text, re.M)
+    assert re.search(r"%bitcast(\.\d+)? = f32\[32768,18,8,1,128\]\{[^}]*T\(1,128\)\} bitcast\(%y", text)
+    assert not re.search(r"= f32\[(262144|32768),2304\]\S* (copy|transpose|fusion)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32768 * 2304 * 4  # not one slot's rows
 
 
 #: (rows, d, f, experts held, tile_rows): the four routed cells' layers, at the row tiles they take
