@@ -60,21 +60,6 @@ TIMER_CUT_REASON = (
 )
 
 
-def device_capable_op(op: typing.Optional[Operator]) -> bool:
-    """Whether an operator's function can PRODUCE device-resident batches
-    (its runner elides the fetch when the next chained member consumes
-    them) — the ``device_capable`` marker on model/elementwise device
-    functions."""
-    return bool(getattr(getattr(op, "function", None), "device_capable", False))
-
-
-def accepts_device_op(op: typing.Optional[Operator]) -> bool:
-    """Whether an operator's function CONSUMES DeviceBatch records
-    directly (``accepts_device_batches`` marker)."""
-    return bool(getattr(getattr(op, "function", None),
-                        "accepts_device_batches", False))
-
-
 def sharding_axes_of(function: typing.Any) -> typing.Optional[typing.Tuple[str, ...]]:
     """Mesh axes a function's jitted step shards its batch over, or None
     for host-side (unsharded) functions.
@@ -131,14 +116,6 @@ class ChainPlan:
     #: (upstream id, downstream id) -> reason.  Forward edges only —
     #: keyed/broadcast edges are structurally unchainable and not listed.
     unchained_reasons: typing.Dict[typing.Tuple[int, int], str]
-    #: fused edges that stay HBM-resident at runtime under
-    #: ``JobConfig.device_resident``: (upstream id, downstream id) pairs
-    #: where the upstream member produces DeviceBatches and the fused
-    #: downstream consumes them — the runtime elides the d2h/h2d pair on
-    #: exactly these hops (the ``device-residency`` lint reads this to
-    #: flag chains that force a fetch mid-segment).
-    device_resident_edges: typing.Set[typing.Tuple[int, int]] = dataclasses.field(
-        default_factory=set)
 
     def chain_of(self, t: Transformation) -> typing.List[Transformation]:
         head = self.head_of[t.id]
@@ -155,16 +132,10 @@ class ChainPlan:
         return [[t.name for t in chain] for chain in self.chains]
 
     def format_topology(self) -> str:
-        """Human-readable chain topology for the analysis/inspector CLIs.
-        ``=>`` marks a fused edge that stays HBM-resident under
-        ``device_resident`` mode (``->`` is a host-record hop)."""
+        """Human-readable chain topology for the analysis/inspector CLIs."""
         lines = []
         for chain in self.chains:
-            members = chain[0].name
-            for up, down in zip(chain, chain[1:]):
-                arrow = ("=>" if (up.id, down.id) in self.device_resident_edges
-                         else "->")
-                members += f" {arrow} {down.name}"
+            members = " -> ".join(t.name for t in chain)
             tag = f"x{chain[0].parallelism}"
             fused = f", {len(chain) - 1} fused edge(s)" if len(chain) > 1 else ""
             lines.append(f"chain [{tag}{fused}]: {members}")
@@ -301,15 +272,4 @@ def compute_chains(
         chains.append(chain)
         for member in chain:
             head_of[member.id] = t
-    # Device-resident segment marking: a fused edge stays HBM-resident
-    # when the upstream member produces DeviceBatches and the fused
-    # downstream consumes them — the runtime wires exactly these hops to
-    # skip the d2h/h2d pair (under JobConfig.device_resident).
-    device_edges: typing.Set[typing.Tuple[int, int]] = set()
-    for chain in chains:
-        for up, down in zip(chain, chain[1:]):
-            if (device_capable_op(operators.get(up.id))
-                    and accepts_device_op(operators.get(down.id))):
-                device_edges.add((up.id, down.id))
-    return ChainPlan(chains=chains, head_of=head_of, unchained_reasons=reasons,
-                     device_resident_edges=device_edges)
+    return ChainPlan(chains=chains, head_of=head_of, unchained_reasons=reasons)

@@ -532,95 +532,6 @@ def _legacy_source_timer_chain(ctx: AnalysisContext, emit: Emit) -> None:
         )
 
 
-@rule("device-residency", Severity.WARN)
-def _device_residency(ctx: AnalysisContext, emit: Emit) -> None:
-    """Under ``JobConfig.device_resident`` a chain of device-capable
-    operators (model -> model, model -> elementwise device map) keeps
-    batches HBM-resident: the d2h/h2d pair is elided per fused hop and
-    the fetch is paid once, at the first host-only consumer.  This rule
-    flags plans that silently give that elision back:
-
-    - WARN: a host-only operator sandwiched between two device-capable
-      operators INSIDE one chain (model -> host map -> model) — the
-      mid-segment fetch + re-upload costs the wire twice where reordering
-      the host step past the segment (or making it a DeviceMapFunction)
-      would cost zero;
-    - WARN: a forward edge between two device-capable operators that the
-      chaining pass refused to fuse (parallelism change, escape hatch,
-      fan-out) — the channel is a host boundary, so the segment cuts for
-      a reason the plan could remove;
-    - INFO: a keyed/broadcast/rebalance edge between device-capable
-      operators — the cut is structural (records re-route between
-      subtasks on the host plane), the fetch there is the designed
-      "exactly once" boundary, not a plan smell.
-
-    Skipped entirely when the job config is present and device
-    residency is off (nothing is elided, so nothing is given back)."""
-    from flink_tensorflow_tpu.analysis.chaining import (
-        accepts_device_op,
-        compute_chains,
-        device_capable_op,
-    )
-
-    if ctx.config is not None and not getattr(ctx.config, "device_resident", False):
-        return
-    plan = compute_chains(ctx.graph, operators=ctx.operators)
-    # Host-only sandwich inside one chain.
-    for chain in plan.chains:
-        last_device: typing.Optional[Transformation] = None
-        hosts_between: typing.List[Transformation] = []
-        for t in chain:
-            op = ctx.operators.get(t.id)
-            if device_capable_op(op):
-                if last_device is not None and hosts_between:
-                    names = ", ".join(h.name for h in hosts_between)
-                    emit(
-                        f"host-only operator(s) {names} sandwiched between "
-                        f"device-capable {last_device.name!r} and {t.name!r} "
-                        "force a mid-segment fetch + re-upload — the chain "
-                        "pays the wire twice where an HBM-resident handoff "
-                        "would pay zero; reorder the host step out of the "
-                        "segment or express it as a DeviceMapFunction",
-                        node=hosts_between[0].name,
-                    )
-                last_device = t
-                hosts_between = []
-            elif last_device is not None:
-                hosts_between.append(t)
-    # Unfused edges between device-capable endpoints.  The downstream
-    # side counts whether it consumes DeviceBatches or is merely
-    # device-capable (a model window re-uploads what the upstream just
-    # fetched — the cut costs the wire either way).
-    for t in ctx.order:
-        for e in t.inputs:
-            up_op = ctx.operators.get(e.upstream.id)
-            down_op = ctx.operators.get(t.id)
-            if not device_capable_op(up_op):
-                continue
-            if not (device_capable_op(down_op) or accepts_device_op(down_op)):
-                continue
-            if (e.upstream.id, t.id) in plan.device_resident_edges:
-                continue
-            if isinstance(e.partitioner, ForwardPartitioner):
-                reason = plan.unchained_reasons.get(
-                    (e.upstream.id, t.id), "edge not fused")
-                emit(
-                    f"device-capable edge is not chained ({reason}) — the "
-                    "channel is a host boundary, so the device-resident "
-                    "segment cuts here and the hop pays d2h + h2d",
-                    node=t.name, edge=_edge_str(e, t),
-                )
-            else:
-                emit(
-                    f"{type(e.partitioner).__name__} edge between "
-                    "device-capable operators always cuts the device-"
-                    "resident segment (records re-route on the host "
-                    "plane); the fetch here is the designed host boundary",
-                    node=t.name, edge=_edge_str(e, t),
-                    severity=Severity.INFO,
-                )
-
-
 @rule("remote-edge-buffer-timeout", Severity.WARN)
 def _remote_edge_buffer_timeout(ctx: AnalysisContext, emit: Emit) -> None:
     """Latency-sensitive plan behind a large remote buffer timeout: an

@@ -15,12 +15,10 @@ provenance through the existing ``Diagnostic``/lint registry:
 
 - ``shardcheck-collectives`` (INFO) — psum/all-gather/reduce-scatter/
   ppermute counts per jit unit per step, straight from the jaxpr.
-- ``shardcheck-reshard`` (WARN; ERROR on device-resident chained edges)
-  — an edge whose upstream declares an OUTPUT layout
-  (``output_sharding_axes``) that mismatches the downstream's declared
-  input sharding forces XLA to insert an implicit reshard per batch; on
-  a PR-7 HBM-resident chained edge that reshard defeats the whole
-  h2d-elision the chain exists for.
+- ``shardcheck-reshard`` (WARN) — an edge whose upstream declares an
+  OUTPUT layout (``output_sharding_axes``) that mismatches the
+  downstream's declared input sharding forces XLA to insert an implicit
+  reshard per batch.
 - ``shardcheck-donation`` (WARN) — large batch args not donated through
   the jit boundary (the KV-pool/param-buffer 2x-HBM trap), dead
   donations (donated arg with no shape-matching output to alias), and
@@ -800,17 +798,9 @@ def _reshard_findings(
     ctx: "AnalysisContext", findings: typing.List[Finding],
 ) -> None:
     """Edge-level implicit-reshard audit: upstream declared OUTPUT layout
-    vs downstream declared input sharding, escalated to ERROR on
-    HBM-resident chained edges (where the reshard defeats the h2d
-    elision the chain exists for)."""
-    from flink_tensorflow_tpu.analysis.chaining import (
-        compute_chains,
-        sharding_axes_of,
-    )
+    vs downstream declared input sharding, a WARN on every mismatch."""
+    from flink_tensorflow_tpu.analysis.chaining import sharding_axes_of
 
-    plan = compute_chains(ctx.graph, operators=ctx.operators)
-    resident_on = ctx.config is None or getattr(
-        ctx.config, "device_resident", False)
     for t in ctx.order:
         down_fn = ctx.function_of(t)
         down_in = sharding_axes_of(down_fn)
@@ -825,22 +815,16 @@ def _reshard_findings(
                 up_out = sharding_axes_of(up_fn)
             if up_out is None or tuple(up_out) == tuple(down_in):
                 continue
-            resident = (resident_on
-                        and (e.upstream.id, t.id) in plan.device_resident_edges)
             findings.append(Finding(
                 rule="shardcheck-reshard",
-                severity=Severity.ERROR if resident else Severity.WARN,
+                severity=Severity.WARN,
                 message=(
                     f"upstream emits batches laid out over axes "
                     f"{tuple(up_out)} but this operator's pjit expects "
                     f"{tuple(down_in)} — XLA inserts an implicit reshard "
-                    "(all-to-all traffic) on EVERY batch crossing this edge"
-                    + ("; the edge is an HBM-resident chained hop, so the "
-                       "reshard defeats the h2d elision the chain exists "
-                       "for — align the layouts or cut the chain"
-                       if resident else
-                       "; align the upstream output_sharding_axes with the "
-                       "consumer (or reshard once, upstream)")),
+                    "(all-to-all traffic) on EVERY batch crossing this edge; "
+                    "align the upstream output_sharding_axes with the "
+                    "consumer (or reshard once, upstream)"),
                 node=t.name, edge=edge_name(e.upstream.name, t.name)))
 
 
@@ -966,8 +950,7 @@ def _register_rules() -> None:
     def _shardcheck_reshard(ctx, emit) -> None:
         """Implicit-reshard audit: an edge whose upstream output layout
         mismatches the downstream pjit's declared input sharding makes
-        XLA reshard EVERY batch; ERROR when the edge is an HBM-resident
-        chained hop (the reshard defeats the h2d elision)."""
+        XLA reshard EVERY batch."""
         _emit_family(ctx, emit, "shardcheck-reshard")
 
     @rule("shardcheck-donation", Severity.WARN)

@@ -150,22 +150,12 @@ class JobConfig:
     #: Where flight dumps land; None records in memory only (no disk
     #: write even on crash).  FLINK_TPU_FLIGHT_PATH overrides.
     flight_path: typing.Optional[str] = None
-    #: Device-resident dataflow (tensors/transfer.DeviceBatch): chains
-    #: of device-capable operators (model -> model, model -> elementwise
-    #: device map) hand HBM-resident batches between fused members — the
-    #: d2h fetch is elided until the first host-only consumer (sink,
-    #: keyed shuffle, remote edge) forces it exactly once, so a chained
-    #: hop pays the wire once per direction end to end instead of twice
-    #: per hop.  Off (the default) keeps every result on the host path.
-    #: FLINK_TPU_DEVICE_RESIDENT=1 force-enables; per-operator override
-    #: via ModelMapFunction(device_resident=True/False).
-    device_resident: bool = False
-    #: Compact on-the-wire dtype for float tensors: "bf16"/"f16" halve
-    #: the bytes of every f32 field on BOTH the h2d hop (model runners
-    #: narrow host-side; the declared dtype is restored inside the
-    #: jitted call) and remote TCP frames (tensors/serde.py restores at
-    #: decode); "int8" (absmax-quantized) applies to TCP frames only.
-    #: None/"f32" ships full width.  FLINK_TPU_WIRE_DTYPE overrides.
+    #: Compact dtype for float tensors on the record plane's wire:
+    #: "bf16"/"f16" halve the bytes of every f32 field in remote TCP
+    #: frames and shuffle payloads (tensors/serde.py restores at decode);
+    #: "int8" is absmax-quantized.  None/"f32" ships full width.  Model
+    #: runners always ship their inputs in the declared dtype.
+    #: FLINK_TPU_WIRE_DTYPE overrides.
     #: Accuracy caveats documented in tensors/serde.py.
     wire_dtype: typing.Optional[str] = None
     #: Frame coalescing on the remote record plane (core/shuffle.py,

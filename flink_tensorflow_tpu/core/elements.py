@@ -6,13 +6,7 @@ end-of-partition events flow through the same channels (SURVEY.md §1 L1).
 This module is the TPU-native framework's equivalent: plain Python objects
 on the host-side record plane.  Device data never flows through CHANNELS —
 records crossing a queue, shuffle or checkpoint carry host buffers (numpy);
-only the model operators move them to HBM.  The one exception is fused
-chains: a ``StreamRecord`` passed by direct call inside a chain may carry a
-:class:`~flink_tensorflow_tpu.tensors.transfer.DeviceBatch` (HBM-resident
-micro-batch) between device-capable operators — the runtime's
-``Output``/``ChainedOutput`` materialize it to host records at the first
-host-only boundary, so channels and snapshots still only ever see host
-buffers.
+only the model operators move them to HBM.
 """
 
 from __future__ import annotations

@@ -413,7 +413,6 @@ class StreamExecutionEnvironment:
             chaining=cfg.chaining,
             sanitize=cfg.sanitize,
             sanitize_log_path=cfg.sanitize_log_path,
-            device_resident=cfg.device_resident,
             wire_dtype=cfg.wire_dtype,
             wire_flush_bytes=cfg.wire_flush_bytes,
             wire_flush_ms=cfg.wire_flush_ms,

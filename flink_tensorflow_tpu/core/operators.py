@@ -65,16 +65,6 @@ class Output:
         self._tracer = tracer
 
     def emit(self, value: typing.Any, timestamp: typing.Optional[float] = None) -> None:
-        if getattr(value, "is_device_batch", False):
-            # Channel boundary = host boundary: a keyed shuffle needs
-            # per-record keys, a remote edge needs bytes, a checkpoint
-            # needs picklable elements — this is where a device-resident
-            # segment ends, so the deferred d2h forces HERE, exactly
-            # once, and the batch fans out as per-record host values.
-            ts = timestamp if timestamp is not None else value.timestamp
-            for tv in value.materialize():
-                self.emit(tv, ts)
-            return
         record = el.StreamRecord(value, timestamp)
         tracer = self._tracer
         if tracer is not None:
@@ -336,16 +326,6 @@ class MapOperator(_FunctionOperator):
             def emit(value, _ts):
                 fifo = self._ts_fifo
                 ts = fifo.popleft() if fifo else None
-                if getattr(value, "is_device_batch", False):
-                    # One emission covers num_records inputs: consume
-                    # their timestamps positionally and stamp the batch
-                    # with the OLDEST (a later materialization fans the
-                    # records out under it; watermark flushes still
-                    # precede the watermark, so event time stays safe).
-                    for _ in range(value.num_records - 1):
-                        if fifo:
-                            fifo.popleft()
-                    value.timestamp = ts
                 self.output.emit(value, ts)
 
             self._collector = fn.Collector(emit)
@@ -353,14 +333,8 @@ class MapOperator(_FunctionOperator):
 
     def process_record(self, record):
         if self._async:
-            value = record.value
-            if getattr(value, "is_device_batch", False):
-                # One device batch fans out into num_records results —
-                # keep the positional timestamp FIFO aligned.
-                self._ts_fifo.extend([record.timestamp] * value.num_records)
-            else:
-                self._ts_fifo.append(record.timestamp)
-            self.function.map_async(value, self._collector)
+            self._ts_fifo.append(record.timestamp)
+            self.function.map_async(record.value, self._collector)
         else:
             self.output.emit(self.function.map(record.value), record.timestamp)
 

@@ -104,38 +104,24 @@ class ChainedOutput:
       forwards — the tail's real Output broadcasts to the next chains.
     """
 
-    __slots__ = ("_subtask", "_unit", "_records_out", "_tracer",
-                 "_accepts_device")
+    __slots__ = ("_subtask", "_unit", "_records_out", "_tracer")
 
     def __init__(self, subtask: "_Subtask", unit: _ChainedUnit, records_out,
-                 tracer=None, accepts_device: bool = False):
+                 tracer=None):
         self._subtask = subtask
         self._unit = unit
         self._records_out = records_out  # upstream operator's out-meter
         self._tracer = tracer
-        #: Whether the downstream chained operator consumes DeviceBatch
-        #: records directly (device-resident handoff).  False = this hop
-        #: is a host boundary: a device batch materializes here (the
-        #: deferred d2h forces exactly once) and fans out per record.
-        self._accepts_device = accepts_device
 
     def emit(self, value: typing.Any, timestamp: typing.Optional[float] = None) -> None:
         unit = self._unit
-        n = 1
-        if getattr(value, "is_device_batch", False):
-            if not self._accepts_device:
-                ts = timestamp if timestamp is not None else value.timestamp
-                for tv in value.materialize():
-                    self.emit(tv, ts)
-                return
-            n = value.num_records  # meters stay per-RECORD under fusion
         t0 = time.monotonic()
         unit.operator.process_record_from(0, el.StreamRecord(value, timestamp))
         t1 = time.monotonic()
         unit.latency.update(t1 - t0)
-        unit.records_in.mark(n)
+        unit.records_in.mark()
         if self._records_out is not None:
-            self._records_out.mark(n)
+            self._records_out.mark()
         tracer = self._tracer
         if tracer is not None:
             tctx = tracer.current()
@@ -746,7 +732,6 @@ class LocalExecutor:
         trace_sample_rate: float = 1.0,
         flight_recorder: bool = True,
         flight_path: typing.Optional[str] = None,
-        device_resident: bool = False,
         wire_dtype: typing.Optional[str] = None,
         wire_flush_bytes: typing.Optional[int] = None,
         wire_flush_ms: typing.Optional[float] = None,
@@ -759,20 +744,11 @@ class LocalExecutor:
         from flink_tensorflow_tpu import tracing
         from flink_tensorflow_tpu.core import sanitizer_rt
         from flink_tensorflow_tpu.core.checkpoint import CheckpointCoordinator
-        from flink_tensorflow_tpu.tensors.transfer import (
-            env_device_resident,
-            env_wire_dtype,
-        )
+        from flink_tensorflow_tpu.tensors.transfer import env_wire_dtype
 
         self.graph = graph
-        #: Device-resident dataflow (tensors/transfer.DeviceBatch):
-        #: chains of device-capable operators hand HBM-resident batches
-        #: between fused members, eliding the d2h/h2d pair per hop; the
-        #: first host-only consumer forces the fetch exactly once.
-        #: JobConfig.device_resident or FLINK_TPU_DEVICE_RESIDENT=1.
-        self.device_resident = device_resident or env_device_resident()
-        #: Job-wide compact wire dtype (h2d + remote frames); model
-        #: functions/remote sinks default to it at open().
+        #: Job-wide compact wire dtype of remote frames; remote sinks
+        #: default to it at open().
         #: JobConfig.wire_dtype or FLINK_TPU_WIRE_DTYPE.
         self.wire_dtype = wire_dtype if wire_dtype is not None else env_wire_dtype()
         if self.wire_dtype == "f32":
@@ -1088,19 +1064,9 @@ class LocalExecutor:
                     unit = st.units[k]
                     nxt = st.units[k + 1]
                     grp_k = self.metrics.group(unit.scope)
-                    accepts = getattr(
-                        getattr(nxt.operator, "function", None),
-                        "accepts_device_batches", False)
                     unit.output = ChainedOutput(
                         st, nxt, grp_k.meter("records_out"),
-                        tracer=self.tracer, accepts_device=accepts)
-                    if accepts and self.device_resident:
-                        # Emission hint: this member's function may keep
-                        # its results HBM-resident — the next chained
-                        # operator consumes DeviceBatches directly.
-                        up_fn = getattr(unit.operator, "function", None)
-                        if getattr(up_fn, "device_capable", False):
-                            up_fn._device_chain_hint = True
+                        tracer=self.tracer)
 
                 self._wire_units(st, gates)
         # Register per-edge record-plane gauges after wiring (the gate
@@ -1192,9 +1158,7 @@ class LocalExecutor:
             # happens-before events (frame send/recv, credit grant/spend)
             # through this at open().
             ctx.sanitizer = self.sanitizer
-            # Device-residency hand-off: model functions resolve their
-            # emission mode / h2d wire dtype from these at open().
-            ctx.device_resident = self.device_resident
+            # Record-plane wire dtype (RemoteSink reads it at open()).
             ctx.wire_dtype = self.wire_dtype
             # Remote-plane coalescing defaults (RemoteSink reads these
             # at open() when its own knobs are unset).

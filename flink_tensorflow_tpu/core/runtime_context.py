@@ -68,13 +68,8 @@ class RuntimeContext:
         #: flight ring and, when tracing is on, in the tracer.  None when
         #: both are off.
         self.spans: typing.Optional[typing.Any] = None
-        #: Device-resident dataflow mode (JobConfig.device_resident):
-        #: model functions consult it at open() to decide whether chained
-        #: results stay HBM-resident (DeviceBatch) instead of fetching.
-        self.device_resident: bool = False
         #: Job-wide compact wire dtype ("bf16"/"f16"/"int8"; None = f32):
-        #: model runners narrow their h2d transfers with it, remote sinks
-        #: their TCP frames.
+        #: remote sinks narrow their TCP frames with it.
         self.wire_dtype: typing.Optional[str] = None
         #: Credit-based flow control on the record plane
         #: (JobConfig.flow_control): RemoteSink consults it at open() to

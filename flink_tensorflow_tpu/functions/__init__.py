@@ -2,7 +2,6 @@
 (BASELINE.json:5; SURVEY.md §2 row 7)."""
 
 from flink_tensorflow_tpu.functions.model_function import (
-    DeviceMapFunction,
     GraphMapFunction,
     GraphWindowFunction,
     ModelMapFunction,
@@ -16,7 +15,6 @@ from flink_tensorflow_tpu.functions.training_function import (
 
 __all__ = [
     "CompiledMethodRunner",
-    "DeviceMapFunction",
     "DPTrainWindowFunction",
     "OnlineTrainFunction",
     "GraphMapFunction",

@@ -63,13 +63,6 @@ class _ModelFunctionBase(fn.RichFunction):
     #: jitted, static-shape code (see flink_tensorflow_tpu.analysis).
     is_jit_boundary = True
 
-    #: Device-residency capability markers (analysis/chaining.py +
-    #: executor wiring): this function both PRODUCES device batches (its
-    #: runner can elide the fetch) and CONSUMES them (subclasses feed
-    #: upstream DeviceArrays straight into their jitted call).
-    device_capable = True
-    accepts_device_batches = True
-
     def __init__(
         self,
         model: ModelSource,
@@ -80,9 +73,6 @@ class _ModelFunctionBase(fn.RichFunction):
         warmup_length_bucket: int = 128,
         donate_inputs: bool = False,
         outputs: typing.Optional[typing.Sequence[str]] = None,
-        transfer_lanes: int = 1,
-        device_resident: typing.Optional[bool] = None,
-        wire_dtype: typing.Optional[str] = None,
         sharding_axes: typing.Optional[typing.Sequence[str]] = None,
         output_sharding_axes: typing.Optional[typing.Sequence[str]] = None,
     ):
@@ -104,19 +94,6 @@ class _ModelFunctionBase(fn.RichFunction):
         self._warmup_length_bucket = warmup_length_bucket
         self._donate = donate_inputs
         self._outputs = outputs
-        self._transfer_lanes = transfer_lanes
-        #: Device-resident emission: True forces DeviceBatch output,
-        #: False forces host records, None (default) follows
-        #: JobConfig.device_resident AND the executor's chained-consumer
-        #: hint (emission only pays off when the next chained operator
-        #: actually consumes device batches).
-        self._device_resident = device_resident
-        #: Compact h2d wire dtype ("bf16"/"f16"); None follows
-        #: JobConfig.wire_dtype.
-        self._wire_dtype = wire_dtype
-        #: Set by the executor (core/runtime._wire_units) when the next
-        #: CHAINED operator declares accepts_device_batches.
-        self._device_chain_hint = False
         self.runner: typing.Optional[CompiledMethodRunner] = None
         self._out: typing.Optional[fn.Collector] = None
         self._derived_schema: typing.Any = _UNKNOWN
@@ -271,29 +248,14 @@ class _ModelFunctionBase(fn.RichFunction):
             account = self._spans.account()
             charge = account.read()
         model = _resolve(self._source)
-        wire = (self._wire_dtype if self._wire_dtype is not None
-                else getattr(ctx, "wire_dtype", None))
         self.runner = CompiledMethodRunner(
             model,
             self._method_name,
             policy=self._policy,
             donate_inputs=self._donate,
             output_names=self._outputs,
-            dispatch_lanes=self._transfer_lanes,
-            wire_dtype=wire,
         )
         self.runner.open(ctx)
-        # Device-resident emission: explicit kwarg wins; otherwise the
-        # job-wide mode applies only where the executor marked the next
-        # chained operator as a device-batch consumer (emitting into a
-        # host-only consumer would just move the same fetch onto the
-        # subtask thread and lose the background-fetch overlap).
-        if self._device_resident is not None:
-            self.runner.emit_device_batches = self._device_resident
-        else:
-            self.runner.emit_device_batches = bool(
-                getattr(ctx, "device_resident", False)
-                and self._device_chain_hint)
         # Completed results wake the subtask loop immediately (instead of
         # waiting out the poll interval) when the runtime provides a
         # gate wakeup hook.
@@ -367,7 +329,7 @@ class ModelMapFunction(_ModelFunctionBase, fn.AsyncMapFunction):
             kw["policy"] = BucketPolicy(batch=BucketLadder.up_to(micro_batch))
         super().__init__(model, method, **kw)
         if pipeline_depth is None:
-            pipeline_depth = max(2, 2 * self._transfer_lanes)
+            pipeline_depth = 2
         if pipeline_depth < 1:
             raise ValueError("pipeline_depth must be >= 1")
         self._micro_batch = micro_batch
@@ -386,23 +348,9 @@ class ModelMapFunction(_ModelFunctionBase, fn.AsyncMapFunction):
 
     def map_async(self, value, out: fn.Collector):
         self._out = out
-        if getattr(value, "is_device_batch", False):
-            # HBM-resident handoff from the upstream chained model: the
-            # batch bypasses the host micro-batch buffer entirely and
-            # feeds the jitted call as-is (no d2h upstream, no h2d
-            # here).  Flush the host buffer FIRST so emission order
-            # stays arrival order (the runner collects FIFO).
+        self._buf.append(value)
+        if len(self._buf) >= self._micro_batch:
             self._dispatch_buf()
-            if not self.runner.dispatch_device(value):
-                # Schema-incompatible batch: pay the fetch at this
-                # boundary and take the host path in bucket-sized chunks.
-                records = value.materialize()
-                for i in range(0, len(records), self._micro_batch):
-                    self.runner.dispatch(records[i:i + self._micro_batch])
-        else:
-            self._buf.append(value)
-            if len(self._buf) >= self._micro_batch:
-                self._dispatch_buf()
         self._last_activity = time.monotonic()
         self._emit(self.runner.collect_batches(self._max_in_flight), out)
 
@@ -428,7 +376,7 @@ class ModelMapFunction(_ModelFunctionBase, fn.AsyncMapFunction):
         records)."""
         if self._last_activity is None:
             return None
-        if not self._buf and not (self.runner and self.runner._pending):
+        if not self._buf and not (self.runner and self.runner.in_flight()):
             return None
         base = self._last_activity
         if self._last_poll is not None and self._last_poll > base:
@@ -516,11 +464,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     hides under compute and the device, not the host's chain, sets the
     period.  ``pipeline_depth=2`` keeps one window fewer in flight (a
     fire then waits for window k-1, and the transfer is exposed wherever
-    it is not short against the step).  ``transfer_lanes > 1``
-    additionally overlaps the transfers of in-flight batches on a thread
-    pool (the lever when single-stream transfer bandwidth is the
-    ceiling); ``pipeline_depth`` defaults to ``max(3, 2 *
-    transfer_lanes)`` so the lanes stay fed.  The cost of each window in
+    it is not short against the step).  The cost of each window in
     flight is one window of inputs on the chip and one in the host's
     arena (275 MB each for 1024 Inception-v3 images).  In-flight batches
     are flushed at end of input and before every state snapshot, so
@@ -546,7 +490,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     on the ring path a large window need not wait for its fire to start
     crossing the link.  Where the runner finds it worth while
     (``CompiledMethodRunner.chunk_input``: a fixed batch of 64 MB or more
-    of inputs, no narrowed wire), every ``chunk_rows`` records that have
+    of inputs), every ``chunk_rows`` records that have
     arrived are claimed and put at once, the fire puts what is left (the
     last chunk of a full window; the rest and the padding rows of one
     fired by its timeout) and the jitted call joins the chunks.  Same
@@ -557,13 +501,6 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     put at the fire.
     """
 
-    #: A window operator counts ELEMENTS into its buffer — one
-    #: DeviceBatch would count as one element and skew the window
-    #: semantics, so device batches materialize at the boundary before
-    #: entering a window (this function still PRODUCES device batches
-    #: when chained into a device-capable consumer).
-    accepts_device_batches = False
-
     def __init__(self, model: ModelSource, method: str = "serve", *,
                  pipeline_depth: typing.Optional[int] = None,
                  idle_flush_s: float = 0.05,
@@ -571,7 +508,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
                  ring_capacity: typing.Optional[int] = None, **kw):
         super().__init__(model, method, **kw)
         if pipeline_depth is None:
-            pipeline_depth = max(3, 2 * self._transfer_lanes)
+            pipeline_depth = 3
         if pipeline_depth < 1:
             raise ValueError("pipeline_depth must be >= 1")
         self._max_in_flight = pipeline_depth - 1
@@ -656,7 +593,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
     def _windows_in_flight(self) -> int:
         """Windows dispatched and not yet fetched (what ``pipeline_depth``
         bounds); 0 once closed."""
-        return len(self.runner._pending) if self.runner is not None else 0
+        return self.runner.in_flight() if self.runner is not None else 0
 
     def close(self) -> None:
         # The runner's close collects what is in flight, oldest first, and
@@ -716,7 +653,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         early.device.append(self.runner.put_chunk(views))
         if self._spans is not None:
             self._spans.span(self._track, "early_put", t0, time.monotonic(), {
-                "seq": self.runner._batch_seq + 1, "chunk": len(early.device) - 1,
+                "seq": self.runner.dispatched_seq + 1, "chunk": len(early.device) - 1,
                 "bytes": sum(v.nbytes for v in views.values())})
 
     @staticmethod
@@ -744,9 +681,10 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
                 drained = runner.collect_batches()
                 self._emit(drained, out)
                 if not drained:
-                    if not runner._pending:
+                    in_flight = runner.in_flight()
+                    if not in_flight:
                         return False
-                    self._emit(runner.collect_batches(len(runner._pending) - 1), out)
+                    self._emit(runner.collect_batches(in_flight - 1), out)
                 if self._ring.try_push(tv.fields):
                     return True
         finally:
@@ -786,7 +724,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             self._metrics.timer("ingest_s").update(self_s)
         if spans is not None:
             spans.span(self._track, "fill", t0, now, charged({
-                "seq": self.runner._batch_seq + 1, "records": records,
+                "seq": self.runner.dispatched_seq + 1, "records": records,
                 "self_s": self_s, "park_s": park_s,
                 "ring_wait_s": self._ring_wait_total_s - ring0,
                 "park_n": park_n, "park_over_max_s": park_over,
@@ -800,7 +738,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         if not tokens:
             return list(elements)
         if self.runner is not None and (
-                self.runner._pending or self.runner.has_completed()):
+                self.runner.in_flight() or self.runner.has_completed()):
             # flush() also runs the deferred ring releases of completed
             # batches, so the ring head is the buffer afterwards.
             drained = self.runner.collect_batches(0)
@@ -867,7 +805,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
             cap = policy.fixed_batch or policy.batch.sizes[-1]
             tail = len(elements) % cap
             self._spans.span(self._track, "fire", t_fire, now, charged({
-                "seq": self.runner._batch_seq, "records": len(elements),
+                "seq": self.runner.dispatched_seq, "records": len(elements),
                 "padded": policy.batch_bucket(tail) - tail if tail else 0,
                 "in_flight": self._fire_in_flight,
                 "blocked_s": self.runner.collect_wait_total_s - blocked0,
@@ -877,7 +815,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         """A batch has just been dispatched: note how many are in flight,
         then emit what is ready and block until at most ``pipeline_depth
         - 1`` are still unfetched."""
-        self._fire_in_flight = max(self._fire_in_flight, len(self.runner._pending))
+        self._fire_in_flight = max(self._fire_in_flight, self.runner.in_flight())
         self._emit(self.runner.collect_batches(self._max_in_flight), out)
 
     def _fire_ring(self, tokens, out: fn.Collector) -> int:
@@ -949,7 +887,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         claims the oldest.  Chunks of it already on the device are dropped,
         once they have crossed: the copy crosses again, in the runner's own
         puts."""
-        if self.runner._pending or self.runner.has_completed():
+        if self.runner.in_flight() or self.runner.has_completed():
             self._emit(self.runner.collect_batches(0), out)
         self._await_puts(head.device)
         claims = head.claims
@@ -987,7 +925,7 @@ class ModelWindowFunction(_ModelFunctionBase, fn.WindowFunction):
         # poll rather than one idle_flush interval.
         if self.runner.has_completed():
             return 0.0
-        if not self.runner._pending or self._last_dispatch is None:
+        if not self.runner.in_flight() or self._last_dispatch is None:
             return None
         base = self._last_dispatch
         if self._last_poll is not None and self._last_poll > base:
@@ -1173,60 +1111,3 @@ class GraphWindowFunction(_GraphFunctionBase, fn.WindowFunction):
         for i in range(0, len(elements), self._batch):
             for record in self._run(elements[i:i + self._batch]):
                 out.collect(record)
-
-
-class DeviceMapFunction(fn.MapFunction):
-    """Elementwise device-side map — a HBM-resident link in a chain.
-
-    Wraps a pure ``arrays -> arrays`` callable (dict of ``[B, ...]``
-    batch-major arrays in, dict out) and applies it jitted.  Fed a
-    :class:`~flink_tensorflow_tpu.tensors.transfer.DeviceBatch` (chained
-    behind a device-resident model), the whole batch transforms ON
-    DEVICE and is re-emitted as a DeviceBatch — the hop costs zero wire
-    bytes, so a model -> elementwise -> model chain stays HBM-resident
-    end to end.  Fed plain host records (unchained placement, or device
-    residency off), each record lifts to a batch of one, transforms, and
-    returns to a host ``TensorValue`` — semantics identical, only the
-    residency differs.
-
-    The callable must be replay-pure (jit traces it once); state, I/O
-    and clocks are as illegal here as inside any model method.
-    """
-
-    device_capable = True
-    accepts_device_batches = True
-
-    def __init__(self, array_fn: typing.Callable[[typing.Mapping[str, typing.Any]],
-                                                 typing.Mapping[str, typing.Any]]):
-        self._array_fn = array_fn
-        self._jit = None
-
-    def clone(self) -> "fn.Function":
-        import copy
-
-        dup = copy.copy(self)
-        dup._jit = None
-        return dup
-
-    def open(self, ctx) -> None:
-        import jax
-
-        self._jit = jax.jit(self._array_fn)
-
-    def close(self) -> None:
-        self._jit = None
-
-    def map(self, value):
-        from flink_tensorflow_tpu.tensors.transfer import DeviceBatch
-
-        if isinstance(value, DeviceBatch):
-            return DeviceBatch(self._jit(value.arrays), value.valid,
-                               value.metas, timestamp=value.timestamp,
-                               tracer=value._tracer, track=value._track)
-        if not isinstance(value, TensorValue):
-            raise TypeError(
-                f"DeviceMapFunction maps tensor records, got {type(value).__name__}")
-        lifted = {n: np.asarray(a)[None] for n, a in value.fields.items()}
-        out = self._jit(lifted)
-        return TensorValue({n: np.asarray(a)[0] for n, a in out.items()},
-                           value.meta)

@@ -14,12 +14,6 @@ hot loop).  The TPU-native engine room:
 - dispatch is async: the jitted call returns futures, and ``run_batch``
   only blocks when fetching results — back-to-back windows overlap host
   batching with device compute.
-- ``dispatch_lanes > 1`` runs assemble+transfer+launch on a small thread
-  pool.  Where the host->device transfer is paid synchronously inside
-  the dispatch call, one lane caps throughput at single-stream transfer
-  bandwidth; concurrent lanes overlap the transfers of consecutive
-  micro-batches.  Results are collected in dispatch order regardless of
-  lane completion order.
 - result fetches run on a dedicated **fetch thread** (r5): the d2h
   round trip happens in the background the moment a batch's lane work
   resolves, so the subtask thread only ever drains already-fetched
@@ -28,25 +22,11 @@ hot loop).  The TPU-native engine room:
   guarantee on every transport, so a readiness-gated fetch may block
   anyway.  The fetch thread removes the need for readiness polling
   entirely: a blocking fetch IS the completion signal.
-- **double-buffered transfers** (r6): even at ``dispatch_lanes=1`` the
-  assemble+h2d+launch runs on a small lane pool (2 workers) instead of
-  the subtask thread, so the h2d of batch N+1 overlaps the device
-  compute of batch N AND the subtask thread stays free to accept
-  arrivals — ``lane_wait``/``ready_wait`` stalls shrink to the pool
-  queue.  ``double_buffer=False`` restores the inline single-lane path.
-- **device-resident dataflow** (r6): with ``emit_device_batches`` set
-  (wired by the executor when the next chained operator accepts device
-  batches), the fetch thread does NOT fetch — it waits for compute via
-  ``block_until_ready`` and hands out ONE
-  :class:`~flink_tensorflow_tpu.tensors.transfer.DeviceBatch` whose
-  arrays stay in HBM; the d2h is elided until the first host-only
-  consumer materializes (trace: ``d2h.elided`` instant here, the
-  deferred ``d2h`` span at the boundary).  Symmetrically,
-  ``dispatch_device`` consumes an upstream DeviceBatch with NO h2d
-  (``h2d.elided``), so a model->model chain pays the wire exactly once
-  per direction end to end.  ``wire_dtype`` ("bf16"/"f16") narrows the
-  h2d bytes of batches that DO cross, with the declared dtype restored
-  inside the jitted call (the upcast fuses into the executable).
+- assemble+h2d+launch runs on a lane pool of two workers, never on the
+  subtask thread: the h2d of batch N+1 overlaps the device compute of
+  batch N, and the subtask thread stays free to accept arrivals
+  (``lane_wait`` is the pool's queue).  Every field crosses in its
+  declared dtype.
 - **chunked input** (``chunk_input``): a caller that holds a window's
   records in place while it fills — the ring path of
   ``ModelWindowFunction`` — may ship it ``chunk_rows`` records at a time
@@ -89,6 +69,10 @@ if typing.TYPE_CHECKING:
 EARLY_CHUNK_MIN_BYTES = 16 << 20
 EARLY_MIN_CHUNKS = 4
 EARLY_MAX_CHUNKS = 8
+
+#: Lane workers of a CompiledMethodRunner: two keep the h2d of one batch in
+#: flight while the one before it computes.
+LANES = 2
 
 
 @functools.lru_cache(maxsize=64)
@@ -1077,34 +1061,15 @@ class CompiledMethodRunner:
         device=None,
         donate_inputs: bool = False,
         output_names: typing.Optional[typing.Sequence[str]] = None,
-        dispatch_lanes: int = 1,
-        wire_dtype: typing.Optional[str] = None,
-        double_buffer: bool = True,
     ):
-        if dispatch_lanes < 1:
-            raise ValueError("dispatch_lanes must be >= 1")
         self.model = model
         self.method = model.method(method_name)
         self.policy = policy or BucketPolicy()
         self.device = device
         self.donate_inputs = donate_inputs
-        self.dispatch_lanes = dispatch_lanes
-        #: Compact h2d wire dtype ("bf16"/"f16"); the declared input
-        #: dtype is restored INSIDE the jitted call (fused upcast).
-        from flink_tensorflow_tpu.tensors.serde import normalize_wire_dtype
-
-        self.wire_dtype = normalize_wire_dtype(wire_dtype)
-        #: Run assemble+h2d+launch on a small lane pool even at
-        #: dispatch_lanes=1, so the h2d of batch N+1 overlaps the
-        #: compute of batch N (and the subtask thread never blocks in
-        #: the transfer).  False restores the inline single-lane path.
-        self.double_buffer = double_buffer
-        #: Device-resident emission: results stay in HBM as ONE
-        #: DeviceBatch per micro-batch; the d2h is elided until a
-        #: host-only consumer materializes.  Set post-open by the model
-        #: function when the executor marked the downstream chained
-        #: operator device-capable (or forced via device_resident=True).
-        self.emit_device_batches = False
+        #: The lane pool (LANES workers, built at open): assemble+h2d+launch
+        #: of every batch, so the h2d of batch N+1 overlaps the compute of
+        #: batch N and the subtask thread never blocks in the transfer.
         self._pool: typing.Optional[concurrent.futures.ThreadPoolExecutor] = None
         #: Subset of method outputs to return; selection happens INSIDE the
         #: jitted fn so XLA dead-code-eliminates unused heads and the
@@ -1127,9 +1092,9 @@ class CompiledMethodRunner:
         #: (:meth:`chunk_input`); None: a batch is one put.
         self.chunk_rows: typing.Optional[int] = None
         self._metrics = None
-        #: In-flight dispatched batches: (batch, output futures, t0).
-        #: Appended by the dispatching thread, consumed (FIFO) by the
-        #: fetch thread; guarded by ``_lock``.
+        #: In-flight dispatched batches: the lane futures of
+        #: :meth:`_launch_batch`.  Appended by the dispatching thread,
+        #: consumed (FIFO) by the fetch thread; guarded by ``_lock``.
         self._pending: collections.deque = collections.deque()
         #: Dispatch timestamps of in-flight batches (same order as
         #: ``_pending``) — lets callers age the oldest batch without
@@ -1185,7 +1150,7 @@ class CompiledMethodRunner:
         if device is None and ctx is not None and ctx.device is not None:
             device = ctx.device
         self.device = device
-        self._transfer = DeviceTransfer(device, self.wire_dtype)
+        self._transfer = DeviceTransfer(device)
         # Params to HBM once — the Session-owns-variables analogue.
         t_params = time.monotonic()
         self._params_on_device, placed = _place_params(self.model.params, device)
@@ -1207,20 +1172,12 @@ class CompiledMethodRunner:
         if select is not None:
             select = (*select, *(n for n in self._count_names if n not in select))
         schema = method.input_schema
-        # Device-side dtype restore: fields a narrowed wire (or an
-        # upstream device batch) delivers in a different dtype are cast
-        # back to the schema's declared dtype as the FIRST op of the
-        # jitted call — XLA fuses the upcast, and an already-correct
-        # dtype is a no-op.  Dynamic-length fields keep their pad dtype.
+        # Fields that arrive in another dtype than the schema declares are
+        # cast to it as the FIRST op of the jitted call (XLA fuses it); an
+        # input already in its declared dtype traces to no op at all.
         restore = {n: schema[n].dtype for n in schema.names}
 
-        from flink_tensorflow_tpu.tensors.transfer import is_scale_key, scale_key
-
         def widen(inputs):
-            # Restores the declared dtype as the FIRST (fused) op of the
-            # jitted call; int8-narrowed fields also multiply their
-            # absmax scale back in (the companion __scale__ inputs ride
-            # the same device_put pytree and never reach the model).
             if isinstance(inputs, tuple):
                 # A batch that crossed in chunks (chunk_input), every
                 # record a flat run (DeviceTransfer.put_chunk): shaped and
@@ -1231,17 +1188,8 @@ class CompiledMethodRunner:
                 inputs = {k: jnp.concatenate(
                     [c[k].reshape(-1, *schema[k].shape) for c in inputs], axis=0)
                     for k in inputs[0]}
-            out = {}
-            for k, v in inputs.items():
-                if is_scale_key(k):
-                    continue
-                if k in restore and v.dtype != restore[k]:
-                    v = v.astype(restore[k])
-                    scale = inputs.get(scale_key(k))
-                    if scale is not None:
-                        v = v * scale
-                out[k] = v
-            return out
+            return {k: v.astype(restore[k]) if k in restore and v.dtype != restore[k] else v
+                    for k, v in inputs.items()}
 
         def prune(outputs):
             if select is None:
@@ -1263,15 +1211,9 @@ class CompiledMethodRunner:
         donate = (1,) if self.donate_inputs else ()
         # Pin execution to the subtask's device; params already live there.
         self._jit_fn = jax.jit(call, donate_argnums=donate)
-        lanes = self.dispatch_lanes
-        if lanes == 1 and self.double_buffer:
-            # Double-buffered transfers: two lane workers keep the h2d
-            # of batch N+1 in flight while batch N computes, and the
-            # subtask thread never pays the transfer inline.
-            lanes = 2
-        if lanes > 1 and self._pool is None:
+        if self._pool is None:
             self._pool = concurrent.futures.ThreadPoolExecutor(
-                max_workers=lanes,
+                max_workers=LANES,
                 thread_name_prefix=f"{self.model.name}-dispatch",
             )
         if self._fetcher is None:
@@ -1301,13 +1243,12 @@ class CompiledMethodRunner:
         aligned chunk straddles the arena's end), of EARLY_CHUNK_MIN_BYTES
         or more, and there are EARLY_MIN_CHUNKS to EARLY_MAX_CHUNKS of them
         (the most that fit): 1024 records of 268 KB go in 8 chunks of 128,
-        34 MB a put.  A narrowed wire keeps the batch whole (int8's scale is
-        its absmax over the batch); so does anything smaller.  An arena
+        34 MB a put.  Anything smaller keeps the batch whole.  An arena
         holds records of one static shape, so the schema is static here.
         Call before :meth:`warmup`, which then compiles the chunked
         signature."""
         fixed = self.policy.fixed_batch
-        if fixed is None or self.wire_dtype is not None:
+        if fixed is None:
             return None
         row_bytes = sum(math.prod(spec.shape) * spec.dtype.itemsize
                         for _, spec in self.method.input_schema)
@@ -1403,9 +1344,9 @@ class CompiledMethodRunner:
         if self._fetcher is not None:
             self._fetcher.join(timeout=10.0)
             self._fetcher = None
-        if self._pool is not None:
-            self._pool.shutdown(wait=True, cancel_futures=True)
-            self._pool = None
+        pool, self._pool = self._pool, None
+        if pool is not None:  # closed before it was opened
+            pool.shutdown(wait=True, cancel_futures=True)
         self._params_on_device = None
         self._jit_fn = None
 
@@ -1415,21 +1356,14 @@ class CompiledMethodRunner:
 
         jax dispatch is async: the jitted call returns future-backed
         arrays immediately, so the device crunches this batch while the
-        host assembles the next one.  With ``dispatch_lanes > 1`` the
-        whole assemble+transfer+launch runs on a lane thread, overlapping
-        the wire transfers of consecutive batches.  Results are collected
-        in dispatch order by :meth:`collect_ready` / :meth:`flush`.
+        host assembles the next one.  The whole assemble+transfer+launch
+        runs on a lane thread.  Results are collected in dispatch order by
+        :meth:`collect_ready` / :meth:`flush`.
         """
         if self._jit_fn is None:
             raise RuntimeError("runner not opened")
-        t0 = time.monotonic()
-        self._batch_seq += 1
-        seq = self._batch_seq
-        if self._pool is not None:
-            item = self._pool.submit(self._dispatch_work, list(records), t0, seq)
-        else:
-            item = self._dispatch_work(records, t0, seq)
-        self._enqueue(item, t0)
+        t0, seq = self._next_seq()
+        self._enqueue(self._pool.submit(self._dispatch_work, list(records), t0, seq), t0)
 
     def dispatch_batch(self, batch: Batch, *,
                        assemble_s: typing.Optional[float] = None,
@@ -1455,15 +1389,14 @@ class CompiledMethodRunner:
         """
         if self._jit_fn is None:
             raise RuntimeError("runner not opened")
-        t0 = time.monotonic()
-        self._batch_seq += 1
-        seq = self._batch_seq
-        if self._pool is not None:
-            item = self._pool.submit(
-                self._launch_batch, batch, t0, seq, assemble_s, on_done, chunks, shipped)
-        else:
-            item = self._launch_batch(batch, t0, seq, assemble_s, on_done, chunks, shipped)
-        self._enqueue(item, t0)
+        t0, seq = self._next_seq()
+        self._enqueue(self._pool.submit(
+            self._launch_batch, batch, t0, seq, assemble_s, on_done, chunks, shipped), t0)
+
+    def _next_seq(self) -> typing.Tuple[float, int]:
+        with self._lock:
+            self._batch_seq += 1
+            return time.monotonic(), self._batch_seq
 
     def _enqueue(self, item, t0: float) -> None:
         with self._lock:
@@ -1501,9 +1434,8 @@ class CompiledMethodRunner:
             t_b = time.monotonic()
             if chunks is not None:
                 inputs, h2d_bytes, early_bytes = self._transfer.ship_chunks(shipped, chunks)
-                wire_saved = 0
             else:
-                inputs, h2d_bytes, wire_saved = self._transfer.ship(batch)
+                inputs, h2d_bytes = self._transfer.ship(batch)
                 early_bytes = 0
             if self.method.needs_lengths:
                 lengths = self._transfer.lengths_to_device(batch)
@@ -1529,11 +1461,9 @@ class CompiledMethodRunner:
             # ``device_put`` is asynchronous: this is the enqueue of the
             # transfer and the launch, not the transfer.
             "dispatch_s": t_c - t_b,
-            # Bytes that actually crossed (narrowed when wire_dtype set).
             "h2d_bytes": h2d_bytes,
             # Those of them that crossed before the dispatch (put_chunk).
             "h2d_early_bytes": early_bytes,
-            "wire_saved": wire_saved,
             "tokens": (_real_tokens(batch, self.method.input_schema["tokens"].shape)
                        if self._counts_tokens else None),
             # Span boundaries: t0 -> t_lane_start is lane-pool queueing
@@ -1543,93 +1473,10 @@ class CompiledMethodRunner:
             "t_dispatched": t_c,
         }
         if account is not None:
-            # What this thread (a lane's, or the subtask's own) was charged
-            # between the two stamps: the ``enqueue`` span's cpu_s / runq_s.
+            # What this lane thread was charged between the two stamps:
+            # the ``enqueue`` span's cpu_s / runq_s.
             timings["enqueue_charge"] = charged({}, charge, account.read())
         return batch, outputs, timings, on_done
-
-    # -- device-resident input (HBM-resident chained handoff) -------------
-    def can_accept_device(self, dbatch) -> bool:
-        """Whether an upstream :class:`DeviceBatch` can feed this runner's
-        jitted call directly: every schema field present among the batch
-        arrays with matching trailing (static) shape.  Dtype mismatches
-        are fine — the jitted call casts to the declared dtype as its
-        first fused op.  Methods taking per-record lengths stay on the
-        host path (the lengths side input is host bookkeeping)."""
-        if self.method.needs_lengths:
-            return False
-        schema = self.method.input_schema
-        for name in schema.names:
-            arr = dbatch.arrays.get(name)
-            if arr is None:
-                return False
-            spec_shape = schema[name].shape
-            got = tuple(arr.shape[1:])
-            if len(got) != len(spec_shape):
-                return False
-            for d, g in zip(spec_shape, got):
-                if d is not None and d != g:
-                    return False
-        return True
-
-    def dispatch_device(self, dbatch) -> bool:
-        """Launch an upstream DeviceBatch WITHOUT a host round trip: the
-        h2d transfer is elided (arrays are already HBM-resident) and the
-        jitted call consumes them directly.  Returns False when the batch
-        is not schema-compatible — the caller falls back to
-        ``materialize()`` + the host dispatch path.
-
-        The consumer takes ownership of the batch's arrays (with
-        ``donate_inputs=True`` XLA may reuse their pages); do not
-        materialize a DeviceBatch after handing it here.
-        """
-        if self._jit_fn is None:
-            raise RuntimeError("runner not opened")
-        if not self.can_accept_device(dbatch):
-            return False
-        t0 = time.monotonic()
-        self._batch_seq += 1
-        seq = self._batch_seq
-        if self._pool is not None:
-            item = self._pool.submit(self._launch_device, dbatch, t0, seq)
-        else:
-            item = self._launch_device(dbatch, t0, seq)
-        self._enqueue(item, t0)
-        return True
-
-    def _launch_device(self, dbatch, t0: float, seq: int):
-        import jax
-
-        from flink_tensorflow_tpu.tensors.batching import Batch
-
-        schema = self.method.input_schema
-        with annotate_batch(f"{self.model.name}.{self.method.name}", seq):
-            t_b = time.monotonic()
-            inputs = {n: dbatch.arrays[n] for n in schema.names}
-            outputs = self._jit_fn(self._params_on_device, inputs)
-            for leaf in jax.tree.leaves(outputs):
-                if hasattr(leaf, "copy_to_host_async"):
-                    try:
-                        leaf.copy_to_host_async()
-                    except Exception:  # noqa: BLE001 - optional fast path
-                        break
-            t_c = time.monotonic()
-        # Bookkeeping shell: unbatch only needs valid/metas, and the
-        # h2d row is honest — zero bytes crossed for this batch.
-        shell = Batch(arrays={}, valid=dbatch.valid, lengths={},
-                      metas=dbatch.metas)
-        timings = {
-            "t0": t0,
-            "seq": seq,
-            "assemble_s": None,
-            "dispatch_s": t_c - t_b,
-            "h2d_bytes": 0,
-            "wire_saved": 0,
-            "h2d_elided": True,
-            "t_lane_start": t_b,
-            "t_dispatched": t_c,
-        }
-        return shell, outputs, timings, None
 
     # -- background fetch ---------------------------------------------------
     def _fetch_loop(self) -> None:
@@ -1667,9 +1514,8 @@ class CompiledMethodRunner:
                 except Exception:  # noqa: BLE001 - wakeup is best-effort
                     pass
 
-    def _process_item(self, item):
-        if isinstance(item, concurrent.futures.Future):
-            item = item.result()  # re-raises lane-thread failures here
+    def _process_item(self, future: concurrent.futures.Future):
+        item = future.result()  # re-raises lane-thread failures here
         # Stamped AFTER the lane future resolves: the fetch thread can
         # reach this batch while its lane is still enqueueing, so the
         # stamp never precedes t_dispatched.
@@ -1678,9 +1524,6 @@ class CompiledMethodRunner:
         reached = account.read() if account is not None else None
         t_fetch_start = time.monotonic()
         batch, outputs, timings, on_done = item
-        if self.emit_device_batches:
-            return self._complete_device(
-                batch, outputs, timings, on_done, t_fetch_start, reached)
         host = DeviceTransfer.fetch(outputs)  # blocks on this batch only
         t_done = time.monotonic()
         fetched = account.read() if account is not None else None
@@ -1706,17 +1549,13 @@ class CompiledMethodRunner:
             self._metrics.histogram("record_latency_s").record(dt / max(1, len(results)))
             self._metrics.timer("unbatch_s").update(t_unbatched - t_done)
             if timings["assemble_s"] is not None:
-                # The list path's stacking copy.  Ring views and device
-                # arrays were never assembled (None) and record nothing:
+                # The list path's stacking copy.  Ring views were never
+                # assembled (None) and record nothing:
                 # their per-record cost is the ``fill`` span's.
                 self._metrics.histogram("assemble_s").record(timings["assemble_s"])
             self._metrics.histogram("dispatch_s").record(timings["dispatch_s"])
             self._metrics.counter("h2d_bytes").inc(timings["h2d_bytes"])
-            self._metrics.counter("h2d_early_bytes").inc(
-                timings.get("h2d_early_bytes", 0))
-            if timings.get("wire_saved"):
-                self._metrics.counter("wire_bytes_saved").inc(
-                    timings["wire_saved"])
+            self._metrics.counter("h2d_early_bytes").inc(timings["h2d_early_bytes"])
             self._metrics.counter("batches").inc()
             self._count(timings)
             self._metrics.counter("padded_records").inc(batch.padded_size - batch.num_records)
@@ -1735,32 +1574,24 @@ class CompiledMethodRunner:
         """One batch's spans off the subtask thread, written by the fetch
         thread from the stamps the lane left: the boundaries t0 ->
         t_lane_start -> t_dispatched -> t_done tile the batch's service
-        time.  A batch fed by an upstream DeviceBatch records NO enqueue
-        span — the elision shows as an ``h2d.elided`` instant (the CI
-        guard greps for exactly this shape: zero transfers between fused
-        model ops).  ``fetch_charge``: what the fetch thread was charged
+        time.  ``fetch_charge``: what the fetch thread was charged
         from ``t_fetch_start`` to ``t_done`` (``fetch_cpu_s`` /
         ``fetch_runq_s``: ``in_flight`` starts on another thread, so they
         are not the whole span's)."""
         spans, track, seq = self._spans, self._trace_track, timings["seq"]
         # ``tokens``: the batch's real positions, where the method takes tokens.
         tokens = {} if timings.get("tokens") is None else {"tokens": timings["tokens"]}
-        if self._pool is not None:
-            # On the list path the lane assembles before it launches:
-            # ``assemble_s`` is a part of this span (None on the ring path).
-            spans.span(track, "lane_wait", timings["t0"],
-                       timings["t_lane_start"],
-                       {"seq": seq, "batch": n,
-                        "assemble_s": timings["assemble_s"]})
-        if timings.get("h2d_elided"):
-            spans.instant(track, "h2d.elided", timings["t_lane_start"],
-                          {"seq": seq, "batch": n})
-        else:
-            spans.span(track, "enqueue", timings["t_lane_start"],
-                       timings["t_dispatched"],
-                       {"seq": seq, "bytes": timings["h2d_bytes"],
-                        "early_bytes": timings["h2d_early_bytes"], "batch": n, **tokens,
-                        **timings.get("enqueue_charge", {})})
+        # On the list path the lane assembles before it launches:
+        # ``assemble_s`` is a part of this span (None on the ring path).
+        spans.span(track, "lane_wait", timings["t0"],
+                   timings["t_lane_start"],
+                   {"seq": seq, "batch": n,
+                    "assemble_s": timings["assemble_s"]})
+        spans.span(track, "enqueue", timings["t_lane_start"],
+                   timings["t_dispatched"],
+                   {"seq": seq, "bytes": timings["h2d_bytes"],
+                    "early_bytes": timings["h2d_early_bytes"], "batch": n, **tokens,
+                    **timings.get("enqueue_charge", {})})
         # Launched .. results on the host.  Where the fetch thread stood
         # when it reached the batch is an accident of its schedule, so it
         # is a number here and no longer a cut between two spans.
@@ -1788,65 +1619,6 @@ class CompiledMethodRunner:
         for name, value in timings["counts"].items():
             self._metrics.counter(name).inc(value)
 
-    def _complete_device(self, batch, outputs, timings, on_done,
-                         t_fetch_start: float, reached=None):
-        """Device-resident completion: wait for COMPUTE (not transfer) —
-        ``block_until_ready`` is the pipeline-depth barrier the fetch
-        used to provide — then hand out one HBM-resident DeviceBatch.
-        The d2h is elided here; it lands (once) wherever the first
-        host-only consumer materializes."""
-        import jax
-
-        from flink_tensorflow_tpu.tensors.transfer import DeviceBatch
-
-        jax.block_until_ready(outputs)
-        t_done = time.monotonic()
-        spans = self._spans
-        fetched = spans.account().read() if spans is not None else None
-        outputs = dict(outputs)
-        timings["counts"] = self._take_counts(outputs, batch.valid)
-        n = batch.num_records
-        dt = t_done - timings["t0"]
-        self.service_ewma_s = (
-            dt if self.service_ewma_s is None
-            else 0.75 * self.service_ewma_s + 0.25 * dt
-        )
-        if spans is not None:
-            # In flight to t_done (block_until_ready IS the barrier); the
-            # d2h.elided instant is what the attribution table and the
-            # CI guard read as "no fetch happened here".
-            self._batch_spans(timings, t_fetch_start, t_done, n,
-                              charged({}, reached, fetched, "fetch_"))
-            spans.instant(self._trace_track, "d2h.elided", t_done,
-                          {"seq": timings["seq"], "batch": n})
-        if self._metrics is not None:
-            self._metrics.meter("records").mark(n)
-            self._metrics.histogram("batch_latency_s").record(dt)
-            self._metrics.histogram("record_latency_s").record(dt / max(1, n))
-            if timings["assemble_s"] is not None:  # as in _process_item
-                self._metrics.histogram("assemble_s").record(timings["assemble_s"])
-            self._metrics.histogram("dispatch_s").record(timings["dispatch_s"])
-            self._metrics.counter("h2d_bytes").inc(timings["h2d_bytes"])
-            self._metrics.counter("h2d_early_bytes").inc(
-                timings.get("h2d_early_bytes", 0))
-            if timings.get("wire_saved"):
-                self._metrics.counter("wire_bytes_saved").inc(
-                    timings["wire_saved"])
-            self._metrics.counter("fetch_elided_batches").inc()
-            self._metrics.counter("batches").inc()
-            self._count(timings)
-            self._metrics.counter("padded_records").inc(
-                batch.padded_size - batch.num_records)
-        if self._roofline is not None:
-            # block_until_ready IS the compute barrier on this path.
-            self._roofline.observe(
-                self.method.name, t_done - timings["t_dispatched"],
-                signature=f"b{batch.padded_size}",
-                h2d_bytes=timings["h2d_bytes"])
-        dbatch = DeviceBatch(outputs, batch.valid, batch.metas,
-                             tracer=spans, track=self._trace_track)
-        return [dbatch], on_done, timings["seq"], time.monotonic()
-
     def _consume(self, entry) -> typing.Tuple[int, typing.List[TensorValue]]:
         """Collect one completed entry on the calling (subtask) thread:
         re-raise fetch-thread failures, run the deferred ring release.
@@ -1868,6 +1640,19 @@ class CompiledMethodRunner:
     def has_completed(self) -> bool:
         """True when fetched results are waiting to be collected."""
         return bool(self._completed)
+
+    def in_flight(self) -> int:
+        """Batches dispatched and not yet fetched (what a caller's pipeline
+        depth bounds)."""
+        with self._lock:
+            return len(self._pending)
+
+    @property
+    def dispatched_seq(self) -> int:
+        """``seq`` of the newest dispatched batch (0 before the first): the
+        next dispatch gets ``dispatched_seq + 1``."""
+        with self._lock:
+            return self._batch_seq
 
     def collect_ready(self, max_in_flight: int = 1) -> typing.List[TensorValue]:
         """Drain completed batches until <= ``max_in_flight`` remain in
@@ -1921,31 +1706,15 @@ class CompiledMethodRunner:
                              {"seq": self.collected_seq, "in_flight": in_flight,
                               **spent})
 
-    def collect_available(self) -> typing.List[TensorValue]:
-        """Drain every batch the fetch thread has already completed —
-        never blocks on in-flight compute or transfer.  This is the
-        open-loop latency lever: the subtask thread emits results the
-        moment they land instead of parking in a full ``flush`` for the
-        whole device round trip (which turns the operator into a
-        blocking M/D/1 server and queues every later window behind the
-        wire — round 3's unexplained 536ms p50)."""
-        return _flat(self.collect_batches())
-
-    def collect_progress(self, max_in_flight: int) -> typing.List[TensorValue]:
-        """Opportunistic collection on the hot path: everything already
-        READY (non-blocking), then block only as far as the pipeline-
-        depth bound requires.  Keeps emission latency at one arrival
-        interval instead of one pipeline drain without sacrificing the
-        depth backpressure."""
-        return _flat(self.collect_batches(max_in_flight))
-
     def collect_batches(self, max_in_flight: typing.Optional[int] = None
                         ) -> typing.List[tuple]:
-        """What the three calls above collect, batch by batch, as ``[(seq,
-        results)]`` in dispatch order: everything already ready
-        (:meth:`collect_available`), then, with ``max_in_flight``, blocking
-        down to that bound (:meth:`collect_progress`; 0 is a flush).  For a
-        caller that emits a batch at a time under the batch's ``seq``."""
+        """Collected batches as ``[(seq, results)]`` in dispatch order:
+        every batch the fetch thread has already completed, never blocking
+        on in-flight work (the subtask thread emits results the moment they
+        land, instead of parking in a full flush for the whole device round
+        trip), then, with ``max_in_flight``, blocking until at most that
+        many are in flight (0 is a flush).  For a caller that emits a batch
+        at a time under the batch's ``seq``."""
         out: typing.List[tuple] = []
         while True:
             with self._lock:

@@ -1,7 +1,7 @@
 """KV-cache blocks and the keyed-state facade that owns them.
 
 One session's cache is a ``[L, C, H, Dh]`` K/V pair plus its valid
-length.  Two residency forms exist, mirroring PR 7's DeviceBatch split:
+length.  Two residency forms exist:
 
 - :class:`KVBlock` — host numpy, picklable: the form that lives in
   checkpoints.  A barrier snapshot converts every resident cache to
@@ -10,10 +10,8 @@ length.  Two residency forms exist, mirroring PR 7's DeviceBatch split:
 - :class:`DeviceKVBlock` — live jax arrays: the form a PREEMPTED
   session's cache keeps between eviction and re-admission when the
   serving config runs device-resident.  Moving a block out of the pool
-  and back in then never touches the host — this closes PR 7's "a
-  DeviceBatch entering a stateful operator counts as one opaque
-  element" deferral for the serving step loop.  Like DeviceBatch it
-  refuses to pickle: a checkpoint crossing is a host boundary, and the
+  and back in then never touches the host.  It refuses to pickle: a
+  checkpoint crossing is a host boundary, and the
   operator's snapshot hook converts first (loudly keeping the
   invariant if some future path forgets).
 
@@ -64,7 +62,7 @@ class DeviceKVBlock:
     — the slice copies device-to-device out of the pool, no d2h — and
     consumed by re-admission (device-to-device scatter back, no h2d).
     ``to_host()`` is the explicit materialization boundary (barrier
-    snapshots call it); pickling raises, same contract as DeviceBatch.
+    snapshots call it); pickling raises.
     """
 
     __slots__ = ("k", "v", "length")

@@ -19,14 +19,13 @@ from flink_tensorflow_tpu.tensors.schema import (
     check_compatible,
     spec,
 )
-from flink_tensorflow_tpu.tensors.transfer import DeviceBatch, DeviceTransfer
+from flink_tensorflow_tpu.tensors.transfer import DeviceTransfer
 from flink_tensorflow_tpu.tensors.value import TensorValue
 
 __all__ = [
     "Batch",
     "BucketLadder",
     "BucketPolicy",
-    "DeviceBatch",
     "DeviceTransfer",
     "RecordSchema",
     "SchemaMismatch",

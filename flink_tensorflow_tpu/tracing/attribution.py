@@ -18,15 +18,9 @@ batch's end-to-end path:
 
 Other spans (``process``, ``emit``, ``fire``, ``collect_wait``,
 ``lane_wait``, ``align``, ``snapshot``, ``split.read``, ...) are
-aggregated too and listed after the canonical block.  Device-resident
-elisions (``h2d.elided`` / ``d2h.elided`` instants — batches whose
-transfer never happened because the chain kept them HBM-resident) appear
-as count-only rows, so a model->model chain's table shows ONE enqueue
-row of real spans on the first model, the deferred ``materialize`` where
-the host first needs the values, and the matching elision counts on the
-other side.  Pure
-functions over event tuples — unit-testable with synthetic data, no
-runtime required.
+aggregated too and listed after the canonical block.  Pure functions
+over event tuples — unit-testable with synthetic data, no runtime
+required.
 """
 
 from __future__ import annotations
@@ -61,19 +55,10 @@ def attribution(events: typing.Iterable[tuple]) -> typing.Dict[str, typing.Dict[
     """``{operator: {stage: {count, p50_ms, p95_ms, p99_ms, total_ms}}}``
     over the tracer's ``(track, name, ph, t0, dur, args)`` events."""
     samples: typing.Dict[str, typing.Dict[str, typing.List[float]]] = {}
-    elisions: typing.Dict[str, typing.Dict[str, int]] = {}
     for track, name, ph, _t0, dur, _args in events:
         op = _operator_of(track)
-        if op is None:
-            continue
-        if ph == "X":
+        if op is not None and ph == "X":
             samples.setdefault(op, {}).setdefault(name, []).append(dur * 1e3)
-        elif ph == "i" and name.endswith(".elided"):
-            # Device-resident elision markers: transfers that never
-            # happened have no duration — count them so the table shows
-            # the elision next to the real h2d/d2h rows.
-            per_op = elisions.setdefault(op, {})
-            per_op[name] = per_op.get(name, 0) + 1
     out: typing.Dict[str, typing.Dict[str, Row]] = {}
     for op, stages in samples.items():
         rows: typing.Dict[str, Row] = {}
@@ -87,11 +72,6 @@ def attribution(events: typing.Iterable[tuple]) -> typing.Dict[str, typing.Dict[
                 "total_ms": round(sum(vals), 3),
             }
         out[op] = rows
-    for op, names in elisions.items():
-        rows = out.setdefault(op, {})
-        for name, count in names.items():
-            rows[name] = {"count": count, "p50_ms": 0.0, "p95_ms": 0.0,
-                          "p99_ms": 0.0, "total_ms": 0.0}
     return out
 
 

@@ -765,114 +765,6 @@ class TestShardingAxisLint:
                    and d.edge == edge_name("up", "down") for d in warns)
 
 
-# ---------------------------------------------------------------------------
-# device-residency lint (ISSUE 7): chain-forces-fetch matrix
-# ---------------------------------------------------------------------------
-
-
-def _res_model(dim=4):
-    """Tiny model whose output schema equals its input schema, so
-    model->model chains are device-batch compatible."""
-    import jax.numpy as jnp
-
-    from flink_tensorflow_tpu.models.base import Model, ModelMethod
-
-    schema = RecordSchema({"x": spec((dim,))})
-
-    def serve(params, inputs):
-        return {"x": inputs["x"] * params["w"]}
-
-    return Model("resmlp", {"w": jnp.ones((dim,), jnp.float32)},
-                 {"serve": ModelMethod("serve", schema, ("x",), serve)})
-
-
-class TestDeviceResidencyLint:
-    def _records(self, dim=4):
-        from flink_tensorflow_tpu.tensors import TensorValue
-
-        return [TensorValue({"x": np.zeros(dim, np.float32)}, {"k": 0})]
-
-    def test_model_model_fused_is_clean_and_marked(self):
-        from flink_tensorflow_tpu.analysis.chaining import compute_chains
-        from flink_tensorflow_tpu.functions import ModelMapFunction
-
-        model = _res_model()
-        env = StreamExecutionEnvironment()
-        (env.from_collection(self._records())
-            .map(ModelMapFunction(model, micro_batch=2), name="m1")
-            .map(ModelMapFunction(model, micro_batch=2), name="m2")
-            .sink_to_list())
-        assert by_rule(analyze(env.graph), "device-residency") == []
-        plan = compute_chains(env.graph)
-        by_name = {t.name: t.id for c in plan.chains for t in c}
-        assert (by_name["m1"], by_name["m2"]) in plan.device_resident_edges
-
-    def test_host_map_sandwich_warns_mid_segment_fetch(self):
-        from flink_tensorflow_tpu.functions import ModelMapFunction
-
-        model = _res_model()
-        env = StreamExecutionEnvironment()
-        (env.from_collection(self._records())
-            .map(ModelMapFunction(model, micro_batch=2), name="m1")
-            .map(_IdMap(), name="hostmap")
-            .map(ModelMapFunction(model, micro_batch=2), name="m2")
-            .sink_to_list())
-        diags = by_rule(analyze(env.graph), "device-residency")
-        warns = [d for d in diags if d.severity == Severity.WARN]
-        assert any(d.node == "hostmap" and "mid-segment fetch" in d.message
-                   for d in warns)
-
-    def test_keyed_edge_cut_is_structural_info(self):
-        from flink_tensorflow_tpu.functions import (
-            ModelMapFunction,
-            ModelWindowFunction,
-        )
-
-        model = _res_model()
-        env = StreamExecutionEnvironment()
-        (env.from_collection(self._records())
-            .map(ModelMapFunction(model, micro_batch=2), name="m1")
-            .key_by(lambda r: r.meta.get("k", 0))
-            .count_window(2)
-            .apply(ModelWindowFunction(model,
-                                       policy=BucketPolicy(fixed_batch=2)),
-                   name="m2")
-            .sink_to_list())
-        diags = by_rule(analyze(env.graph), "device-residency")
-        assert diags and all(d.severity == Severity.INFO for d in diags)
-        assert any("host boundary" in d.message or "cuts" in d.message
-                   for d in diags)
-
-    def test_unfused_forward_edge_between_models_warns(self):
-        from flink_tensorflow_tpu.functions import ModelMapFunction
-
-        model = _res_model()
-        env = StreamExecutionEnvironment()
-        (env.from_collection(self._records())
-            .map(ModelMapFunction(model, micro_batch=2), name="m1")
-            .map(ModelMapFunction(model, micro_batch=2), name="m2")
-            .start_new_chain()
-            .sink_to_list())
-        diags = by_rule(analyze(env.graph), "device-residency")
-        assert any(d.severity == Severity.WARN
-                   and d.edge == edge_name("m1", "m2") for d in diags)
-
-    def test_rule_skipped_when_config_disables_residency(self):
-        from flink_tensorflow_tpu.functions import ModelMapFunction
-
-        model = _res_model()
-        env = StreamExecutionEnvironment()  # device_resident defaults off
-        (env.from_collection(self._records())
-            .map(ModelMapFunction(model, micro_batch=2), name="m1")
-            .map(_IdMap(), name="hostmap")
-            .map(ModelMapFunction(model, micro_batch=2), name="m2")
-            .sink_to_list())
-        assert by_rule(analyze(env.graph, config=env.config),
-                       "device-residency") == []
-        on = env.configure(device_resident=True).config
-        assert by_rule(analyze(env.graph, config=on), "device-residency") != []
-
-
 class TestExactlyOnceBoundaryLint:
     """exactly-once-boundary: restartable plan behind a non-replayable
     (TCP) source is at-least-once — the documented io/remote.py hole."""

@@ -181,14 +181,14 @@ class TestWindowsInFlight:
             self._fire(f, images[0:2], out)
             self._fire(f, images[2:4], out)
             assert got == []
-            assert len(f.runner._pending) == 2
+            assert f.runner.in_flight() == 2
             assert reg.report()["model.0.windows_in_flight"] == 2
             # The barrier's flush waits for both, however long they take.
             threading.Timer(0.2, held_fetch.set).start()
             assert f.snapshot_state() is None
             assert [r.meta["i"] for r in got] == [0, 1, 2, 3]
             assert [int(r["label"]) for r in got] == expected_labels[:4]
-            assert not f.runner._pending and not f.runner.has_completed()
+            assert not f.runner.in_flight() and not f.runner.has_completed()
             assert reg.report()["model.0.windows_in_flight"] == 0
         finally:
             held_fetch.set()
@@ -296,7 +296,7 @@ def _shape_model(shape, dtype, name="shapes"):
 
 class TestEarlyShippingRule:
     """Whether a window crosses the link in chunks is decided at ``open()``
-    from the batch, the schema, the wire and the arena: no argument names it."""
+    from the batch, the schema and the arena: no argument names it."""
 
     @pytest.mark.parametrize("shape, dtype, fixed, arena, kw, rows", [
         # The flagship: 1024 x 268 KB, an arena of 8192 slots: 8 chunks of 34 MB.
@@ -312,10 +312,10 @@ class TestEarlyShippingRule:
         pytest.param((299, 299, 3), np.uint8, 1000, 8192, {}, None, id="batch_no_chunk_divides"),
         # Two records of int32[4096] (the language-model cell): 32 KB a window.
         pytest.param((4096,), np.int32, 2, 16, {}, None, id="two_int32_4096"),
-        pytest.param((299, 299, 3), np.float32, 1024, 8192, {"wire_dtype": "bf16"}, None,
-                     id="narrowed_wire"),
-        pytest.param((299, 299, 3), np.float32, 1024, 8192, {"wire_dtype": "int8"}, None,
-                     id="int8_wire_scale_is_per_batch"),
+        # Full-width float32 inputs chunk like any others: 137 MB chunks in 8,
+        # and at 64 records 8.6 MB would be under the floor, so 4 of 17 MB.
+        pytest.param((299, 299, 3), np.float32, 1024, 8192, {}, 128, id="float32_1024"),
+        pytest.param((299, 299, 3), np.float32, 64, 512, {}, 16, id="float32_64_in_4"),
         pytest.param((299, 299, 3), np.uint8, None, 8192, {}, None, id="no_fixed_batch"),
     ])
     def test_chunk_rows(self, shape, dtype, fixed, arena, kw, rows):
@@ -418,10 +418,10 @@ class TestEarlyShippingRule:
         enqueues = [e[5] for e in events if e[1] == "enqueue"]
         assert [a["early_bytes"] * 8 for a in enqueues] == [a["bytes"] * 7 for a in enqueues]
 
-    @pytest.mark.parametrize("case", ["two_int32_4096", "list_path", "wire_dtype"])
+    @pytest.mark.parametrize("case", ["two_int32_4096", "list_path"])
     def test_anything_else_crosses_whole_in_one_put(self, lenet_model, monkeypatch, case):
-        """A window of two ``int32[4096]`` records, the list path, a narrowed
-        wire: one whole put a window, as before, and the counter reads 0."""
+        """A window of two ``int32[4096]`` records, the list path: one whole
+        put a window, as before, and the counter reads 0."""
         from flink_tensorflow_tpu.functions import runner as runner_module
 
         seen = self._puts(monkeypatch)
@@ -433,14 +433,14 @@ class TestEarlyShippingRule:
             kw = {}
         else:
             # Lenet's windows with the floor lowered to their size: only the
-            # list path, or the wire, keeps them whole.
+            # list path keeps them whole.
             monkeypatch.setattr(runner_module, "EARLY_CHUNK_MIN_BYTES", 1 << 10)
             window = 32
             model = lenet_model
             rng = np.random.RandomState(5)
             records = [TensorValue({"image": rng.rand(28, 28, 1).astype(np.float32)}, {"i": i})
                        for i in range(3 * window)]
-            kw = {"use_ring": False} if case == "list_path" else {"wire_dtype": "bf16"}
+            kw = {"use_ring": False}
         results, metrics = self._served(
             ModelWindowFunction(model, policy=BucketPolicy(fixed_batch=window), **kw),
             records, window)
@@ -472,8 +472,7 @@ class TestStageTiling:
         from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
 
         r = CompiledMethodRunner(
-            model, policy=BucketPolicy(batch=BucketLadder.up_to(4)),
-            dispatch_lanes=2)
+            model, policy=BucketPolicy(batch=BucketLadder.up_to(4)))
         r.open(None)
         try:
             r.warmup([1, 2, 4])
@@ -504,7 +503,7 @@ class TestStageTiling:
             r.close()
 
 
-def _lenet_runner(**kw):
+def _lenet_runner(warmup=(1, 2, 4, 8)):
     import jax
 
     from flink_tensorflow_tpu.functions.runner import CompiledMethodRunner
@@ -514,10 +513,15 @@ def _lenet_runner(**kw):
     mdef = get_model_def("lenet", num_classes=10)
     model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
     r = CompiledMethodRunner(
-        model, policy=BucketPolicy(batch=BucketLadder.up_to(8)), **kw)
+        model, policy=BucketPolicy(batch=BucketLadder.up_to(8)))
     r.open(None)
-    r.warmup([1, 2, 4, 8])
+    r.warmup(warmup)
     return r
+
+
+def _collected(runner, max_in_flight=None):
+    """``runner.collect_batches(max_in_flight)`` as one list of records."""
+    return [r for _, records in runner.collect_batches(max_in_flight) for r in records]
 
 
 def _recs(n):
@@ -537,7 +541,7 @@ class TestBackgroundFetch:
     with NO collect call from the subtask thread."""
 
     def test_results_complete_without_any_collect_call(self):
-        r = _lenet_runner(dispatch_lanes=2)
+        r = _lenet_runner()
         try:
             r.dispatch(_recs(2))
             deadline = time.monotonic() + 10.0
@@ -545,13 +549,13 @@ class TestBackgroundFetch:
             while not r.has_completed() and time.monotonic() < deadline:
                 time.sleep(0.002)
             assert r.has_completed()
-            out = r.collect_available()
+            out = _collected(r)
             assert len(out) == 2
         finally:
             r.close()
 
     def test_on_results_ready_fires_per_completed_batch(self):
-        r = _lenet_runner(dispatch_lanes=1)
+        r = _lenet_runner()
         hits = []
         r.on_results_ready = lambda: hits.append(time.monotonic())
         try:
@@ -561,7 +565,7 @@ class TestBackgroundFetch:
             while len(hits) < 2 and time.monotonic() < deadline:
                 time.sleep(0.002)
             assert len(hits) == 2
-            assert len(r.collect_available()) == 3
+            assert len(_collected(r)) == 3
         finally:
             r.close()
 
@@ -570,7 +574,7 @@ class TestBackgroundFetch:
         runs at COLLECTION (subtask thread), not on the fetch thread."""
         from flink_tensorflow_tpu.tensors.batching import assemble, BucketPolicy
 
-        r = _lenet_runner(dispatch_lanes=1)
+        r = _lenet_runner()
         done_threads = []
         try:
             recs = _recs(2)
@@ -583,7 +587,7 @@ class TestBackgroundFetch:
             while not r.has_completed() and time.monotonic() < deadline:
                 time.sleep(0.002)
             assert not done_threads  # fetched, but release deferred
-            out = r.collect_available()
+            out = _collected(r)
             assert len(out) == 2
             assert done_threads == [threading.main_thread()]
         finally:
@@ -595,7 +599,7 @@ class TestBackgroundFetch:
         batches), and each record still owns its metadata."""
         from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
 
-        r = _lenet_runner(dispatch_lanes=1)
+        r = _lenet_runner()
         ring = FlightRecorder()
         r._spans, r._trace_track = SpanHook(ring), "lenet.0"
         try:
@@ -625,7 +629,7 @@ class TestBackgroundFetch:
         model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
         svc = ModelWindowFunction(
             model, policy=BucketPolicy(batch=BucketLadder.up_to(8)),
-            warmup_batches=(2,), transfer_lanes=2, pipeline_depth=8,
+            warmup_batches=(2,), pipeline_depth=8,
             idle_flush_s=30.0)  # poll interval alone would strand results
         emitted = []
         out = fn.Collector(lambda v, ts=None: emitted.append(v))
@@ -659,8 +663,7 @@ class TestBackgroundFetch:
         # An idle deadline that cannot pass by itself: under loaded
         # workers the poll below may outlast any short one, and the
         # completion wake would then find the flush due as well.
-        f = ModelMapFunction(model, micro_batch=8, idle_flush_s=600.0,
-                             transfer_lanes=1)
+        f = ModelMapFunction(model, micro_batch=8, idle_flush_s=600.0)
         emitted = []
         out = fn.Collector(lambda v, ts=None: emitted.append(v))
         f.open(None)
@@ -688,7 +691,7 @@ class TestBackgroundFetch:
 
     def test_fetch_thread_stress_fifo_and_completeness(self):
         """Concurrency shakeout for the fetch-thread path: many small
-        batches through both lane modes with a mixed, randomly-timed
+        batches through the lane pool with a mixed, randomly-timed
         collect pattern (available/ready/progress/defer) must deliver
         every record exactly once, in dispatch order, with nothing left
         pending — and close() must not deadlock regardless of where the
@@ -696,32 +699,31 @@ class TestBackgroundFetch:
         import random
 
         rng = random.Random(7)
-        for lanes in (1, 3):
-            r = _lenet_runner(dispatch_lanes=lanes)
-            try:
-                total = 120
-                recs = _recs(total)
-                out = []
-                i = 0
-                while i < total:
-                    n = rng.choice((1, 2, 3))
-                    r.dispatch(recs[i:i + n])
-                    i += n
-                    mode = rng.random()
-                    if mode < 0.35:
-                        out.extend(r.collect_available())
-                    elif mode < 0.6:
-                        out.extend(r.collect_ready(rng.choice((1, 2, 4))))
-                    elif mode < 0.8:
-                        out.extend(r.collect_progress(rng.choice((1, 2, 4))))
-                    # else: defer — let batches pile up for later modes
-                    if rng.random() < 0.2:
-                        time.sleep(0.002)
-                out.extend(r.flush())
-                assert [v.meta["id"] for v in out] == list(range(total))
-                assert not r._pending and not r.has_completed()
-            finally:
-                r.close()
+        r = _lenet_runner()
+        try:
+            total = 120
+            recs = _recs(total)
+            out = []
+            i = 0
+            while i < total:
+                n = rng.choice((1, 2, 3))
+                r.dispatch(recs[i:i + n])
+                i += n
+                mode = rng.random()
+                if mode < 0.35:
+                    out.extend(_collected(r))
+                elif mode < 0.6:
+                    out.extend(r.collect_ready(rng.choice((1, 2, 4))))
+                elif mode < 0.8:
+                    out.extend(_collected(r, rng.choice((1, 2, 4))))
+                # else: defer — let batches pile up for later modes
+                if rng.random() < 0.2:
+                    time.sleep(0.002)
+            out.extend(r.flush())
+            assert [v.meta["id"] for v in out] == list(range(total))
+            assert r.in_flight() == 0 and not r.has_completed()
+        finally:
+            r.close()
 
     def test_gate_wake_breaks_poll_sleep(self):
         """InputGate.wake() returns a blocked poll immediately, losing
@@ -739,3 +741,257 @@ class TestBackgroundFetch:
         gate.put(0, el.StreamRecord("x"))
         idx, element = gate.poll(timeout=1.0)
         assert idx == 0 and element.value == "x"
+
+
+DIM = 8
+
+
+def _res_model(dim=DIM, name="resmlp"):
+    """A model whose output schema is its input schema, so two of them chain."""
+    from flink_tensorflow_tpu.models.base import Model, ModelMethod
+    from flink_tensorflow_tpu.tensors.schema import RecordSchema, spec
+
+    schema = RecordSchema({"x": spec((dim,))})
+
+    def serve(params, inputs):
+        return {"x": jnp.tanh(inputs["x"] @ params["w"]) + inputs["x"]}
+
+    rng = np.random.RandomState(0)
+    params = {"w": jnp.asarray(rng.randn(dim, dim).astype(np.float32) * 0.1)}
+    return Model(name, params, {"serve": ModelMethod("serve", schema, ("x",), serve)})
+
+
+def _res_records(n, dim=DIM):
+    return [TensorValue({"x": np.full(dim, i, np.float32) / n}, {"id": i})
+            for i in range(n)]
+
+
+def _chain_env(records, trace=False, idle_flush_s=0.005, ckpt_dir=None, throttle=0.0):
+    """``records`` through two chained model maps of four-record micro-batches."""
+    model = _res_model()
+    env = StreamExecutionEnvironment(parallelism=1)
+    env.configure(trace=trace)
+    if ckpt_dir is not None:
+        env.enable_checkpointing(ckpt_dir)
+    env.source_throttle_s = throttle
+    out = (
+        env.from_collection(records)
+        .map(ModelMapFunction(model, micro_batch=4, idle_flush_s=idle_flush_s), name="m1")
+        .map(ModelMapFunction(model, micro_batch=4, idle_flush_s=idle_flush_s), name="m2")
+        .sink_to_list()
+    )
+    return env, out
+
+
+class TestOnePath:
+    """Every batch takes one path: assembled on the host, put and launched on
+    a lane of the runner's two-worker pool, fetched by its fetch thread."""
+
+    def test_every_opened_runner_launches_on_its_two_worker_pool(self):
+        """Every opened runner launches on its two-worker pool, never on the
+        caller's thread."""
+        from flink_tensorflow_tpu.functions import runner as runner_module
+
+        r = _lenet_runner()
+        launched = []
+        launch = r._launch_batch
+
+        def recording(*args):
+            launched.append(threading.current_thread())
+            return launch(*args)
+
+        r._launch_batch = recording
+        try:
+            assert r._pool._max_workers == runner_module.LANES == 2
+            r.run_batch(_recs(3))
+            r.dispatch(_recs(2))
+            r.dispatch(_recs(1))
+            assert len(_collected(r, 0)) == 3
+            assert len(launched) == 3
+            assert threading.current_thread() not in launched
+            assert all(t.name.startswith("lenet-dispatch") for t in launched)
+        finally:
+            r.close()
+        assert r._pool is None
+
+    def test_exactly_one_h2d_and_one_d2h_per_batch(self, monkeypatch):
+        """A traced model -> model chain: each model puts every batch once
+        (one ``enqueue``, its bytes in ``h2d_bytes``) and fetches it once
+        (one ``DeviceTransfer.fetch``, one ``unbatch``)."""
+        from flink_tensorflow_tpu.tensors.transfer import DeviceTransfer
+        from flink_tensorflow_tpu.tracing.attribution import attribution
+
+        fetched = []
+        fetch = DeviceTransfer.fetch
+
+        def counting(outputs):
+            fetched.append(1)
+            return fetch(outputs)
+
+        monkeypatch.setattr(DeviceTransfer, "fetch", staticmethod(counting))
+        # An idle flush longer than the run: every micro-batch is a full one.
+        env, out = _chain_env(_res_records(12), trace=True, idle_flush_s=60.0)
+        handle = env.execute_async()
+        handle.wait(timeout=120)
+        assert [r.meta["id"] for r in out] == list(range(12))
+        events = handle.executor.tracer.events()
+        metrics = handle.executor.metrics.report()
+        batches = 3  # 12 records / micro_batch 4, in each model
+        assert len(fetched) == 2 * batches
+        table = attribution(events)
+        for model in ("m1", "m2"):
+            for stage in ("lane_wait", "enqueue", "in_flight", "unbatch"):
+                assert sum(1 for e in events if e[0] == f"{model}.0" and e[1] == stage
+                           and e[2] == "X") == batches, (model, stage)
+            assert table[model]["enqueue"]["count"] == batches
+            assert metrics[f"{model}.0.h2d_bytes"] == 12 * DIM * 4
+
+    def test_checkpoint_mid_device_segment_is_exactly_once(self, tmp_path):
+        """A barrier that reaches a model -> model chain while batches are in
+        flight on both models: each flushes before it snapshots, and the
+        restored run emits exactly the records after the barrier, with the
+        values of an uninterrupted run."""
+        n = 120
+        recs = _res_records(n)
+        ckpt = str(tmp_path / "ckpts")
+
+        env_ref, ref = _chain_env(recs)
+        env_ref.execute(timeout=120)
+        by_id = {r.meta["id"]: r for r in ref}
+        assert len(by_id) == n
+
+        env1, _ = _chain_env(recs, ckpt_dir=ckpt, throttle=0.002)
+        handle = env1.execute_async()
+        time.sleep(0.25)
+        snaps = handle.trigger_checkpoint(timeout=30)
+        offset = sum(s["operator"]["offset"] for s in snaps["collection"].values())
+        assert 0 < offset < n, f"want a mid-stream barrier, offset={offset}"
+        handle.cancel()
+        handle.wait(timeout=30)
+
+        env2, out2 = _chain_env(recs, ckpt_dir=ckpt)
+        env2.execute(restore_from=ckpt, timeout=120)
+        assert [r.meta["id"] for r in out2] == list(range(offset, n))
+        for r in out2:
+            np.testing.assert_allclose(r["x"], by_id[r.meta["id"]]["x"], rtol=1e-6)
+
+
+def _tokens_model():
+    """A method over padded ``int32`` tokens and their true lengths."""
+    from flink_tensorflow_tpu.models.base import Model, ModelMethod
+    from flink_tensorflow_tpu.tensors.schema import RecordSchema, spec
+
+    schema = RecordSchema({"tokens": spec((None,), np.int32)})
+
+    def serve(params, inputs, lengths):
+        tokens = inputs["tokens"]
+        mask = jnp.arange(tokens.shape[1])[None, :] < lengths["tokens"][:, None]
+        return {"total": jnp.where(mask, params["table"][tokens], 0.0).sum(axis=1)}
+
+    return Model("tokens", {"table": jnp.arange(64, dtype=jnp.float32)},
+                 {"serve": ModelMethod("serve", schema, ("total",), serve,
+                                       needs_lengths=True)})
+
+
+def _two_heads_model():
+    from flink_tensorflow_tpu.models.base import Model, ModelMethod
+    from flink_tensorflow_tpu.tensors.schema import RecordSchema, spec
+
+    schema = RecordSchema({"x": spec((6,), np.float32)})
+
+    def serve(params, inputs):
+        h = inputs["x"] @ params["w"]
+        return {"logits": h, "label": jnp.argmax(h, axis=-1)}
+
+    return Model("heads", {"w": jnp.ones((6, 3), jnp.float32)},
+                 {"serve": ModelMethod("serve", schema, ("logits", "label"), serve)})
+
+
+class TestJittedCall:
+    @pytest.mark.parametrize("case", ["uint8_image", "int32_tokens_with_lengths",
+                                      "output_selected"])
+    def test_the_call_lowers_to_the_methods_own_program(self, case):
+        """On a whole batch in its declared dtypes, the runner's jitted call
+        is ``jax.jit`` of the method's own function with the same output
+        selection: the dtype restore and the chunk join add no op."""
+        from flink_tensorflow_tpu.functions.runner import CompiledMethodRunner
+        from flink_tensorflow_tpu.tensors.batching import BucketLadder, assemble
+
+        outputs = None
+        if case == "uint8_image":
+            model = _shape_model((8, 8, 3), np.uint8)
+            records = [TensorValue({"x": np.full((8, 8, 3), i, np.uint8)}) for i in range(3)]
+        elif case == "int32_tokens_with_lengths":
+            model = _tokens_model()
+            records = [TensorValue({"tokens": np.arange(n, dtype=np.int32)}) for n in (3, 9, 5)]
+        else:
+            model, outputs = _two_heads_model(), ("label",)
+            records = [TensorValue({"x": np.full(6, i, np.float32)}) for i in range(3)]
+        r = CompiledMethodRunner(model, policy=BucketPolicy(batch=BucketLadder.up_to(4)),
+                                 output_names=outputs)
+        r.open(None)
+        try:
+            method = r.method
+            batch = assemble(records, method.input_schema, r.policy)
+            inputs, _ = r._transfer.ship(batch)
+            args = (r._params_on_device, inputs)
+
+            def select(out):
+                return out if outputs is None else {k: out[k] for k in outputs}
+
+            if method.needs_lengths:
+                args += (r._transfer.lengths_to_device(batch),)
+
+                def call(params, inputs, lengths):
+                    return select(method.fn(params, inputs, lengths))
+            else:
+                def call(params, inputs):
+                    return select(method.fn(params, inputs))
+
+            assert r._jit_fn.lower(*args).as_text() == jax.jit(call).lower(*args).as_text()
+        finally:
+            r.close()
+
+
+class TestInFlight:
+    """``in_flight()`` and ``dispatched_seq``: what a window function asks
+    the runner about the batches it has handed over."""
+
+    def test_in_flight_counts_dispatched_batches_until_fetched(self, held_fetch):
+        r = _lenet_runner(warmup=())  # a warm-up would wait on the held fetch
+        try:
+            assert r.in_flight() == 0
+            r.dispatch(_recs(2))
+            r.dispatch(_recs(1))
+            assert r.in_flight() == 2  # the device stands still
+            held_fetch.set()
+            assert len(_collected(r, 1)) == 2
+            assert r.in_flight() <= 1
+            assert len(_collected(r, 0)) == 1
+            assert r.in_flight() == 0 and not r.has_completed()
+        finally:
+            held_fetch.set()
+            r.close()
+
+    def test_dispatched_seq_is_the_seq_of_the_newest_batch(self):
+        """Both dispatch calls number their batch ``dispatched_seq``, which
+        its spans and its collection carry; collecting leaves it as it is."""
+        from flink_tensorflow_tpu.tensors.batching import assemble
+
+        from flink_tensorflow_tpu.tracing.flight import FlightRecorder, SpanHook
+
+        r = _lenet_runner(warmup=())
+        try:
+            ring = FlightRecorder()
+            r._spans, r._trace_track = SpanHook(ring), "lenet.0"
+            assert r.dispatched_seq == 0
+            r.dispatch(_recs(2))
+            assert r.dispatched_seq == 1
+            r.dispatch_batch(assemble(_recs(4), r.method.input_schema, r.policy))
+            assert r.dispatched_seq == 2
+            assert [seq for seq, _ in r.collect_batches(0)] == [1, 2]
+            assert r.dispatched_seq == r.collected_seq == 2
+            enqueued = [e[5]["seq"] for e in ring.events() if e[1] == "enqueue"]
+            assert enqueued == [1, 2]
+        finally:
+            r.close()

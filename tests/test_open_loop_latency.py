@@ -5,8 +5,8 @@ framework defects, each pinned here:
 
 1. Results were only emitted by a BLOCKING flush (idle-flush timer) or
    by the pipeline-depth drain — the subtask thread parked for whole
-   device round trips.  ``CompiledMethodRunner.collect_available`` now
-   fetches exactly the batches whose outputs report ready, never
+   device round trips.  ``CompiledMethodRunner.collect_batches`` now
+   collects exactly the batches the fetch thread has completed, never
    blocking, and ``ModelWindowFunction.fire_due`` polls it.
 2. The adaptive trigger ignored service time: an end-to-end budget was
    spent entirely on holds.  ``observe_service_time`` (fed by
@@ -59,6 +59,11 @@ def _span(ring, name, seq=None):
     return ev[3], ev[3] + ev[4], ev[5]
 
 
+def _collected(runner, max_in_flight=None):
+    """``runner.collect_batches(max_in_flight)`` as one list of records."""
+    return [r for _, records in runner.collect_batches(max_in_flight) for r in records]
+
+
 def _recs(n):
     rng = np.random.RandomState(0)
     return [
@@ -70,29 +75,29 @@ def _recs(n):
 
 class TestCollectAvailable:
     def test_collects_ready_batches_without_blocking(self):
-        r = _lenet_runner(dispatch_lanes=2)
+        r = _lenet_runner()
         try:
             r.dispatch(_recs(2))
             deadline = time.monotonic() + 10.0
             out = []
             while not out and time.monotonic() < deadline:
-                out = r.collect_available()
+                out = _collected(r)
                 time.sleep(0.002)
             assert len(out) == 2
-            assert not r._pending and not r._pending_t0
+            assert r.in_flight() == 0 and r.oldest_pending_age_s() is None
         finally:
             r.close()
 
     def test_returns_empty_when_nothing_pending(self):
-        r = _lenet_runner(dispatch_lanes=1)
+        r = _lenet_runner()
         try:
-            assert r.collect_available() == []
+            assert _collected(r) == []
             assert r.oldest_pending_age_s() is None
         finally:
             r.close()
 
     def test_preserves_fifo_order(self):
-        r = _lenet_runner(dispatch_lanes=2)
+        r = _lenet_runner()
         try:
             recs = _recs(6)
             for i in range(0, 6, 2):
@@ -100,21 +105,21 @@ class TestCollectAvailable:
             deadline = time.monotonic() + 10.0
             out = []
             while len(out) < 6 and time.monotonic() < deadline:
-                out.extend(r.collect_available())
+                out.extend(_collected(r))
                 time.sleep(0.002)
             assert [v.meta["id"] for v in out] == list(range(6))
         finally:
             r.close()
 
     def test_lane_failure_surfaces_through_fetch(self):
-        r = _lenet_runner(dispatch_lanes=2)
+        r = _lenet_runner()
         try:
             bad = TensorValue({"image": np.zeros((7, 7, 1), np.float32)})
             r.dispatch([bad])  # wrong shape: lane raises during assemble
             deadline = time.monotonic() + 10.0
             with pytest.raises(Exception):
                 while time.monotonic() < deadline:
-                    r.collect_available()
+                    _collected(r)
                     time.sleep(0.002)
                 raise AssertionError("lane failure never surfaced")
         finally:
@@ -123,7 +128,7 @@ class TestCollectAvailable:
             r.close()
 
     def test_service_ewma_updates_on_fetch(self):
-        r = _lenet_runner(dispatch_lanes=1)
+        r = _lenet_runner()
         try:
             assert r.service_ewma_s is None
             r.run_batch(_recs(2))
@@ -135,12 +140,12 @@ class TestCollectAvailable:
         """The cuts the per-record stamps carried, read from the batch's
         spans: lane_wait -> enqueue -> in_flight -> unbatch, one of each,
         sharing the batch's seq."""
-        r = _lenet_runner(dispatch_lanes=1)
+        r = _lenet_runner()
         ring = _with_spans(r)
         try:
             out = r.run_batch(_recs(3))
             assert len(out) == 3
-            seq = r._batch_seq
+            seq = r.dispatched_seq
             t0, t_lane_start, lane = _span(ring, "lane_wait", seq)
             _, t_dispatched, enq = _span(ring, "enqueue", seq)
             t_disp, t_done, fly = _span(ring, "in_flight", seq)
@@ -157,7 +162,7 @@ class TestCollectAvailable:
             r.close()
 
     def test_no_hook_records_nothing_and_meta_is_untouched(self):
-        r = _lenet_runner(dispatch_lanes=1)
+        r = _lenet_runner()
         try:
             assert r._spans is None
             out = r.run_batch(_recs(1))
@@ -318,8 +323,7 @@ class TestAsyncMapPolling:
 
         mdef = get_model_def("lenet", num_classes=10)
         model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
-        f = ModelMapFunction(model, micro_batch=8, idle_flush_s=0.005,
-                             transfer_lanes=2)
+        f = ModelMapFunction(model, micro_batch=8, idle_flush_s=0.005)
         emitted = []
         out = fn.Collector(lambda v, ts=None: emitted.append(v))
         f.open(None)
@@ -334,7 +338,7 @@ class TestAsyncMapPolling:
                     time.sleep(max(0.0, min(d - time.monotonic(), 0.01)))
                     f.fire_due(time.monotonic())
             assert len(emitted) == 3
-            assert not f._buf and not f.runner._pending
+            assert not f._buf and not f.runner.in_flight()
         finally:
             f.close()
 
@@ -354,7 +358,7 @@ class TestPollingEmission:
         model = mdef.to_model(jax.jit(mdef.init_fn)(jax.random.key(0)))
         svc = ModelWindowFunction(
             model, policy=BucketPolicy(batch=BucketLadder.up_to(8)),
-            warmup_batches=(1, 2, 4, 8), transfer_lanes=2,
+            warmup_batches=(1, 2, 4, 8),
             pipeline_depth=8, idle_flush_s=0.005)
         emitted = []
         out = fn.Collector(lambda v, ts=None: emitted.append(v))
@@ -370,6 +374,6 @@ class TestPollingEmission:
                     time.sleep(max(0.0, min(d - time.monotonic(), 0.01)))
                     svc.fire_due(time.monotonic())
             assert len(emitted) == 2
-            assert not svc.runner._pending  # drained, not stuck at depth
+            assert not svc.runner.in_flight()  # drained, not stuck at depth
         finally:
             svc.close()

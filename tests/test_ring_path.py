@@ -99,25 +99,24 @@ class TestRingWindowPath:
         finally:
             f.close()
 
-    @pytest.mark.parametrize("lanes, depth", [(1, 3), (2, 4), (3, 6)])
-    def test_default_depth_and_the_ring_sized_with_it(self, lenet_model, lanes, depth):
-        """``pipeline_depth`` defaults to max(3, 2 * transfer_lanes), and
-        the auto-sized arena follows it: (depth + 2) batches of slots, which
+    @pytest.mark.parametrize("pipeline_depth, depth, capacity",
+                             [(None, 3, 32), (2, 2, 16), (4, 4, 32)],
+                             ids=["default_3", "depth_2", "depth_4"])
+    def test_default_depth_and_the_ring_sized_with_it(self, lenet_model, pipeline_depth,
+                                                      depth, capacity):
+        """``pipeline_depth`` defaults to 3, an explicit one wins, and the
+        auto-sized arena follows it: (depth + 2) batches of slots, which
         the ring rounds up to a power of two."""
-        f = ModelWindowFunction(lenet_model, policy=BucketPolicy(fixed_batch=B),
-                                transfer_lanes=lanes)
+        kw = {} if pipeline_depth is None else {"pipeline_depth": pipeline_depth}
+        f = ModelWindowFunction(lenet_model, policy=BucketPolicy(fixed_batch=B), **kw)
         assert f._max_in_flight == depth - 1
         f.open(_ctx())
         try:
             assert f._ring is not None
             assert f._ring_capacity == (depth + 2) * B
-            assert f._ring.capacity == 32  # 20, 24 and 32 asked
+            assert f._ring.capacity == capacity  # 20, 16 and 24 asked
         finally:
             f.close()
-        # An explicit depth still wins (2 is the behaviour before PR 29).
-        two = ModelWindowFunction(lenet_model, policy=BucketPolicy(fixed_batch=B),
-                                  transfer_lanes=lanes, pipeline_depth=2)
-        assert two._max_in_flight == 1
 
     def test_open_pages_the_whole_arena_in(self, lenet_model):
         """The arena is allocated lazily; ``open`` touches every page of it, so

@@ -194,14 +194,11 @@ class TestPartition:
 
 
 # ---------------------------------------------------------------------------
-# Seeded defect 3: implicit reshard across a device-resident chain.
+# Seeded defect 3: implicit reshard across a chained edge.
 # ---------------------------------------------------------------------------
 class TestReshard:
     def _chained(self, up_out_axes):
         def build(env):
-            # Device residency ON: the chained edge keeps batches in HBM,
-            # which is exactly what a layout mismatch would defeat.
-            env.configure(device_resident=True)
             env.from_collection([{}]).map(
                 ModelMapFunction(_tiny_model(), "serve",
                                  sharding_axes=("data",),
@@ -215,23 +212,22 @@ class TestReshard:
         return _plan(build)
 
     def test_layout_mismatch_on_device_resident_chain_is_error(self):
+        """A layout mismatch across a chained edge is a WARN naming both
+        axis tuples: every batch crossing the edge is resharded."""
         from flink_tensorflow_tpu.analysis import compute_chains
 
         env = self._chained(("model",))
-        # Preconditions: the two model maps really did chain, with a
-        # device-resident edge between them — the reshard then defeats
-        # the h2d elision and must escalate to ERROR.
         diags = analyze(env.graph, config=env.config)
         ops = {t.id: t.operator_factory() for t in env.graph.transformations}
         plan = compute_chains(env.graph, operators=ops)
-        assert plan.device_resident_edges, "fixture did not chain"
+        assert ["up", "down"] in plan.names(), "fixture did not chain"
         hits = [d for d in diags if d.rule == "shardcheck-reshard"]
-        assert hits, "layout mismatch across the chain not flagged"
-        assert hits[0].severity == Severity.ERROR
+        assert len(hits) == 1, "layout mismatch across the chain not flagged"
+        assert hits[0].severity == Severity.WARN
         assert hits[0].edge == "up -> down"
         assert "('model',)" in hits[0].message
         assert "('data',)" in hits[0].message
-        assert "h2d elision" in hits[0].message
+        assert "implicit reshard" in hits[0].message
 
     def test_matching_layouts_are_clean(self):
         env = self._chained(("data",))
